@@ -6,9 +6,8 @@ experiment trains the SAME tiny MoE LM (same init, same data order) at
 capacity_factor 1.0 / 1.25 / 2.0 and a dropless control (capacity >=
 top_k * tokens, so nothing can overflow), and records final train loss,
 eval loss, and the measured drop fraction. Quality impact is a property
-of the routing algebra, not the accelerator, so the sweep runs anywhere
-(the committed table in BASELINE.md came from the 8-device CPU mesh
-host). Run: python examples/moe_capacity_sweep.py [steps]
+of the routing algebra, not the accelerator, so the sweep runs anywhere.
+Run: python examples/moe_capacity_sweep.py [steps]
 """
 
 import os

@@ -686,6 +686,12 @@ def find_default_manifest(start: str = ".") -> str | None:
 
 
 # ----------------------------------------------------- program enumeration
+class TooFewDevices(RuntimeError):
+    """A canonical group needs a larger mesh than this process has —
+    the one skip reason that is the environment's and not a fault
+    (``tests/test_hlo.py`` fails on any other)."""
+
+
 def canonical_programs(
     only: list[str] | None = None, skip: list[str] | None = None,
 ) -> tuple[list[dict], list[tuple[str, str]]]:
@@ -697,8 +703,8 @@ def canonical_programs(
     BUILDERS: the jit closures lowered here are the same functions the
     production engines dispatch, so aliasing, program structure, and
     dtype flow are the real thing — only the weights are small.
-    Engine families that this environment cannot trace (jax version
-    gaps) are reported in ``skipped``, never silently dropped."""
+    Engine families that this environment cannot trace are reported
+    in ``skipped``, never silently dropped."""
     import jax
     import jax.numpy as jnp
 
@@ -882,7 +888,7 @@ def canonical_programs(
         from tensorlink_tpu.train.trainer import softmax_cross_entropy
 
         if len(jax.devices()) < 2:
-            raise RuntimeError("needs >= 2 devices for a pipe mesh")
+            raise TooFewDevices("needs >= 2 devices for a pipe mesh")
         gm = GPT2(GPT2Config(
             vocab_size=64, dim=16, num_layers=2, num_heads=2, max_len=32,
             dropout=0.0,
@@ -924,7 +930,7 @@ def canonical_programs(
     def infer_group() -> list[dict]:
         ndev = len(jax.devices())
         if ndev < 4:
-            raise RuntimeError(
+            raise TooFewDevices(
                 f"kv_seq_shard needs a seq=4 mesh, only {ndev} device(s)"
             )
         cfg = LlamaConfig(
@@ -1058,12 +1064,12 @@ def main(argv: list[str] | None = None) -> int:
     # process that initialized the backend beforehand (an in-process
     # test harness, a TPU operator) keeps its own runtime, and the
     # enumeration then adapts by skipping the groups it cannot mesh.
+    from tensorlink_tpu.runtime.mesh import virtual_cpu_xla_flags
+
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
+    os.environ["XLA_FLAGS"] = virtual_cpu_xla_flags(
+        8, os.environ.get("XLA_FLAGS", "")
+    )
 
     args = build_parser().parse_args(argv)
     if args.list_rules:

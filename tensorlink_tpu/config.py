@@ -212,8 +212,10 @@ class NodeConfig:
     step_watchdog_s: float | None = 300.0
     # persistent XLA compilation cache (runtime/compile_cache.py): a
     # restarted node reloads its compiled serving/stage programs from
-    # disk instead of re-paying XLA. None defers to the
-    # TL_COMPILE_CACHE_DIR environment variable; both unset = off.
+    # disk instead of re-paying XLA. JAX_COMPILATION_CACHE_DIR, when
+    # set, decides the directory and this field is then only checked
+    # against it; otherwise None defers to TL_COMPILE_CACHE_DIR, and
+    # with both unset the cache lives in <checkout>/.jax_cache.
     compile_cache_dir: str | None = None
     # persistent autotune store (runtime/autotune.py): measured
     # flash-block overrides, prefill-bucket sets, and the adaptive-

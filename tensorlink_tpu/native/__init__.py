@@ -9,8 +9,8 @@ Python with no integrity checking (src/p2p/connection.py:39-151).
 The shared library is built on demand with g++ (baked into the image) and
 cached next to the source; every entry point has a pure-Python fallback
 so the package works without a toolchain — callers use `crc32c()` /
-`gather()` and never see which implementation ran. `HAVE_NATIVE` reports
-which one is live.
+`gather()` and never see which implementation ran. `have_native()`
+reports which one is live.
 """
 
 from __future__ import annotations

@@ -339,8 +339,8 @@ class MultiHeadAttention(Module):
             # One matmul instead of three: at decode (T=1, tiny batch)
             # each projection kernel is launch-bound, and fusing q/k/v
             # removed ~2 convolution launches + their bias/reshape
-            # fusions per layer per token (measured r5 on v5e — see
-            # BASELINE.md decode entry). Layout is Megatron-style
+            # fusions per layer per token (measured r5 on v5e).
+            # Layout is Megatron-style
             # PER-KV-GROUP interleave [.., G, (H/G q | 1 k | 1 v), Dh]
             # so a column TP split stays head-aligned whenever the model
             # axis divides num_kv_heads (the same alignment plain GQA TP

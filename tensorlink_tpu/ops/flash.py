@@ -20,16 +20,15 @@ import jax
 import jax.numpy as jnp
 
 from tensorlink_tpu.nn.attention import band_keep, dot_product_attention
+from tensorlink_tpu.ops.pallas import (
+    gate_closed,
+    on_tpu,
+    partitioned_by_xla,
+)
 from tensorlink_tpu.ops.pallas.flash_attention import (
     flash_attention_bwd,
     flash_attention_fwd_lse,
 )
-
-
-def _use_pallas(interpret: bool) -> bool:
-    if interpret:
-        return True
-    return jax.devices()[0].platform == "tpu"
 
 
 def _tile_ok(T: int) -> bool:
@@ -133,7 +132,18 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
 
 
 def _kernel_path(q, k, interpret) -> bool:
-    return _use_pallas(interpret) and _tile_ok(q.shape[1]) and _tile_ok(k.shape[1])
+    """Static gate for the Pallas kernels: silent off the TPU (the jnp
+    path is the only one there); on it a refusal records its reason."""
+    if not interpret and not on_tpu():
+        return False
+    closed = partial(gate_closed, "flash_attention", q=q.shape, k=k.shape)
+    if not interpret and (why := partitioned_by_xla()):
+        return closed(why)
+    if _tile_ok(q.shape[1]) and _tile_ok(k.shape[1]):
+        return True
+    return closed(
+        f"seq {q.shape[1]}/{k.shape[1]} does not tile into 128-blocks"
+    )
 
 
 def _fallback_attn(q, k, v, kv_mask, causal, window=None):

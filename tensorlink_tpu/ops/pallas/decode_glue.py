@@ -15,8 +15,9 @@ Decode shapes are tiny (rows = serving slots), so the whole operand set
 lives in VMEM with no grid.
 
 Off-TPU (and for any shape the kernel doesn't cover) the public entry
-falls back to the identical jnp expression — CPU CI exercises both the
-fallback (always) and the kernel via ``interpret=True`` parity tests.
+takes the identical jnp expression — CPU CI exercises both that path
+(always) and the kernel via ``interpret=True`` parity tests. On a TPU a
+refusal records its reason (``ops/pallas gate_closed``).
 """
 
 from __future__ import annotations
@@ -28,6 +29,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from tensorlink_tpu.ops.pallas import (
+    gate_closed,
+    on_tpu,
+    partitioned_by_xla,
+)
 
 LANES = 128
 
@@ -74,17 +81,24 @@ def _kernel_nobias(x_ref, res_ref, scale_ref, r_ref, y_ref,
 
 
 def _kernel_ok(x, interpret: bool) -> bool:
+    if not interpret and not on_tpu():
+        return False
+    closed = partial(gate_closed, "decode_glue", x=x.shape)
     if not _ENABLED:
-        return False
-    if not interpret and jax.devices()[0].platform != "tpu":
-        return False
+        return closed("TL_DECODE_GLUE=0")
+    if not interpret and (why := partitioned_by_xla()):
+        return closed(why)
     D = x.shape[-1]
-    # lane-aligned feature dim; decode rows are few — everything fits
-    # VMEM ungridded (64 rows x 8192 f32 is 2 MB)
+    if D % LANES:
+        return closed(f"feature dim {D} is not a multiple of {LANES} lanes")
+    # decode rows are few — everything fits VMEM ungridded (64 rows x
+    # 8192 f32 is 2 MB)
     rows = 1
     for d in x.shape[:-1]:
         rows *= d
-    return D % LANES == 0 and rows * D * 4 <= 8 * 1024 * 1024
+    if rows * D * 4 > 8 * 1024 * 1024:
+        return closed(f"{rows} rows x {D} does not fit VMEM ungridded")
+    return True
 
 
 def fused_residual_norm(
@@ -131,6 +145,7 @@ def fused_residual_norm(
                 pl.BlockSpec(memory_space=pltpu.VMEM),
             ),
             interpret=interpret,
+            name="tl_decode_glue",
         )(*ops)
         return r.reshape(*lead, D), y.reshape(*lead, D)
     # fallback: same f32 math, XLA-fused

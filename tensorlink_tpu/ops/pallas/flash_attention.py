@@ -326,6 +326,7 @@ def flash_attention_fwd_lse(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="tl_flash_fwd",
     )(*args)
     return o, lse[..., 0]
 
@@ -548,6 +549,7 @@ def flash_attention_bwd(
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="tl_flash_bwd_dq",
     )(*args)
 
     # dkv grid swaps the outer two block axes: (b, h, kj, qi)
@@ -577,6 +579,7 @@ def flash_attention_bwd(
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="tl_flash_bwd_dkv",
     )(*args)
     if group > 1:  # sum each GQA group back to its kv head
         dk = dk.reshape(B, Hkv, group, Tk, D).sum(axis=2)

@@ -20,10 +20,13 @@ Grid layout ``(B, H, NSUP, G)``, last dim fastest:
   the flash kernels;
 - ``NSUP x G``: the row's ``max_blocks`` logical pages, walked
   ``G = pages_per_step`` at a time. Each ``g`` stashes its page's
-  masked scores (and dequantized V) in VMEM scratch; the online-softmax
-  rescale runs ONCE per superstep over the ``G * bs`` stripe — ``G``
-  is the tunable that amortizes rescale overhead over page DMA, the
-  knob ``runtime/autotune.py`` persists beside the flash blocks.
+  masked scores (and dequantized V) in page-major VMEM scratch
+  (``[G, T, bs]`` / ``[G, bs, D]``: a page is a leading-dim index — a
+  ``bs``-wide dynamic slice of one lane axis is not something the TPU
+  compiler takes); the online-softmax rescale runs ONCE per superstep
+  over the ``G`` stashed pages — ``G`` is the tunable that amortizes
+  rescale overhead over page DMA, the knob ``runtime/autotune.py``
+  persists beside the flash blocks.
 
 Pages outside a row's live range (beyond ``lengths[b]``, or wholly
 below the sliding-window band) clamp their index map into the live
@@ -55,6 +58,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from tensorlink_tpu.ops.pallas import (
+    gate_closed,
+    on_tpu,
+    partitioned_by_xla,
+)
 
 LANES = 128
 NEG_INF = -1e30  # finite: exp underflows to 0.0, NaN-free (see nn.attention)
@@ -230,24 +239,30 @@ def _paged_kernel(
     if has_mask:
         keep = jnp.logical_and(keep, mask_ref[0, 0] > 0)
     s = jnp.where(keep, s, NEG_INF)
-    pl.store(s_scr, (slice(None), pl.dslice(g * bs, bs)), s)
-    pl.store(v_scr, (pl.dslice(g * bs, bs), slice(None)), vb)
+    s_scr[g] = s
+    v_scr[g] = vb
 
     @pl.when(g == G - 1)
     def _update():
-        s_all = s_scr[...]  # [T, G * bs]
+        s_all = s_scr[...]  # [G, T, bs]
         m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s_all, axis=1, keepdims=True))
-        p = jnp.exp(s_all - m_new)
+        m_new = jnp.maximum(
+            m_prev,
+            jnp.max(jnp.max(s_all, axis=0), axis=1, keepdims=True),
+        )
+        p = jnp.exp(s_all - m_new[None])
         # recover the mask from the score sentinel: when every stripe
         # entry is masked, exp(s - m_new) above is exp(0) = 1, not 0
         p = jnp.where(s_all > NEG_INF * 0.5, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v_scr[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        l_new = alpha * l_scr[:, 0:1] + jnp.sum(
+            jnp.sum(p, axis=0), axis=1, keepdims=True
         )
+        pv = jax.lax.dot_general(
+            p, v_scr[...], (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [G, T, D]
+        acc_scr[...] = acc_scr[...] * alpha + jnp.sum(pv, axis=0)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -265,20 +280,27 @@ def paged_decode_ok(
     """Static gate: can (and should) the kernel serve this call?
     ``TL_PAGED_KERNEL=0`` forces False everywhere — the pure-XLA
     gather path is then bit-for-bit what it was before this kernel
-    existed."""
+    existed. Off the TPU (and not interpreting) the answer is a silent
+    False; past that point every refusal records its reason
+    (``ops/pallas gate_closed``)."""
     mode = _mode()
-    if mode == "0":
-        return False
     it = (mode == "interpret") if interpret is None else interpret
-    if not it and jax.devices()[0].platform != "tpu":
+    if not it and not on_tpu():
         return False
+    closed = partial(
+        gate_closed, "paged_decode", q=q.shape, pool=k_pool.shape
+    )
+    if mode == "0":
+        return closed("TL_PAGED_KERNEL=0")
+    if not it and (why := partitioned_by_xla()):
+        return closed(why)
     D = q.shape[-1]
     if not it and D % LANES:
-        return False  # lane-aligned head dim on hardware
+        return closed(f"head dim {D} is not a multiple of {LANES} lanes")
     if q.shape[2] % k_pool.shape[2]:
-        return False  # GQA needs Hkv | H
+        return closed("GQA needs Hkv | H")
     if mask is not None and (mask.ndim != 4 or mask.shape[1] != 1):
-        return False  # per-head masks stay on the XLA path
+        return closed("per-head masks stay on the XLA path")
     return True
 
 
@@ -360,7 +382,7 @@ def paged_decode_attention(
         return (phys, h // group, 0, 0)
 
     def _mask_map(b, h, jc, g, len_ref, bt_ref):
-        return (b, 0, 0, _page(jc, g, len_ref, bt_ref, b))
+        return (b, _page(jc, g, len_ref, bt_ref, b), 0, 0)
 
     # head-major layouts (flash-kernel convention: the last two block
     # dims equal the array dims, so tiny decode shapes tile legally)
@@ -380,9 +402,13 @@ def paged_decode_attention(
                 sc.transpose(0, 2, 1)[..., None].astype(jnp.float32)
             )
     if has_mask:
+        # page-major [B, MB, T, bs]: a (T, bs) block then spans the
+        # array's last two dims (a bs-wide slice of the Lv lane axis
+        # is not a legal TPU block)
         in_specs.append(pl.BlockSpec((1, 1, T, bs), _mask_map))
         args.append(
             jnp.broadcast_to(mask, (B, 1, T, Lv)).astype(jnp.float32)
+            .reshape(B, T, MB, bs).transpose(0, 2, 1, 3)
         )
     kernel = partial(
         _paged_kernel, T=T, bs=bs, G=G, scale=D ** -0.5,
@@ -394,8 +420,8 @@ def paged_decode_attention(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, T, D), _q_map),
         scratch_shapes=[
-            pltpu.VMEM((T, G * bs), jnp.float32),  # score stripe
-            pltpu.VMEM((G * bs, D), jnp.float32),  # dequantized V stripe
+            pltpu.VMEM((G, T, bs), jnp.float32),  # score stripe
+            pltpu.VMEM((G, bs, D), jnp.float32),  # dequantized V stripe
             pltpu.VMEM((T, LANES), jnp.float32),   # running max
             pltpu.VMEM((T, LANES), jnp.float32),   # running normalizer
             pltpu.VMEM((T, D), jnp.float32),       # output accumulator
@@ -406,5 +432,6 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qT.shape, q.dtype),
         interpret=it,
+        name="tl_paged_decode",
     )(lengths, bt32, *args)
     return o.transpose(0, 2, 1, 3)

@@ -347,10 +347,9 @@ def _ring_flash_usable(q, k, mask, interpret) -> tuple:
     """(kv_mask | None, usable: bool) — kernel path preconditions: TPU
     (or interpret), tile-able local lengths, mask absent or a global
     key-padding vector [B, 1, 1, S*Tk]."""
-    from tensorlink_tpu.ops.flash import _tile_ok, _use_pallas
+    from tensorlink_tpu.ops.flash import _kernel_path
 
-    if not (_use_pallas(interpret) and _tile_ok(q.shape[1])
-            and _tile_ok(k.shape[1])):
+    if not _kernel_path(q, k, interpret):
         return None, False
     if mask is None:
         return None, True

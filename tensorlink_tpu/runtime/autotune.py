@@ -131,10 +131,9 @@ def apply_paged_overrides(record: dict) -> int:
 
 
 class AutotuneStore:
-    """Directory of per-key tuning records. ``resolve`` mirrors
-    ``enable_compile_cache``'s directory discipline: explicit argument,
-    then ``$TL_AUTOTUNE_DIR``, else None (= feature off, every call a
-    no-op)."""
+    """Directory of per-key tuning records. ``resolve`` takes the
+    explicit argument, then ``$TL_AUTOTUNE_DIR``, else None (= feature
+    off, every call a no-op)."""
 
     def __init__(self, root: str, *, recorder=None):
         self.root = Path(root).expanduser()

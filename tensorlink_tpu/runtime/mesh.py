@@ -66,6 +66,34 @@ def shutdown_distributed() -> None:
         _distributed_initialized = False
 
 
+def virtual_cpu_xla_flags(n_devices: int, flags: str = "") -> str:
+    """``XLA_FLAGS`` for an ``n_devices``-device virtual CPU platform,
+    merged into the ``flags`` already set. Takes effect only if it is
+    in the environment before anything asks jax for its devices (the
+    backend is built on the first such call). ``tests/conftest.py``
+    spells the same flags out, because it must not import jax first."""
+    import re
+
+    m = re.search(r"--xla_force_host_platform_device_count=(\d+)", flags)
+    if m is None:
+        flags += f" --xla_force_host_platform_device_count={n_devices}"
+    elif int(m.group(1)) < n_devices:
+        # an existing smaller count would win over ours
+        flags = (
+            flags[: m.start()]
+            + f"--xla_force_host_platform_device_count={n_devices}"
+            + flags[m.end():]
+        )
+    if "xla_disable_hlo_passes" not in flags:
+        # XLA:CPU's AllReducePromotion pass aborts the PROCESS on the
+        # bf16 all-reduce jax 0.9.0 emits for a psum inside a partially
+        # manual shard_map (ShardedTrainer with pipe > 1; CHANGES.md,
+        # PR 24, has the reproducer); the CPU runtime reduces bf16
+        # without it
+        flags += " --xla_disable_hlo_passes=all-reduce-promotion"
+    return flags.strip()
+
+
 def make_mesh(cfg: MeshConfig, devices: Sequence[jax.Device] | None = None) -> Mesh:
     """Build the global mesh with axes (data, pipe, model, seq).
 

@@ -65,8 +65,12 @@ def _single_process_reference(devices):
 
 def test_two_process_mesh_matches_single_process(devices):
     coord = f"127.0.0.1:{_free_port()}"
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    # the children are CPU-only and their environment says so: 4
+    # virtual devices each, 8 in the global mesh
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+    )
     procs = [
         subprocess.Popen(
             [sys.executable, _WORKER, coord, str(i)],

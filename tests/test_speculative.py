@@ -12,6 +12,7 @@ satellite (a restarted process demonstrably reuses kernels).
 """
 
 import json
+import os
 import subprocess
 import sys
 
@@ -430,10 +431,14 @@ def test_compile_cache_restart_reuses_kernels(tmp_path):
 
     d = str(tmp_path / "cc")
 
+    # a directory given from outside would win over the one under test
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+
     def run():
         out = subprocess.run(
             [sys.executable, "-c", _CC_SCRIPT, d],
-            capture_output=True, text=True, timeout=600,
+            capture_output=True, text=True, timeout=600, env=env,
         )
         assert out.returncode == 0, out.stderr[-2000:]
         return json.loads(out.stdout.strip().splitlines()[-1])

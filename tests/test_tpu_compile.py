@@ -1,0 +1,223 @@
+"""The main path's Pallas kernels through the TPU compiler, without a chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is described, not attached. Interpret-mode parity tests
+cannot see what it refuses (a slice not aligned to the tiling, a block
+that is no legal tile, more VMEM than a kernel may use), so every
+kernel is compiled here at the widths it serves, and has to be IN the
+compiled program as a ``tpu_custom_call``.
+
+Only one process may load the TPU's library, so everything that touches
+the topology lives in this one file, inside fixtures: nothing at import,
+in a ``skipif`` or in ``parametrize`` arguments, and no child process.
+Nothing runs, so nothing here is a result or a time.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache.compilation_cache import reset_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(topo):
+    """``compile_for_chip(fn, *shapes) -> compiled text`` for one
+    described v5e chip. The persistent cache is off around these
+    compiles: an executable for an absent chip can be written to it but
+    not read back."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    reset_cache()  # tlint: disable=TL503 the switch is read at cache init
+
+    def compile_(fn, *shapes):
+        args = [
+            jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes
+        ]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was)
+    reset_cache()  # tlint: disable=TL503 as above, switching back
+
+
+def _kernel_lines(text: str, name: str) -> list[str]:
+    return [
+        ln for ln in text.splitlines()
+        if "tpu_custom_call" in ln and name in ln
+    ]
+
+
+# BERT-base at seq 512 with its key-padding mask; a 128-wide-head
+# decoder at seq 2048, causal
+FLASH_SHAPES = [
+    pytest.param(8, 12, 512, 64, True, False, id="B8-H12-T512-D64-kvmask"),
+    pytest.param(2, 32, 2048, 128, False, True, id="B2-H32-T2048-D128-causal"),
+]
+
+
+@pytest.mark.parametrize("B,H,T,D,masked,causal", FLASH_SHAPES)
+def test_flash_forward_compiles(compile_for_chip, B, H, T, D, masked, causal):
+    from tensorlink_tpu.ops.flash import flash_block_for
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        flash_attention_fwd_lse,
+    )
+
+    blk = flash_block_for(T, B)
+
+    def fwd(q, k, v, *mask):
+        return flash_attention_fwd_lse(
+            q, k, v, mask[0] if mask else None, causal=causal,
+            block_q=blk, block_k=blk,
+        )
+
+    qkv = [((B, H, T, D), BF16)] * 3
+    mask = [((B, T), jnp.float32)] if masked else []
+    assert _kernel_lines(compile_for_chip(fwd, *qkv, *mask), "tl_flash_fwd")
+
+
+@pytest.mark.parametrize("B,H,T,D,masked,causal", FLASH_SHAPES)
+def test_flash_backward_compiles(compile_for_chip, B, H, T, D, masked, causal):
+    from tensorlink_tpu.ops.flash import flash_block_for
+    from tensorlink_tpu.ops.pallas.flash_attention import flash_attention_bwd
+
+    blk = flash_block_for(T, B)
+
+    def bwd(q, k, v, o, lse, do, *mask):
+        return flash_attention_bwd(
+            q, k, v, o, lse, do, mask[0] if mask else None, causal=causal,
+            block_q=blk, block_k=blk,
+        )
+
+    t4 = ((B, H, T, D), BF16)
+    mask = [((B, T), jnp.float32)] if masked else []
+    text = compile_for_chip(
+        bwd, t4, t4, t4, t4, ((B, H, T), jnp.float32), t4, *mask
+    )
+    assert _kernel_lines(text, "tl_flash_bwd_dq")
+    assert _kernel_lines(text, "tl_flash_bwd_dkv")
+
+
+@pytest.mark.parametrize("T", [1, 4])
+@pytest.mark.parametrize("pools", ["bf16", "int8"])
+def test_paged_decode_compiles(compile_for_chip, monkeypatch, pools, T):
+    """Mistral-7B widths (32 query / 8 KV heads of 128), 16-token
+    pages, 8 slots of a 1024-token view, window 4096: single-token
+    decode and a verify-K chunk, bf16 and int8 pools."""
+    from tensorlink_tpu.ops.pallas import paged_decode
+
+    # the gate asks jax.devices(), which is the CPU here
+    monkeypatch.setattr(paged_decode, "on_tpu", lambda: True)
+    B, H, Hkv, D, bs, MB = 8, 32, 8, 128, 16, 64
+    NB = B * MB + 1
+    quant = pools == "int8"
+    pool = ((NB, bs, Hkv, D), jnp.int8 if quant else BF16)
+    scales = [((NB, bs, Hkv), jnp.float32)] * 2 if quant else []
+
+    def decode(q, k, v, bt, lengths, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return paged_decode.paged_decode_attention(
+            q, k, v, bt, lengths, window=4096, **kw
+        )
+
+    text = compile_for_chip(
+        decode, ((B, T, H, D), BF16), pool, pool, ((B, MB), jnp.int32),
+        ((B,), jnp.int32), *scales,
+    )
+    assert _kernel_lines(text, "tl_paged_decode")
+
+
+def test_paged_decode_with_mask_compiles(compile_for_chip, monkeypatch):
+    """The explicit view-width mask: its (T, bs) page block is legal
+    only page-major."""
+    from tensorlink_tpu.ops.pallas import paged_decode
+
+    monkeypatch.setattr(paged_decode, "on_tpu", lambda: True)
+    B, T, H, D, bs, MB = 8, 4, 16, 128, 16, 64  # an MHA shape
+    NB = B * MB + 1
+    pool = ((NB, bs, H, D), BF16)
+
+    def decode(q, k, v, bt, lengths, mask):
+        return paged_decode.paged_decode_attention(
+            q, k, v, bt, lengths, mask=mask
+        )
+
+    text = compile_for_chip(
+        decode, ((B, T, H, D), BF16), pool, pool, ((B, MB), jnp.int32),
+        ((B,), jnp.int32), ((B, 1, T, MB * bs), jnp.bool_),
+    )
+    assert _kernel_lines(text, "tl_paged_decode")
+
+
+@pytest.mark.parametrize(
+    "kind,D,bias",
+    [("layer", 768, True), ("rms", 4096, False)],
+    ids=["layer-D768", "rms-D4096"],
+)
+def test_fused_residual_norm_compiles(
+    compile_for_chip, monkeypatch, kind, D, bias
+):
+    from tensorlink_tpu.ops.pallas import decode_glue
+
+    monkeypatch.setattr(decode_glue, "on_tpu", lambda: True)
+
+    def glue(x, res, scale, *b):
+        return decode_glue.fused_residual_norm(
+            x, res, scale, b[0] if b else None, kind=kind
+        )
+
+    row = ((8, 1, D), BF16)
+    vec = [((D,), jnp.float32)] * (2 if bias else 1)
+    assert _kernel_lines(
+        compile_for_chip(glue, row, row, *vec), "tl_decode_glue"
+    )
+
+
+def test_gate_closes_where_xla_partitions(topo, compile_for_chip, monkeypatch):
+    """XLA cannot split a Mosaic kernel: on a four-chip mesh whose axes
+    it partitions, lowering one raises. The gate has to close there —
+    the program compiles without the kernel — and say why."""
+    from tensorlink_tpu.ops.pallas import decode_glue
+    from tensorlink_tpu.runtime.flight import default_recorder
+
+    monkeypatch.setattr(decode_glue, "on_tpu", lambda: True)
+    mesh = Mesh(
+        [[d] for d in topo.devices], ("model", "data"),
+    )
+    rows = NamedSharding(mesh, P())
+    args = [
+        jax.ShapeDtypeStruct(s, dt, sharding=rows)
+        for s, dt in [((8, 1, 768), BF16)] * 2 + [((768,), jnp.float32)]
+    ]
+    seen = len(default_recorder().events(kind="kernel.gate_closed"))
+    with jax.set_mesh(mesh):
+        text = jax.jit(
+            lambda x, r, s: decode_glue.fused_residual_norm(
+                x, r, s, kind="layer"
+            )
+        ).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
+    why = [
+        e["attrs"]["reason"] for e in
+        default_recorder().events(kind="kernel.gate_closed")[seen:]
+    ]
+    assert why and "partitioned by XLA" in why[-1], why
