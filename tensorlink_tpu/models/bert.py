@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from tensorlink_tpu.nn.module import Module
 from tensorlink_tpu.nn.layers import Dense, Dropout, Embedding, LayerNorm
 from tensorlink_tpu.nn.transformer import TransformerBlock, TransformerStack
+from tensorlink_tpu.runtime.tracing import scope
 
 
 @dataclass(frozen=True)
@@ -88,14 +89,15 @@ class Bert(Module):
         pos = jnp.arange(T)[None, :]
         if token_type_ids is None:
             token_type_ids = jnp.zeros_like(input_ids)
-        x = (
-            self.children["tok_emb"].apply(params["tok_emb"], input_ids)
-            + self.children["pos_emb"].apply(params["pos_emb"], pos)
-            + self.children["type_emb"].apply(params["type_emb"], token_type_ids)
-        )
-        x = self.children["emb_norm"].apply(params["emb_norm"], x)
         r0, r1 = jax.random.split(rng) if rng is not None else (None, None)
-        x = self.children["emb_drop"].apply(params["emb_drop"], x, rng=r0, train=train)
+        with scope("embed"):
+            x = (
+                self.children["tok_emb"].apply(params["tok_emb"], input_ids)
+                + self.children["pos_emb"].apply(params["pos_emb"], pos)
+                + self.children["type_emb"].apply(params["type_emb"], token_type_ids)
+            )
+            x = self.children["emb_norm"].apply(params["emb_norm"], x)
+            x = self.children["emb_drop"].apply(params["emb_drop"], x, rng=r0, train=train)
 
         mask = None
         if attention_mask is not None:
@@ -104,7 +106,8 @@ class Bert(Module):
         h = self.children["encoder"].apply(
             params["encoder"], x, mask=mask, rng=r1, train=train
         )
-        pooled = jnp.tanh(self.children["pooler"].apply(params["pooler"], h[:, 0]))
+        with scope("head"):
+            pooled = jnp.tanh(self.children["pooler"].apply(params["pooler"], h[:, 0]))
         return {"last_hidden_state": h, "pooled": pooled}
 
 

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 from tensorlink_tpu.nn.module import Module
 from tensorlink_tpu.nn.layers import Dropout, Embedding, LayerNorm
 from tensorlink_tpu.nn.transformer import TransformerBlock, TransformerStack
+from tensorlink_tpu.runtime.tracing import scope
 
 
 @dataclass(frozen=True)
@@ -94,10 +95,11 @@ class GPT2(Module):
                 positions = idx + jnp.arange(T)[None, :]
             else:
                 positions = jnp.arange(T)[None, :]
-        x = self.children["wte"].apply(params["wte"], input_ids)
-        x = x + self.children["wpe"].apply(params["wpe"], positions)
         r0, r1 = jax.random.split(rng) if rng is not None else (None, None)
-        x = self.children["drop"].apply(params["drop"], x, rng=r0, train=train)
+        with scope("embed"):
+            x = self.children["wte"].apply(params["wte"], input_ids)
+            x = x + self.children["wpe"].apply(params["wpe"], positions)
+            x = self.children["drop"].apply(params["drop"], x, rng=r0, train=train)
 
         blocks = self.children["blocks"]
         if caches is not None:
@@ -110,8 +112,9 @@ class GPT2(Module):
             new_caches = None
             x = blocks.apply(params["blocks"], x, mask=mask, rng=r1, train=train)
 
-        x = self.children["ln_f"].apply(params["ln_f"], x)
-        out = self.children["wte"].attend(params["wte"], x) if logits else x
+        with scope("head"):
+            x = self.children["ln_f"].apply(params["ln_f"], x)
+            out = self.children["wte"].attend(params["wte"], x) if logits else x
         if caches is not None:
             return out, new_caches
         return out
@@ -132,13 +135,15 @@ class GPT2(Module):
             ids = batch["input_ids"]
             T = ids.shape[1]
             pos = jnp.arange(T)[None, :]
-            tok = wte.apply(emb_params["wte"], ids)
-            x = tok + wpe.apply(emb_params["wpe"], pos).astype(tok.dtype)
-            return drop.apply({}, x, rng=rng, train=rng is not None)
+            with scope("embed"):
+                tok = wte.apply(emb_params["wte"], ids)
+                x = tok + wpe.apply(emb_params["wpe"], pos).astype(tok.dtype)
+                return drop.apply({}, x, rng=rng, train=rng is not None)
 
         def head_fn(all_params, x, batch, rng=None):
-            h = ln_f.apply(all_params["head"]["ln_f"], x)
-            return wte.attend(all_params["embed"]["wte"], h)
+            with scope("head"):
+                h = ln_f.apply(all_params["head"]["ln_f"], x)
+                return wte.attend(all_params["embed"]["wte"], h)
 
         return PipelineParts(
             embed_fn=embed_fn,

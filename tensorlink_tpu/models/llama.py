@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from tensorlink_tpu.nn.module import Module
 from tensorlink_tpu.nn.layers import Dense, Embedding, RMSNorm
 from tensorlink_tpu.nn.transformer import TransformerBlock, TransformerStack
+from tensorlink_tpu.runtime.tracing import scope
 
 
 @dataclass(frozen=True)
@@ -133,6 +134,17 @@ class Llama(Module):
         self.child("norm_f", RMSNorm(cfg.dim, eps=cfg.rms_eps))
         self.child("lm_head", Dense(cfg.dim, cfg.vocab_size, use_bias=False, shard="col"))
 
+    def _embed(self, emb_params, input_ids):
+        with scope("embed"):
+            return self.children["tok_emb"].apply(emb_params, input_ids)
+
+    def _head(self, norm_params, head_params, x, logits: bool = True):
+        with scope("head"):
+            x = self.children["norm_f"].apply(norm_params, x)
+            if not logits:
+                return x
+            return self.children["lm_head"].apply(head_params, x)
+
     def apply(
         self,
         params,
@@ -146,7 +158,7 @@ class Llama(Module):
         logits: bool = True,
         **_,
     ):
-        x = self.children["tok_emb"].apply(params["tok_emb"], input_ids)
+        x = self._embed(params["tok_emb"], input_ids)
         blocks = self.children["blocks"]
         if caches is not None:
             attn_caches = [c["attn"] for c in caches]
@@ -161,10 +173,7 @@ class Llama(Module):
                 params["blocks"], x, mask=mask, positions=positions,
                 rng=rng, train=train,
             )
-        x = self.children["norm_f"].apply(params["norm_f"], x)
-        out = (
-            self.children["lm_head"].apply(params["lm_head"], x) if logits else x
-        )
+        out = self._head(params["norm_f"], params["lm_head"], x, logits)
         if caches is not None:
             return out, new_caches
         return out
@@ -176,28 +185,25 @@ class Llama(Module):
         """-> (logits, aux): the summed MoE router load-balancing loss
         across blocks (0.0 for dense configs). Mixtral-style training
         adds ``aux_weight * aux`` to the task loss."""
-        x = self.children["tok_emb"].apply(params["tok_emb"], input_ids)
+        x = self._embed(params["tok_emb"], input_ids)
         x, aux = self.children["blocks"].apply_with_aux(
             params["blocks"], x, mask=mask, positions=positions,
             rng=rng, train=train,
         )
-        x = self.children["norm_f"].apply(params["norm_f"], x)
-        return self.children["lm_head"].apply(params["lm_head"], x), aux
+        return self._head(params["norm_f"], params["lm_head"], x), aux
 
     def as_pipeline_parts(self, params):
         from tensorlink_tpu.parallel.engine import PipelineParts
 
         stack = self.children["blocks"]
         block = stack.blocks()[0]
-        tok_emb = self.children["tok_emb"]
-        norm_f, lm_head = self.children["norm_f"], self.children["lm_head"]
 
         def embed_fn(emb_params, batch, rng=None):
-            return tok_emb.apply(emb_params["tok_emb"], batch["input_ids"])
+            return self._embed(emb_params["tok_emb"], batch["input_ids"])
 
         def head_fn(all_params, x, batch, rng=None):
-            h = norm_f.apply(all_params["head"]["norm_f"], x)
-            return lm_head.apply(all_params["head"]["lm_head"], h)
+            head = all_params["head"]
+            return self._head(head["norm_f"], head["lm_head"], x)
 
         return PipelineParts(
             embed_fn=embed_fn,

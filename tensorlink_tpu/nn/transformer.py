@@ -12,6 +12,7 @@ import jax
 from tensorlink_tpu.nn.module import Module
 from tensorlink_tpu.nn.layers import Dense, Dropout, LayerNorm, RMSNorm
 from tensorlink_tpu.nn.attention import MultiHeadAttention
+from tensorlink_tpu.runtime.tracing import scope
 
 
 ACTIVATIONS = {
@@ -187,37 +188,47 @@ class TransformerBlock(Module):
             jax.random.split(rng, 3) if rng is not None else (None, None, None)
         )
 
+        # two scopes a block, each a half with its norm and residual:
+        # every device instruction of the block reads tl.attn or tl.mlp
+        # in its op path (the fused residual+norm2 kernel is the mlp's)
         new_cache = None
         if self.norm_style == "pre":
-            h = n1.apply(params["norm1"], x)
-            a = attn.apply(params["attn"], h, mask=mask, cache=cache, positions=positions)
-            if cache is not None:
-                a, new_cache = a
-            if (
-                cache is not None and not train and x.shape[1] == 1
-                and _decode_glue().should_fuse(a, self.norm)
-            ):
-                # decode fast path: residual add + norm2 in ONE kernel
-                # launch (T=1 steps are launch-bound; the add/mean/var/
-                # rsqrt/scale chain is otherwise 2 tiny fusions per
-                # block per token — see ops/pallas/decode_glue.py)
-                x, h = _decode_glue().fused_residual_norm(
-                    a, x, params["norm2"]["scale"],
-                    params["norm2"].get("bias"),
-                    eps=self.norm_eps, kind=self.norm,
+            with scope("attn"):
+                h = n1.apply(params["norm1"], x)
+                a = attn.apply(params["attn"], h, mask=mask, cache=cache, positions=positions)
+                if cache is not None:
+                    a, new_cache = a
+                fuse = (
+                    cache is not None and not train and x.shape[1] == 1
+                    and _decode_glue().should_fuse(a, self.norm)
                 )
-            else:
-                x = x + drop.apply(params["drop"], a, rng=r1, train=train)
-                h = n2.apply(params["norm2"], x)
-            m, aux = self._mlp(params["mlp"], h, r2, train)
-            x = x + drop.apply(params["drop"], m, rng=r3, train=train)
+                if not fuse:
+                    x = x + drop.apply(params["drop"], a, rng=r1, train=train)
+            with scope("mlp"):
+                if fuse:
+                    # decode fast path: residual add + norm2 in ONE
+                    # kernel launch (T=1 steps are launch-bound; the
+                    # add/mean/var/rsqrt/scale chain is otherwise 2
+                    # tiny fusions per block per token — see
+                    # ops/pallas/decode_glue.py)
+                    x, h = _decode_glue().fused_residual_norm(
+                        a, x, params["norm2"]["scale"],
+                        params["norm2"].get("bias"),
+                        eps=self.norm_eps, kind=self.norm,
+                    )
+                else:
+                    h = n2.apply(params["norm2"], x)
+                m, aux = self._mlp(params["mlp"], h, r2, train)
+                x = x + drop.apply(params["drop"], m, rng=r3, train=train)
         else:  # post-LN (BERT)
-            a = attn.apply(params["attn"], x, mask=mask, cache=cache, positions=positions)
-            if cache is not None:
-                a, new_cache = a
-            x = n1.apply(params["norm1"], x + drop.apply(params["drop"], a, rng=r1, train=train))
-            m, aux = self._mlp(params["mlp"], x, r2, train)
-            x = n2.apply(params["norm2"], x + drop.apply(params["drop"], m, rng=r3, train=train))
+            with scope("attn"):
+                a = attn.apply(params["attn"], x, mask=mask, cache=cache, positions=positions)
+                if cache is not None:
+                    a, new_cache = a
+                x = n1.apply(params["norm1"], x + drop.apply(params["drop"], a, rng=r1, train=train))
+            with scope("mlp"):
+                m, aux = self._mlp(params["mlp"], x, r2, train)
+                x = n2.apply(params["norm2"], x + drop.apply(params["drop"], m, rng=r3, train=train))
         return x, new_cache, aux
 
     def apply(self, params, x, *, mask=None, cache=None, positions=None, rng=None, train=False, **_):

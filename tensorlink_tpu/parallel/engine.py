@@ -35,13 +35,9 @@ from tensorlink_tpu.nn.module import Module
 from tensorlink_tpu.parallel.pp import Pipeline, stack_stage_params
 from tensorlink_tpu.parallel.pp1f1b import Pipeline1F1B
 from tensorlink_tpu.runtime.metrics import pipeline_bubble_fraction
-from tensorlink_tpu.train.optim import (
-    apply_updates,
-    clip_by_global_norm,
-    make_optimizer,
-    make_schedule,
-)
-from tensorlink_tpu.train.trainer import TrainState
+from tensorlink_tpu.runtime.tracing import region, scope
+from tensorlink_tpu.train.optim import make_optimizer, make_schedule
+from tensorlink_tpu.train.trainer import TrainState, finish_step
 
 
 @dataclasses.dataclass
@@ -372,12 +368,13 @@ class ShardedTrainer:
 
     # -- step ------------------------------------------------------------
     def _cast(self, params):
-        return jax.tree.map(
-            lambda x: x.astype(self.compute_dtype)
-            if jnp.issubdtype(x.dtype, jnp.floating)
-            else x,
-            params,
-        )
+        with scope("train.cast"):
+            return jax.tree.map(
+                lambda x: x.astype(self.compute_dtype)
+                if jnp.issubdtype(x.dtype, jnp.floating)
+                else x,
+                params,
+            )
 
     def _micro_extras(self, batch, m: int):
         """extras_fn output resliced to [M, mb, ...] leaves (or None)."""
@@ -506,47 +503,19 @@ class ShardedTrainer:
             from tensorlink_tpu.nn.lora import mask_to_lora
 
             grads = mask_to_lora(grads)
-        # non-finite sentinel, BEFORE clipping (an inf leaf turns the
-        # clip norm nan and poisons every grad — the flag must name the
-        # raw anomaly); mirrors train/trainer.py so skip_nonfinite_updates
-        # is honored by BOTH trainers, not silently ignored here
-        grads_finite = jax.tree_util.tree_reduce(
-            lambda a, g: a & jnp.isfinite(g).all(),
-            grads,
-            jnp.array(True),
-        )
-        nonfinite = ~(jnp.isfinite(loss) & grads_finite)
-        if self.cfg.grad_clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, self.cfg.grad_clip_norm)
-        else:
-            gnorm = jnp.zeros(())
-        updates, opt_state = self.optimizer.update(
-            grads, state.opt_state, state.params, state.step
-        )
-        if self.cfg.train_only == "lora":
-            from tensorlink_tpu.nn.lora import mask_to_lora
+        return finish_step(self.cfg, self.optimizer, state, loss, grads)
 
-            updates = mask_to_lora(updates)
-        params = apply_updates(state.params, updates)
-        new_state = TrainState(
-            params=params, opt_state=opt_state, step=state.step + 1
-        )
-        if self.cfg.skip_nonfinite_updates:
-            # select the OLD state wholesale (params, moments, step): a
-            # poisoned batch must leave no trace in the model
-            new_state = jax.tree.map(
-                lambda new, old: jnp.where(nonfinite, old, new),
-                new_state,
-                state,
-            )
-        return (
-            new_state,
-            {"loss": loss, "grad_norm": gnorm, "nonfinite": nonfinite},
-        )
+    def _jit_step(self):
+        # the function's name is the program's: launches read
+        # jit_tl_sharded_train_step in a device trace
+        def tl_sharded_train_step(state, batch, rng):
+            return self._step(state, batch, rng)
+
+        return jax.jit(tl_sharded_train_step, donate_argnums=(0,))
 
     def train_step(self, state: TrainState, batch, rng=None):
         if self._step_fn is None:
-            self._step_fn = jax.jit(self._step, static_argnums=(), donate_argnums=(0,))
+            self._step_fn = self._jit_step()
         batch = jax.device_put(batch, self._batch_sh)
         # telemetry keys on (shape, dtype, rng-variant) — a retrace is
         # labeled compile_step and kept out of the latency histogram
@@ -561,7 +530,7 @@ class ShardedTrainer:
         # modules that pin intermediate shardings on Auto axes (MoE's
         # all_to_all dispatch, nn/moe.py) can engage; everything else is
         # unaffected (all axes here are Auto outside the pipe shard_map).
-        with cm, jax.set_mesh(self.mesh):
+        with cm, region("train.step"), jax.set_mesh(self.mesh):
             state, stats = self._step_fn(state, batch, rng)
         # host-side anomaly accounting rides ONLY the telemetry path —
         # bool() forces a device sync (same tradeoff as train/trainer.py)
@@ -591,7 +560,7 @@ class ShardedTrainer:
         purpose — the lazily-built ``_step_fn`` may belong to a live
         training loop whose trace cache must not see audit avals."""
         donated = len(jax.tree.leaves(state))
-        fn = jax.jit(self._step, donate_argnums=(0,))
+        fn = self._jit_step()
         sharded_batch = jax.device_put(batch, self._batch_sh)
 
         def lower():

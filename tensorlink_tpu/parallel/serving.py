@@ -100,6 +100,7 @@ from tensorlink_tpu.runtime.compile_cache import (
     enable_compile_cache,
 )
 from tensorlink_tpu.runtime.metrics import DEFAULT_BUCKETS
+from tensorlink_tpu.runtime.tracing import event, region, scope
 
 __all__ = [
     "ContinuousBatchingEngine",
@@ -675,7 +676,7 @@ class ContinuousBatchingEngine:
             key = jax.random.fold_in(jax.random.key(seed), n)
             return sample_logits(logits_row, key, temperature, top_k, top_p)
 
-        def chunk(params, state):
+        def tl_decode(params, state):
             def step(state, _):
                 caches, valid = state["caches"], state["valid"]
                 live, tok = state["live"], state["tok"]
@@ -685,7 +686,8 @@ class ContinuousBatchingEngine:
                 # the fed token's cache slot becomes attendable for live
                 # rows; a retired row's index parks at its final value
                 # (its write is never validated, or dropped at capacity)
-                valid = valid.at[rows, index].max(live, mode="drop")
+                with scope("serve.cache_write"):
+                    valid = valid.at[rows, index].max(live, mode="drop")
                 logits, caches = model.apply(
                     params,
                     tok[:, None],
@@ -695,26 +697,29 @@ class ContinuousBatchingEngine:
                 )
                 # the module advanced EVERY row's index by 1; only live
                 # rows actually consumed a slot
-                new_index = index + live.astype(jnp.int32)
-                caches = _with_cache_index(caches, new_index)
-                new_n_valid = n_valid + live.astype(jnp.int32)
-                nxt = jax.vmap(sample_row)(
-                    state["seed"], new_n_valid, logits[:, -1]
-                ).astype(jnp.int32)
-                emit = jnp.where(live, nxt, fill)
-                remaining = remaining - live.astype(jnp.int32)
-                ended = remaining <= 0
-                if eos is not None:
-                    ended = ended | (nxt == eos)
-                new_state = {
-                    "caches": caches,
-                    "valid": valid,
-                    "n_valid": new_n_valid,
-                    "tok": jnp.where(live, nxt, tok),
-                    "seed": state["seed"],
-                    "remaining": remaining,
-                    "live": live & ~ended,
-                }
+                with scope("serve.cache_write"):
+                    new_index = index + live.astype(jnp.int32)
+                    caches = _with_cache_index(caches, new_index)
+                    new_n_valid = n_valid + live.astype(jnp.int32)
+                with scope("serve.sample"):
+                    nxt = jax.vmap(sample_row)(
+                        state["seed"], new_n_valid, logits[:, -1]
+                    ).astype(jnp.int32)
+                    emit = jnp.where(live, nxt, fill)
+                with scope("serve.cache_write"):
+                    remaining = remaining - live.astype(jnp.int32)
+                    ended = remaining <= 0
+                    if eos is not None:
+                        ended = ended | (nxt == eos)
+                    new_state = {
+                        "caches": caches,
+                        "valid": valid,
+                        "n_valid": new_n_valid,
+                        "tok": jnp.where(live, nxt, tok),
+                        "seed": state["seed"],
+                        "remaining": remaining,
+                        "live": live & ~ended,
+                    }
                 return new_state, emit
 
             state, toks = jax.lax.scan(step, state, None, length=K)
@@ -722,7 +727,7 @@ class ContinuousBatchingEngine:
 
         # donate the whole serving state: the KV cache updates in place
         # across chunk calls instead of being copied per dispatch
-        return jax.jit(chunk, donate_argnums=(1,))
+        return jax.jit(tl_decode, donate_argnums=(1,))
 
     # ----------------------------------------------------- speculative chunk
     def _spec_open_mask(self, state, f0):
@@ -803,27 +808,28 @@ class ContinuousBatchingEngine:
                 params, toks_in, caches=caches, positions=positions,
                 mask=open_mask,
             )
-            if dlg is None:
-                def vrow(lg, pr, s, n, kl):
-                    return spec_verify(
-                        lg, pr, spec.verify_key(s, n),
-                        temperature, top_k, top_p, k_live=kl,
-                    )
+            with scope("serve.sample"):
+                if dlg is None:
+                    def vrow(lg, pr, s, n, kl):
+                        return spec_verify(
+                            lg, pr, spec.verify_key(s, n),
+                            temperature, top_k, top_p, k_live=kl,
+                        )
 
-                n_raw, emitted = jax.vmap(vrow)(
-                    logits, props, seed, n_valid, k_live
-                )
-            else:
-                def vrow(lg, pr, dl, s, n, kl):
-                    return spec_verify(
-                        lg, pr, spec.verify_key(s, n),
-                        temperature, top_k, top_p, draft_logits=dl,
-                        k_live=kl,
+                    n_raw, emitted = jax.vmap(vrow)(
+                        logits, props, seed, n_valid, k_live
                     )
+                else:
+                    def vrow(lg, pr, dl, s, n, kl):
+                        return spec_verify(
+                            lg, pr, spec.verify_key(s, n),
+                            temperature, top_k, top_p, draft_logits=dl,
+                            k_live=kl,
+                        )
 
-                n_raw, emitted = jax.vmap(vrow)(
-                    logits, props, dlg, seed, n_valid, k_live
-                )
+                    n_raw, emitted = jax.vmap(vrow)(
+                        logits, props, dlg, seed, n_valid, k_live
+                    )
             idxk = jnp.arange(K + 1)
             # draft-quality truth BEFORE the EOS/budget clips below: a
             # clipped emission is the REQUEST ending, not the draft
@@ -851,35 +857,36 @@ class ContinuousBatchingEngine:
             tok_new = jnp.take_along_axis(
                 emitted, jnp.maximum(n_emit - 1, 0)[:, None], axis=1
             )[:, 0]
-            ar = jnp.arange(L)[None, :]
-            newly = (ar >= f0[:, None]) & (ar < (f0 + n_emit)[:, None])
-            nf = f0 + n_emit  # rolled-back frontier (rollback = reset)
-            new_state = {
-                **state,
-                "caches": _with_cache_index(caches, nf),
-                "valid": valid | newly,
-                "n_valid": n_valid + n_emit,
-                "tok": jnp.where(live, tok_new, tok),
-                "remaining": new_remaining,
-                "live": live & ~ended,
-            }
-            if draft_mode:
-                # draft frontier follows the target's exactly (the K+1
-                # draft steps covered every slot up to f0+K, so no hole)
-                new_state["draft"] = _with_cache_index(dcaches, nf)
-            else:
-                # bank the fed tokens for future prompt-lookups: slots
-                # [f0, f0+n_emit) now hold genuine sequence tokens;
-                # later slots hold rejected garbage past the frontier
-                rows = jnp.arange(S)[:, None]
-                new_state["ids"] = state["ids"].at[
-                    rows, f0[:, None] + idxk[None, :]
-                ].set(toks_in, mode="drop")
+            with scope("serve.cache_write"):
+                ar = jnp.arange(L)[None, :]
+                newly = (ar >= f0[:, None]) & (ar < (f0 + n_emit)[:, None])
+                nf = f0 + n_emit  # rolled-back frontier (rollback = reset)
+                new_state = {
+                    **state,
+                    "caches": _with_cache_index(caches, nf),
+                    "valid": valid | newly,
+                    "n_valid": n_valid + n_emit,
+                    "tok": jnp.where(live, tok_new, tok),
+                    "remaining": new_remaining,
+                    "live": live & ~ended,
+                }
+                if draft_mode:
+                    # draft frontier follows the target's exactly (the K+1
+                    # draft steps covered every slot up to f0+K, so no hole)
+                    new_state["draft"] = _with_cache_index(dcaches, nf)
+                else:
+                    # bank the fed tokens for future prompt-lookups: slots
+                    # [f0, f0+n_emit) now hold genuine sequence tokens;
+                    # later slots hold rejected garbage past the frontier
+                    rows = jnp.arange(S)[:, None]
+                    new_state["ids"] = state["ids"].at[
+                        rows, f0[:, None] + idxk[None, :]
+                    ].set(toks_in, mode="drop")
             return new_state, (
                 emitted.T, n_emit, n_acc.astype(jnp.int32), fb, n_prop,
             )
 
-        def chunk(params, dparams, state, k_eff):
+        def tl_spec_chunk(params, dparams, state, k_eff):
             # guard garbage input: the device contract below (emission
             # and block growth both bounded by k_eff + 1) only holds
             # inside [1, K]
@@ -890,7 +897,7 @@ class ContinuousBatchingEngine:
             )
             return (state, *out)
 
-        return self._jit_program(chunk)
+        return self._jit_program(tl_spec_chunk)
 
     def _spec_k_array(self) -> list[int]:
         """Per-slot effective K for the NEXT dispatched spec chunk:
@@ -939,10 +946,14 @@ class ContinuousBatchingEngine:
         and both prefill forms must never diverge on it."""
         if self.spec is not None and self.spec.mode == "draft":
             return jax.jit(fn, donate_argnums=(2,))
-        return jax.jit(
-            lambda params, state, *a: fn(params, None, state, *a),
-            donate_argnums=(1,),
-        )
+
+        def run(params, state, *a):
+            return fn(params, None, state, *a)
+
+        # the function's name is the program's in a device trace
+        # (jit_tl_prefill_chunk, ...): both forms carry fn's
+        run.__name__ = run.__qualname__ = fn.__name__
+        return jax.jit(run, donate_argnums=(1,))
 
     def _decode_program_name(self) -> str:
         return "spec_chunk" if self.spec is not None else "decode"
@@ -979,8 +990,8 @@ class ContinuousBatchingEngine:
         spec = self.spec
         draft_mode = spec is not None and spec.mode == "draft"
 
-        def prefill(params, dparams, state, ids, pad_mask, slot, seed,
-                    max_new):
+        def tl_prefill(params, dparams, state, ids, pad_mask, slot, seed,
+                       max_new):
             pos = jnp.maximum(jnp.cumsum(pad_mask, axis=-1) - 1, 0)
             nv = pad_mask.sum(-1)[0].astype(jnp.int32)
             small = model.init_caches(1, Tp, dtype=eng.cache_dtype)
@@ -993,10 +1004,11 @@ class ContinuousBatchingEngine:
             logits, small = model.apply(
                 params, ids, caches=small, positions=pos, mask=causal
             )
-            key0 = jax.random.fold_in(jax.random.key(seed), nv)
-            tok0 = sample_logits(
-                logits[0, -1], key0, temperature, top_k, top_p
-            ).astype(jnp.int32)
+            with scope("serve.sample"):
+                key0 = jax.random.fold_in(jax.random.key(seed), nv)
+                tok0 = sample_logits(
+                    logits[0, -1], key0, temperature, top_k, top_p
+                ).astype(jnp.int32)
             done0 = max_new <= 1
             if eos is not None:
                 done0 = done0 | (tok0 == eos)
@@ -1010,22 +1022,23 @@ class ContinuousBatchingEngine:
                     return big.at[slot].set(small_leaf.astype(big.dtype))
                 return big
 
-            caches = jax.tree.map(graft, state["caches"], small)
-            valid_row = jnp.zeros((L,), bool).at[:Tp].set(
-                pad_mask[0].astype(bool)
-            )
-            new_state = {
-                **state,
-                "caches": caches,
-                "valid": state["valid"].at[slot].set(valid_row),
-                "n_valid": state["n_valid"].at[slot].set(nv),
-                "tok": state["tok"].at[slot].set(tok0),
-                "seed": state["seed"].at[slot].set(seed),
-                "remaining": state["remaining"].at[slot].set(
-                    (max_new - 1).astype(jnp.int32)
-                ),
-                "live": state["live"].at[slot].set(~done0),
-            }
+            with scope("serve.cache_write"):
+                caches = jax.tree.map(graft, state["caches"], small)
+                valid_row = jnp.zeros((L,), bool).at[:Tp].set(
+                    pad_mask[0].astype(bool)
+                )
+                new_state = {
+                    **state,
+                    "caches": caches,
+                    "valid": state["valid"].at[slot].set(valid_row),
+                    "n_valid": state["n_valid"].at[slot].set(nv),
+                    "tok": state["tok"].at[slot].set(tok0),
+                    "seed": state["seed"].at[slot].set(seed),
+                    "remaining": state["remaining"].at[slot].set(
+                        (max_new - 1).astype(jnp.int32)
+                    ),
+                    "live": state["live"].at[slot].set(~done0),
+                }
             if draft_mode:
                 # the draft's own prompt pass: identical slot layout, so
                 # the same graft lands it beside the target's cache
@@ -1047,7 +1060,7 @@ class ContinuousBatchingEngine:
                 )
             return new_state, tok0
 
-        return self._jit_program(prefill)
+        return self._jit_program(tl_prefill)
 
     def _get_prefill(self, Tp: int):
         """Compiled prefill program for bucket ``Tp`` from the bounded
@@ -1573,16 +1586,18 @@ class ContinuousBatchingEngine:
             jnp.asarray(pm), jnp.int32(slot), jnp.uint32(req.seed),
             jnp.int32(req.max_new),
         )
-        req.admitted_at = time.perf_counter()
-        try:
-            self._state, tok0 = fn(*args)
-        except (TypeError, ValueError):
-            # an AOT executable is stricter than jit about input
-            # shardings/avals; if a jax-version quirk rejects the call
-            # (argument checking happens before the donated state is
-            # consumed), fall back to the plain jit path for this bucket
-            fn = self._prefill_jit[Tp] = self._build_prefill(Tp)
-            self._state, tok0 = fn(*args)
+        self._note_admitted(req)
+        with region("serve.prefill_dispatch"):
+            try:
+                self._state, tok0 = fn(*args)
+            except (TypeError, ValueError):
+                # an AOT executable is stricter than jit about input
+                # shardings/avals; if a jax-version quirk rejects the
+                # call (argument checking happens before the donated
+                # state is consumed), fall back to the plain jit path
+                # for this bucket
+                fn = self._prefill_jit[Tp] = self._build_prefill(Tp)
+                self._state, tok0 = fn(*args)
         # admission IS the prefill dispatch on this engine (the paged
         # engine stamps these apart, chunked prefill runs later steps)
         req.prefill_started_at = time.perf_counter()
@@ -1593,6 +1608,17 @@ class ContinuousBatchingEngine:
             if self.metering:
                 req.disp_hist.append(req.disp)
         self._event("serving.admit", rid=req.rid, slot=slot, padded=Tp)
+
+    def _note_admitted(self, req: _Request) -> None:
+        """Stamp the slot grant; the first one (a preempted request
+        gets another) is the ``tl.serve.admitted`` event of a capture."""
+        first = req.admitted_at is None
+        req.admitted_at = time.perf_counter()
+        if first:
+            event(
+                "serve.admitted", rid=req.rid,
+                waited_ms=(req.admitted_at - req.submitted_at) * 1e3,
+            )
 
     def _maybe_record_ttft(self, req: _Request) -> None:
         if req.first_token_at is not None or req.first_token is None:
@@ -2030,6 +2056,15 @@ class ContinuousBatchingEngine:
     def _pending_slots(self):
         return ()  # paged: the slots still mid-chunked-prefill
 
+    def _note_first_token(self, req: _Request) -> None:
+        """``tl.serve.first_token``: the host holds the request's first
+        token as an int (a resumed request's re-prefill is not one)."""
+        if not req.tokens and req.failed is None:
+            event(
+                "serve.first_token", rid=req.rid,
+                ttft_ms=(time.perf_counter() - req.submitted_at) * 1e3,
+            )
+
     def _take_first(self, req: _Request) -> None:
         """Fold the prefill's first token into the stream (syncs a
         long-since-computed scalar). TTFT is recorded here at the
@@ -2040,6 +2075,7 @@ class ContinuousBatchingEngine:
         ``req.tokens`` may legitimately be non-empty here.)"""
         if req.first_token is not None:
             t0 = int(np.asarray(req.first_token))
+            self._note_first_token(req)
             if req.disp is not None and self._timer is not None:
                 self._timer.drained(req.disp)  # prefill synced here
             req.disp = None
@@ -2056,13 +2092,17 @@ class ContinuousBatchingEngine:
         slots, dispatch one decode chunk, sync the oldest chunk once
         ``pipeline_depth`` are in flight. Returns False when fully idle
         (nothing queued, running, or in flight)."""
-        with self._lock:
+        with self._lock, region("serve.step"):
             self._maybe_self_heal()
-            self._expire_deadlines_locked()
-            self._admit_waiting()
+            with region("serve.admit"):
+                # (admission IS the prefill dispatch on this engine:
+                # serve.prefill_dispatch nests inside)
+                self._expire_deadlines_locked()
+                self._admit_waiting()
             busy = any(r is not None for r in self._slot_req)
             if busy:
-                payload, disp = self._dispatch_decode()
+                with region("serve.decode_dispatch"):
+                    payload, disp = self._dispatch_decode()
                 self._inflight.append((payload, tuple(self._slot_req), disp))
             for r in self._slot_req:
                 if r is not None:
@@ -2071,8 +2111,11 @@ class ContinuousBatchingEngine:
                 # opportunistic ready stamping: one is_ready per pending
                 # FIFO head per step — the attribution granularity
                 self._timer.poll()
-            while len(self._inflight) > (self.pipeline_depth if busy else 0):
-                self._drain_one()
+            with region("serve.drain"):
+                while len(self._inflight) > (
+                    self.pipeline_depth if busy else 0
+                ):
+                    self._drain_one()
             if not busy:
                 self._maybe_self_heal()  # just drained fully idle
             return bool(
@@ -2484,8 +2527,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         spec = self.spec
         draft_mode = spec is not None and spec.mode == "draft"
 
-        def chunk(params, dparams, state, ids, slot, start, nreal, seed,
-                  max_new, is_final):
+        def tl_prefill_chunk(params, dparams, state, ids, slot, start,
+                             nreal, seed, max_new, is_final):
             caches = state["caches"]
             # pool arrays (k/v and any int8 scale siblings) pass through
             # by key; only index/block_table take the 1-row slot view
@@ -2517,27 +2560,29 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             last = jax.lax.dynamic_index_in_dim(
                 logits[0], nreal - 1, axis=0, keepdims=False
             )
-            key0 = jax.random.fold_in(jax.random.key(seed), n_end)
-            tok0 = sample_logits(
-                last, key0, temperature, top_k, top_p
-            ).astype(jnp.int32)
+            with scope("serve.sample"):
+                key0 = jax.random.fold_in(jax.random.key(seed), n_end)
+                tok0 = sample_logits(
+                    last, key0, temperature, top_k, top_p
+                ).astype(jnp.int32)
             done0 = max_new <= 1
             if eos is not None:
                 done0 = done0 | (tok0 == eos)
-            new_state = {
-                **state,
-                "caches": new_caches,
-                "valid": state["valid"].at[slot].set(
-                    jnp.arange(L) < n_end
-                ),
-                "n_valid": state["n_valid"].at[slot].set(n_end),
-                "tok": state["tok"].at[slot].set(tok0),
-                "seed": state["seed"].at[slot].set(seed),
-                "remaining": state["remaining"].at[slot].set(
-                    jnp.where(is_final, max_new - 1, 0)
-                ),
-                "live": state["live"].at[slot].set(is_final & ~done0),
-            }
+            with scope("serve.cache_write"):
+                new_state = {
+                    **state,
+                    "caches": new_caches,
+                    "valid": state["valid"].at[slot].set(
+                        jnp.arange(L) < n_end
+                    ),
+                    "n_valid": state["n_valid"].at[slot].set(n_end),
+                    "tok": state["tok"].at[slot].set(tok0),
+                    "seed": state["seed"].at[slot].set(seed),
+                    "remaining": state["remaining"].at[slot].set(
+                        jnp.where(is_final, max_new - 1, 0)
+                    ),
+                    "live": state["live"].at[slot].set(is_final & ~done0),
+                }
             if draft_mode:
                 # the draft prefills the same chunk through its
                 # CONTIGUOUS per-slot cache: a 1-row scalar-index slice,
@@ -2588,7 +2633,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 )
             return new_state, tok0
 
-        return self._jit_program(chunk)
+        return self._jit_program(tl_prefill_chunk)
 
     def _map_caches(self, state, fn):
         return {
@@ -2604,7 +2649,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         first position the new request will write (its old parked index
         could otherwise alias a SHARED block through the new table)."""
 
-        def run(state, slot, row, start, set_start):
+        def tl_pool_table(state, slot, row, start, set_start):
             def upd(c):
                 idx = jnp.where(set_start, start, c["index"][slot])
                 return {
@@ -2615,7 +2660,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
             return self._map_caches(state, upd)
 
-        return jax.jit(run, donate_argnums=(0,))
+        return jax.jit(tl_pool_table, donate_argnums=(0,))
 
     def _build_retire_op(self):
         """Kill a slot on device: live off, valid row cleared, block
@@ -2623,7 +2668,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         instead of landing in a block about to be remapped."""
         NB, L = self.pool.num_blocks, self.L
 
-        def run(state, slot):
+        def tl_pool_retire(state, slot):
             state = self._map_caches(
                 state,
                 lambda c: {
@@ -2641,7 +2686,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 ),
             }
 
-        return jax.jit(run, donate_argnums=(0,))
+        return jax.jit(tl_pool_retire, donate_argnums=(0,))
 
     def _build_copy_op(self):
         """Copy-on-write: duplicate block ``src`` into ``dst`` across
@@ -2650,7 +2695,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         ``dst``)."""
         keys = self._pool_keys
 
-        def run(state, src, dst):
+        def tl_pool_copy(state, src, dst):
             return self._map_caches(
                 state,
                 lambda c: {
@@ -2662,7 +2707,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 },
             )
 
-        return jax.jit(run, donate_argnums=(0,))
+        return jax.jit(tl_pool_copy, donate_argnums=(0,))
 
     # ------------------------------------------- disaggregated serving
     # Prefill/decode disaggregation across the mesh (ROADMAP item 1):
@@ -2685,7 +2730,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         so one shape-static program serves any block count."""
         keys = self._pool_keys
 
-        def run(state, blocks, bids):
+        def tl_pool_graft(state, blocks, bids):
             def upd(c, bl):
                 return {
                     **c,
@@ -2705,7 +2750,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 ],
             }
 
-        return jax.jit(run, donate_argnums=(0,))
+        return jax.jit(tl_pool_graft, donate_argnums=(0,))
 
     def _build_adopt_op(self):
         """Adopt an imported prefill into a slot's scalar row state —
@@ -2717,7 +2762,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         spec = self.spec
         ngram = spec is not None and spec.mode == "ngram"
 
-        def run(state, slot, nv, tok, seed, remaining, live, ids_row):
+        def tl_pool_adopt(state, slot, nv, tok, seed, remaining, live, ids_row):
             out = {
                 **state,
                 "valid": state["valid"].at[slot].set(jnp.arange(L) < nv),
@@ -2734,7 +2779,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 out["ids"] = state["ids"].at[slot].set(ids_row)
             return out
 
-        return jax.jit(run, donate_argnums=(0,))
+        return jax.jit(tl_pool_adopt, donate_argnums=(0,))
 
     def _disagg_guard(self) -> None:
         with self._lock:  # a self-heal may swap self.spec
@@ -2874,6 +2919,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 f"{len(bids)} (internal accounting bug)"
             )
         tok0 = int(np.asarray(req.first_token))
+        self._note_first_token(req)
         if req.disp is not None and self._timer is not None:
             self._timer.drained(req.disp)
             req.disp = None
@@ -3418,7 +3464,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             return False
         slot = self._free.pop()
         req.slot = slot
-        req.admitted_at = time.perf_counter()
+        self._note_admitted(req)
         self._slot_req[slot] = req
         self._slot_blocks[slot] = (
             hits + ([tail_bid] if tail is not None else []) + new_blocks
@@ -3682,11 +3728,13 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     def step(self) -> bool:
         """One scheduler iteration: admit, dispatch at most one prefill
         chunk, grow block tables, dispatch one decode chunk, drain."""
-        with self._lock:
+        with self._lock, region("serve.step"):
             self._maybe_self_heal()
-            self._expire_deadlines_locked()
-            self._admit_waiting()
-            prefilling = self._dispatch_prefill_chunk()
+            with region("serve.admit"):
+                self._expire_deadlines_locked()
+                self._admit_waiting()
+            with region("serve.prefill_dispatch"):
+                prefilling = self._dispatch_prefill_chunk()
             decoding = [
                 s for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._pending and not r.hold
@@ -3698,10 +3746,12 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 # drain could widen the device's bound past the blocks
                 # just grown
                 self._k_dispatch = self._spec_k_array()
+            with region("serve.grow_blocks"):
+                if decoding:
+                    decoding = self._grow_blocks(decoding)
             if decoding:
-                decoding = self._grow_blocks(decoding)
-            if decoding:
-                payload, disp = self._dispatch_decode()
+                with region("serve.decode_dispatch"):
+                    payload, disp = self._dispatch_decode()
                 live = set(decoding)
                 # mid-prefill slots are NOT live on device: their rows
                 # emit fill tokens that must never reach a request
@@ -3719,8 +3769,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             # step whose controller has moved on
             self._k_dispatch = None
             busy = bool(decoding or prefilling)
-            while len(self._inflight) > (self.pipeline_depth if busy else 0):
-                self._drain_one()
+            with region("serve.drain"):
+                while len(self._inflight) > (
+                    self.pipeline_depth if busy else 0
+                ):
+                    self._drain_one()
             if not busy:
                 self._maybe_self_heal()  # just drained fully idle
             self.peak_blocks_in_use = max(

@@ -10,7 +10,6 @@ from tensorlink_tpu.runtime.mesh import MeshRuntime, make_mesh  # noqa: F401
 from tensorlink_tpu.runtime.metrics import (  # noqa: F401
     Histogram,
     Metrics,
-    StepTimer,
 )
 from tensorlink_tpu.runtime.tracing import (  # noqa: F401
     Span,

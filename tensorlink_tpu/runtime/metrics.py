@@ -19,37 +19,6 @@ from dataclasses import dataclass, field
 from typing import Any, Deque
 
 
-class StepTimer:
-    """Wall-clock step timer with warmup discard."""
-
-    def __init__(self, warmup: int = 2):
-        self.warmup = warmup
-        self.times: list[float] = []
-        self._t0: float | None = None
-        self._steps = 0
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        self._steps += 1
-        if self._steps > self.warmup:
-            self.times.append(dt)
-
-    @property
-    def mean_s(self) -> float:
-        return sum(self.times) / len(self.times) if self.times else math.nan
-
-    @property
-    def p50_s(self) -> float:
-        if not self.times:
-            return math.nan
-        s = sorted(self.times)
-        return s[len(s) // 2]
-
-
 def pipeline_bubble_fraction(num_stages: int, num_micro: int) -> float:
     """Ideal GPipe bubble fraction (S-1)/(M+S-1).
 

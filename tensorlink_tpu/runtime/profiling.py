@@ -1,5 +1,5 @@
-"""Profiling: jax.profiler traces, per-step timing, and the always-on
-device-time telemetry layer.
+"""Profiling: jax.profiler traces and the always-on device-time
+telemetry layer.
 
 The reference's only timing instrumentation is a PING/PONG latency probe
 (src/p2p/smart_node.py:889-892); there is no tracer of any kind (survey
@@ -41,13 +41,6 @@ def trace(log_dir: str = "/tmp/tensorlink_tpu_trace") -> Iterator[str]:
         yield log_dir
 
 
-@contextlib.contextmanager
-def step_trace(name: str) -> Iterator[None]:
-    """Named sub-span inside an active trace (shows up on the timeline)."""
-    with jax.profiler.StepTraceAnnotation(name):
-        yield
-
-
 def roofline(
     *,
     flops_per_step: float,
@@ -85,27 +78,6 @@ def roofline(
         out["measured_step_s"] = measured_step_s
         out["fraction_of_binding_floor"] = floor / measured_step_s
     return out
-
-
-class Stopwatch:
-    """Synchronized device timing: forces a host read of `arr` before
-    stopping the clock. JAX returns before the device finishes, so a
-    timed region has to end in a wait: a scalar host read is one, as
-    `block_until_ready` is."""
-
-    def __init__(self):
-        self.t0 = None
-        self.elapsed_s = 0.0
-
-    def start(self) -> "Stopwatch":
-        self.t0 = time.perf_counter()
-        return self
-
-    def stop(self, sync_array=None) -> float:
-        if sync_array is not None:
-            float(jax.tree.leaves(sync_array)[0].reshape(-1)[0])
-        self.elapsed_s = time.perf_counter() - self.t0
-        return self.elapsed_s
 
 
 def parse_op_breakdown(trace_events: list, lane: str = "XLA Ops") -> dict:
@@ -507,7 +479,7 @@ def measure_capability(
     True``) and a fresh measurement is merge-saved so restarts skip it.
 
     Each timed region ends in a scalar host read, which waits for the
-    device as ``block_until_ready`` does (same as :class:`Stopwatch`)."""
+    device as ``block_until_ready`` does."""
     from tensorlink_tpu.runtime.compile_cache import runtime_fingerprint
 
     rt = runtime_fingerprint()

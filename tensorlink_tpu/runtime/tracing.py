@@ -19,6 +19,15 @@ ends so spans from different nodes land on one shared timeline (skew is
 whatever NTP leaves, microseconds on a LAN — fine for ms-scale RPCs);
 durations subtract the same clock, so a span is internally consistent
 even if the host steps its clock between traces.
+
+Every span also goes to the profiler: :func:`region` (which
+``Tracer.span`` is built on) enters a ``jax.profiler.TraceAnnotation``
+named ``tl.<name>``, so inside a ``jax.profiler`` capture the program's
+spans lie on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the
+device's lines, on one clock. :func:`scope` is the device-side twin (a
+``jax.named_scope`` in every instruction's op path) and
+the jitted functions are called ``tl_<what>``, which is the name their
+launches carry. The names are one closed table, :data:`VOCABULARY`.
 """
 
 from __future__ import annotations
@@ -101,6 +110,136 @@ def current_trace_context() -> dict[str, str] | None:
     return None if s is None else s.context()
 
 
+# ------------------------------------------------------ the tl. vocabulary
+# Every name the program writes into a profiler capture, with the layer
+# it belongs to and what it bounds. Kinds: "span" (host interval,
+# region()), "event" (host instant, event()), "scope" (a component of
+# every enclosed device instruction's op path, scope()), "program" (a
+# jitted function: its launches read ``jit_tl_<name>`` on the device's
+# ``XLA Modules`` line; the function handed to ``jax.jit`` is simply
+# called ``tl_<name>``). Host names appear as ``tl.<name>``.
+VOCABULARY: dict[str, tuple[str, str, str]] = {
+    # -- host: the two hot loops
+    "train.step": ("span", "train/trainer.py", "one call of the jitted train step (Trainer, ShardedTrainer): host dispatch, returns before the device ends"),
+    "serve.step": ("span", "parallel/serving.py", "one scheduler turn of either engine, under its lock"),
+    "serve.admit": ("span", "parallel/serving.py", "deadline expiry and admission of waiting requests into free slots"),
+    "serve.prefill_dispatch": ("span", "parallel/serving.py", "host side of one prefill (chunk) launch"),
+    "serve.grow_blocks": ("span", "parallel/serving.py", "block-table growth ahead of the decode frontier (paged engine)"),
+    "serve.decode_dispatch": ("span", "parallel/serving.py", "host side of one decode / spec chunk launch"),
+    "serve.drain": ("span", "parallel/serving.py", "the host's wait for the oldest chunks in flight: the device's time, not the host's"),
+    "serve.admitted": ("event", "parallel/serving.py", "a request got its slot: rid, waited_ms (submit to slot)"),
+    "serve.first_token": ("event", "parallel/serving.py", "the host holds a request's first token as an int: rid, ttft_ms (submit to token)"),
+    # -- device: scopes inside the programs
+    "embed": ("scope", "model embedding", "token/position lookup and embedding dropout"),
+    "attn": ("scope", "nn/attention.py", "a block's attention half: its norm, projections, attention, residual"),
+    "mlp": ("scope", "nn/transformer.py", "a block's feed-forward half (dense or MoE): its norm, matmuls, residual"),
+    "head": ("scope", "model head", "final norm and the unembedding matmul"),
+    "loss": ("scope", "train/trainer.py", "the loss from logits"),
+    "train.cast": ("scope", "train/trainer.py", "the dtype policy: master weights to the compute dtype, and the gradients' way back"),
+    "train.accumulate": ("scope", "train/trainer.py", "gradient accumulation over micro-batches: the zero tree and acc + g/micro"),
+    "train.sentinel": ("scope", "train/trainer.py", "the non-finite check over loss and gradients"),
+    "train.clip": ("scope", "train/optim.py", "global-norm clipping"),
+    "train.optimizer": ("scope", "train/optim.py", "optimizer update, its application, and the skip-on-non-finite select"),
+    "serve.sample": ("scope", "parallel/serving.py", "sampling from the last logits"),
+    "serve.cache_write": ("scope", "parallel/serving.py", "KV written back into the engine's state: graft of a prefilled cache, index and validity bookkeeping"),
+    # -- device: programs
+    "train_step": ("program", "train/trainer.py", "Trainer's step"),
+    "sharded_train_step": ("program", "parallel/engine.py", "ShardedTrainer's step"),
+    "decode": ("program", "parallel/serving.py", "decode chunk of either engine"),
+    "spec_chunk": ("program", "parallel/serving.py", "speculative decode chunk"),
+    "prefill": ("program", "parallel/serving.py", "the contiguous engine's whole-prompt prefill"),
+    "prefill_chunk": ("program", "parallel/serving.py", "the paged engine's prefill chunk"),
+    "pool_table": ("program", "parallel/serving.py", "point a slot's block-table row"),
+    "pool_retire": ("program", "parallel/serving.py", "kill a slot on device"),
+    "pool_copy": ("program", "parallel/serving.py", "copy-on-write of one block"),
+    "pool_graft": ("program", "parallel/serving.py", "scatter imported blocks into the pools"),
+    "pool_adopt": ("program", "parallel/serving.py", "adopt an imported prefill into a slot"),
+}
+# What the table closes: the names the two hot loops and the device
+# programs write. A span opened through ``Tracer.span`` (RPC dispatch
+# ``rpc.<mtype>``, ``stage<i>.*``, StepTelemetry's ``trainer.step``,
+# the per-request ``serving.*`` legs) reaches a capture under the name
+# its call site gives it, and is outside the table: ``mtype`` comes
+# from the wire, and no reader of a capture looks for those.
+PREFIX = "tl."
+
+
+def known(name: str) -> bool:
+    """Whether ``name`` (with or without ``tl.``) is in the table."""
+    return name.removeprefix(PREFIX) in VOCABULARY
+
+
+# jax.profiler.TraceAnnotation, looked up on first use so the module
+# stays importable without jax; tests put a recording stand-in here
+_annotate: Callable | None = None
+
+
+def _annotation(name: str, attrs: dict):
+    global _annotate
+    if _annotate is None:
+        import jax
+
+        _annotate = jax.profiler.TraceAnnotation
+    return _annotate(PREFIX + name, **attrs)
+
+
+class region:
+    """The one host span: always a ``TraceAnnotation`` ``tl.<name>``
+    (next to free while no profile is being taken; keep ``attrs`` to
+    numbers and short strings), and a recorded :class:`Span` too when a
+    ``tracer`` is given. The hot loops give none: their phases are for
+    a capture, and a node's span buffer is for per-request and RPC
+    spans. ``with region(...) as s`` gives that span, or None."""
+
+    __slots__ = ("_name", "_tracer", "_remote", "_attrs", "_ann", "_span",
+                 "_token")
+
+    def __init__(
+        self, name: str, tracer: "Tracer | None" = None, /, *,
+        remote: dict | None = None, **attrs,
+    ):
+        self._name, self._tracer = name, tracer
+        self._remote, self._attrs = remote, attrs
+        self._span = None
+
+    def __enter__(self) -> "Span | None":
+        self._ann = _annotation(self._name, self._attrs)
+        self._ann.__enter__()
+        if self._tracer is None:
+            return None
+        s = self._span = self._tracer.start_span(
+            self._name, self._attrs, self._remote
+        )
+        self._token = _current_span.set(s)
+        return s
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        s = self._span
+        if s is not None:
+            if exc_type is not None:
+                s.status = "error"
+                s.attrs.setdefault("error", exc_type.__name__)
+            _current_span.reset(self._token)
+            self._tracer.finish_span(s, s.status)
+        self._ann.__exit__(exc_type, exc, tb)
+
+
+def event(name: str, /, **attrs) -> None:
+    """An instant on the host's line of a capture, carrying numbers the
+    caller already has. Nothing is recorded outside a capture."""
+    with _annotation(name, attrs):
+        pass
+
+
+def scope(name: str):
+    """``jax.named_scope("tl.<name>")``: every instruction traced
+    inside carries it in its op path, backward ones inside
+    ``transpose(jvp(...))``. Metadata only: no instruction changes."""
+    import jax
+
+    return jax.named_scope(PREFIX + name)
+
+
 class Tracer:
     """Per-node span recorder with a bounded buffer (oldest evicted).
 
@@ -152,26 +291,15 @@ class Tracer:
             start_ns=time.time_ns(),
         )
 
-    @contextlib.contextmanager
     def span(
         self,
         name: str,
         attrs: dict | None = None,
         remote: dict | None = None,
-    ) -> Iterator[Span]:
-        s = self.start_span(name, attrs, remote)
-        token = _current_span.set(s)
-        try:
-            yield s
-        except BaseException as e:
-            s.status = "error"
-            s.attrs.setdefault("error", type(e).__name__)
-            raise
-        finally:
-            _current_span.reset(token)
-            s.end_ns = time.time_ns()
-            with self._lock:
-                self._spans.append(s)
+    ):
+        """A recorded span that is also a profiler annotation: see
+        :func:`region`."""
+        return region(name, self, remote=remote, **(attrs or {}))
 
     def trace(
         self, name: str | None = None, attrs: dict | None = None
@@ -358,16 +486,11 @@ class StepTelemetry:
         key = self.shape_key(batch, rng)
         first = key not in self._seen
         self._seen.add(key)
-        cm = (
-            self.tracer.span(
-                f"{self.prefix}.compile_step" if first else f"{self.prefix}.step",
-                self.attrs,
-            )
-            if self.tracer is not None
-            else contextlib.nullcontext()
-        )
         t0 = time.perf_counter()
-        with cm:
+        with region(
+            f"{self.prefix}.compile_step" if first else f"{self.prefix}.step",
+            self.tracer, **self.attrs,
+        ):
             yield
         if self.metrics is not None:
             dt = time.perf_counter() - t0
@@ -381,12 +504,7 @@ class StepTelemetry:
         """Wrap the batch fetch: ``{prefix}.data`` span + ``data_s``
         series, so input-pipeline stalls show on the step timeline."""
         t0 = time.perf_counter()
-        cm = (
-            self.tracer.span(f"{self.prefix}.data")
-            if self.tracer is not None
-            else contextlib.nullcontext()
-        )
-        with cm:
+        with region(f"{self.prefix}.data", self.tracer):
             yield
         if self.metrics is not None:
             self.metrics.observe("data_s", time.perf_counter() - t0)

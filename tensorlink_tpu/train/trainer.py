@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from tensorlink_tpu.config import TrainConfig
 from tensorlink_tpu.nn.module import Module
+from tensorlink_tpu.runtime.tracing import region, scope
 from tensorlink_tpu.train.optim import (
     Optimizer,
     apply_updates,
@@ -29,14 +30,16 @@ from tensorlink_tpu.train.optim import (
 
 def softmax_cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """Mean CE; labels are int ids. Computed in f32."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - ll)
+    with scope("loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        return jnp.mean(logz - ll)
 
 
 def mse_loss(pred: jax.Array, target: jax.Array) -> jax.Array:
-    return jnp.mean(jnp.square(pred.astype(jnp.float32) - target.astype(jnp.float32)))
+    with scope("loss"):
+        return jnp.mean(jnp.square(pred.astype(jnp.float32) - target.astype(jnp.float32)))
 
 
 @jax.tree_util.register_dataclass
@@ -53,6 +56,56 @@ class TrainState:
             opt_state=optimizer.init(params),
             step=jnp.zeros((), jnp.int32),
         )
+
+
+def finish_step(cfg: TrainConfig, optimizer: Optimizer, state, loss, grads):
+    """What every train step does with its loss and gradients, traced
+    inside the step program (Trainer and ShardedTrainer alike): the
+    non-finite sentinel, clipping, the optimizer, and the new state."""
+    # non-finite sentinel, in-jit and BEFORE clipping (clipping a
+    # tree with an inf leaf turns the norm nan and poisons every
+    # grad — the flag must name the raw anomaly): one all-reduce
+    # over grad leaves + the loss scalar, no host sync here
+    with scope("train.sentinel"):
+        grads_finite = jax.tree_util.tree_reduce(
+            lambda a, g: a & jnp.isfinite(g).all(),
+            grads,
+            jnp.array(True),
+        )
+        nonfinite = ~(jnp.isfinite(loss) & grads_finite)
+    if cfg.grad_clip_norm:
+        with scope("train.clip"):
+            grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip_norm)
+    else:
+        gnorm = jnp.zeros(())
+    with scope("train.optimizer"):
+        updates, opt_state = optimizer.update(
+            grads, state.opt_state, state.params, state.step
+        )
+        if cfg.train_only == "lora":
+            # AdamW's decoupled weight decay would otherwise shrink
+            # frozen weights with zero grad
+            from tensorlink_tpu.nn.lora import mask_to_lora
+
+            updates = mask_to_lora(updates)
+        params = apply_updates(state.params, updates)
+        new_state = TrainState(
+            params=params, opt_state=opt_state, step=state.step + 1
+        )
+        if cfg.skip_nonfinite_updates:
+            # select the OLD state wholesale (params, moments, step): a
+            # poisoned batch must leave no trace in the model — not even
+            # an optimizer-moment update or a schedule tick
+            new_state = jax.tree.map(
+                lambda new, old: jnp.where(nonfinite, old, new),
+                new_state,
+                state,
+            )
+    return new_state, {
+        "loss": loss,
+        "grad_norm": gnorm,
+        "nonfinite": nonfinite,
+    }
 
 
 class Trainer:
@@ -124,8 +177,14 @@ class Trainer:
         )
         self.compute_dtype = jnp.dtype(cfg.dtype)
         self.donate = bool(donate)
+
+        # the function's name is the program's: launches read
+        # jit_tl_train_step in a device trace
+        def tl_train_step(state, batch, rng):
+            return self._step(state, batch, rng)
+
         self._train_step = jax.jit(
-            self._step, donate_argnums=(0,) if donate else ()
+            tl_train_step, donate_argnums=(0,) if donate else ()
         )
         self._eval_step = jax.jit(self._eval)
 
@@ -136,12 +195,13 @@ class Trainer:
 
     # -- inner step (traced) --------------------------------------------
     def _loss_for_grad(self, params, batch, rng):
-        cast = jax.tree.map(
-            lambda x: x.astype(self.compute_dtype)
-            if jnp.issubdtype(x.dtype, jnp.floating)
-            else x,
-            params,
-        )
+        with scope("train.cast"):
+            cast = jax.tree.map(
+                lambda x: x.astype(self.compute_dtype)
+                if jnp.issubdtype(x.dtype, jnp.floating)
+                else x,
+                params,
+            )
         return self.loss_fn(self.module, cast, batch, rng)
 
     def _step(self, state: TrainState, batch, rng):
@@ -159,18 +219,21 @@ class Trainer:
                 )
 
             mb = micro_batches(batch)
-            zero = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), state.params
-            )
+            with scope("train.accumulate"):
+                zero = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), state.params
+                )
 
             def body(acc, xs):
                 mb_i, r = xs
                 loss_i, g = jax.value_and_grad(self._loss_for_grad)(
                     state.params, mb_i, r
                 )
-                acc = jax.tree.map(
-                    lambda a, gi: a + gi.astype(jnp.float32) / micro, acc, g
-                )
+                with scope("train.accumulate"):
+                    acc = jax.tree.map(
+                        lambda a, gi: a + gi.astype(jnp.float32) / micro,
+                        acc, g,
+                    )
                 return acc, loss_i
 
             rngs = jax.random.split(rng, micro)
@@ -179,49 +242,12 @@ class Trainer:
 
         if self.cfg.train_only == "lora":
             # mask GRADS before clipping/optimizer (frozen params must
-            # not pollute the clip norm or accumulate moments) AND the
-            # final updates (AdamW's decoupled weight decay would
-            # otherwise shrink frozen weights with zero grad)
+            # not pollute the clip norm or accumulate moments);
+            # finish_step masks the final updates too
             from tensorlink_tpu.nn.lora import mask_to_lora
 
             grads = mask_to_lora(grads)
-        # non-finite sentinel, in-jit and BEFORE clipping (clipping a
-        # tree with an inf leaf turns the norm nan and poisons every
-        # grad — the flag must name the raw anomaly): one all-reduce
-        # over grad leaves + the loss scalar, no host sync here
-        grads_finite = jax.tree_util.tree_reduce(
-            lambda a, g: a & jnp.isfinite(g).all(),
-            grads,
-            jnp.array(True),
-        )
-        nonfinite = ~(jnp.isfinite(loss) & grads_finite)
-        if self.cfg.grad_clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, self.cfg.grad_clip_norm)
-        else:
-            gnorm = jnp.zeros(())
-        updates, opt_state = self.optimizer.update(
-            grads, state.opt_state, state.params, state.step
-        )
-        if self.cfg.train_only == "lora":
-            from tensorlink_tpu.nn.lora import mask_to_lora
-
-            updates = mask_to_lora(updates)
-        params = apply_updates(state.params, updates)
-        new_state = TrainState(params=params, opt_state=opt_state, step=state.step + 1)
-        if self.cfg.skip_nonfinite_updates:
-            # select the OLD state wholesale (params, moments, step): a
-            # poisoned batch must leave no trace in the model — not even
-            # an optimizer-moment update or a schedule tick
-            new_state = jax.tree.map(
-                lambda new, old: jnp.where(nonfinite, old, new),
-                new_state,
-                state,
-            )
-        return new_state, {
-            "loss": loss,
-            "grad_norm": gnorm,
-            "nonfinite": nonfinite,
-        }
+        return finish_step(self.cfg, self.optimizer, state, loss, grads)
 
     def _eval(self, params, batch, rng):
         return self._loss_for_grad(params, batch, rng)
@@ -258,14 +284,15 @@ class Trainer:
 
     def train_step(self, state: TrainState, batch, rng):
         if self._telemetry is None:
-            return self._train_step(state, batch, rng)
+            with region("train.step"):
+                return self._train_step(state, batch, rng)
         # skip device timing on a compile call (StepTelemetry's cache
         # key): charging XLA compile as device-busy would poison the
         # EWMAs for the whole run
         time_this = self._timer is not None and self._telemetry.seen(
             batch, rng
         )
-        with self._telemetry.step(batch, rng):
+        with self._telemetry.step(batch, rng), region("train.step"):
             state, stats = self._train_step(state, batch, rng)
         disp = (
             self._timer.dispatch("train_step", stats.get("loss"))

@@ -9,7 +9,6 @@ from tensorlink_tpu.config import FrameworkConfig, MeshConfig, TrainConfig
 from tensorlink_tpu.runtime.mesh import MeshRuntime, make_mesh, local_device_info
 from tensorlink_tpu.runtime.metrics import (
     Metrics,
-    StepTimer,
     pipeline_bubble_fraction,
     throughput,
 )
@@ -64,14 +63,6 @@ def test_metrics_snapshot():
     assert snap["counters"]["steps"] == 5
     assert snap["loss"]["n"] == 5
     assert throughput(100, 2.0, 4) == 12.5
-
-
-def test_step_timer():
-    t = StepTimer(warmup=1)
-    for _ in range(3):
-        with t:
-            pass
-    assert len(t.times) == 2 and t.mean_s >= 0
 
 
 def test_parse_op_breakdown_synthetic():
