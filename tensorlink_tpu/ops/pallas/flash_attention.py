@@ -23,16 +23,28 @@ Grouped-query attention (Hkv < H) is handled by the BlockSpec index maps
 traffic or residual memory. dk/dv come back at H heads and are summed
 over each group by the caller (one cheap transient reshape-sum).
 
-Under ``causal=True`` blocks strictly above the diagonal are skipped
-(their p is identically 0), saving ~half the FLOPs of causal training.
+Under ``causal=True`` (no window, square blocks) the kernels tell three
+kinds of block apart from the block indices alone. Above the diagonal:
+skipped, and the BlockSpec index map aims the step at the diagonal
+block, so it costs no fetch either. Below it: one unmasked tile, with no
+iota, compare or ``where``. On it: worked in sub-tiles (128 wide in the
+forward, 256 in the backward kernels), of which only those at or below
+the diagonal are computed, n(n+1)/2 of n*n, and only the n squares the
+diagonal crosses are masked. At T=1024 with one 1024-block that is 36 of
+64 (forward) or 10 of 16 (backward) sub-tiles, 56-62 % of the score
+square where causality needs 50 %; the kernels before PR 28 computed
+75 % of it at 512-blocks and masked every tile. A ``kv_mask`` keeps its
+``where`` on every tile; a window, ``causal=False`` and blocks that are
+not square keep the whole-block body.
 
 Sliding-window attention (``window``, Mistral-style) RESTRICTS THE GRID:
 for causal windows each q-block's k-loop covers only the
 ceil((bq+window)/bk)+1 blocks its band can intersect, with the BlockSpec
-index map aiming the DMA at the band (predicating compute alone measured
-SLOWER than full causal on v5e — skipped blocks still paid their HBM
-fetch). Measured v5e bf16 T=32768 W=4096 (the Mistral-7B shape):
-fwd 2.38x, fwd+bwd 2.74x over full causal.
+index map aiming the DMA at the band (an earlier round measured
+predicating compute alone slower than full causal on a v5e: skipped
+blocks still paid their HBM fetch). Measured on a v5e in PR 28, bf16 [1, 8, 32768, 128]
+W=4096 (the Mistral-7B shape), 1024-blocks, kernels' device time:
+forward 2.8x, forward and backward 2.8x over full causal.
 
 Layout: [B, H, T, D] inside the kernels (contiguous lanes along D).
 Grids: fwd/dq (B, H, Tq/bq, Tk/bk) with k innermost; dkv
@@ -120,23 +132,146 @@ def _keep_mask(mask_ref, causal, qi, kj, block_q, block_k, shape,
     return keep
 
 
-def _recompute_p(q_ref, k_ref, lse_ref, mask_ref, qi, kj, *, causal, scale,
-                 block_q, block_k, window=None):
-    """Shared backward-side recompute: p = exp(s - lse) for one block,
-    with causal/window/padding masking applied. Returns (q, k, p) f32."""
-    q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32)
-    s = scale * jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+# Sub-tile widths of the diagonal block (_diagonal_tiles), as a v5e
+# chose them at [4, 16, 1024, 64] bf16 (PERF.md, PR 28): the forward is
+# 29 % faster at 128 than at 256 (36 of 64 tiles against 40, and half
+# the masked area), the two backward kernels 4-5 % faster at 256 (their
+# matmuls stream 256 rows past each weight tile instead of 128, which
+# outweighs the 4 extra tiles).
+SUB_TILE_FWD = 128
+SUB_TILE_BWD = 256
+
+
+def _diagonal_aware(causal: bool, window: int | None, block_q: int,
+                    block_k: int) -> bool:
+    """Static choice of the kernels' body, from what a call fixes at
+    trace time. True: causal, no window, square blocks, so that block
+    qi == kj is the one the diagonal crosses, corner to corner, and the
+    kernels tell the blocks below, on and above it apart. False: the
+    whole-block body, for everything else (no diagonal; a band, which
+    has its own restricted grid; blocks that are not square)."""
+    return causal and window is None and block_q == block_k
+
+
+def _sub_tile(causal: bool, window: int | None, block_q: int,
+              block_k: int, width: int) -> int | None:
+    """Width of the sub-tiles the diagonal block is worked in (None:
+    the whole-block body, see _diagonal_aware)."""
+    if not _diagonal_aware(causal, window, block_q, block_k):
+        return None
+    for sub in (width, LANES):
+        if block_q % sub == 0:
+            return sub
+    return block_q  # a short sequence's single block: one masked tile
+
+
+def _diagonal_tiles(block: int, sub: int, mirror: bool = False):
+    """The sub-tiles of the diagonal block that causality leaves, as
+    ``[(strip, [(span, on_diagonal), ...]), ...]`` of static slices:
+    n(n+1)/2 of the n*n, n = block/sub. A strip is ``sub`` q rows and
+    its spans the k columns before it (whole, no mask) and its own (the
+    square the diagonal crosses); mirrored, for the dkv kernel, a strip
+    is ``sub`` k columns and its spans its own q rows and those below."""
+    out = []
+    for i in range(block // sub):
+        strip = pl.ds(i * sub, sub)
+        before, after = i * sub, block - (i + 1) * sub
+        if mirror:
+            rest = [(pl.ds((i + 1) * sub, after), False)] if after else []
+            out.append((strip, [(strip, True)] + rest))
+        else:
+            rest = [(pl.ds(0, before), False)] if before else []
+            out.append((strip, rest + [(strip, True)]))
+    return out
+
+
+def _tile_keep(mask_ref, cols, on_diagonal: bool, shape):
+    """Keep mask of one tile of the diagonal-aware body (None = keep
+    all): the lower triangle on a diagonal square (local positions do:
+    the block's and the sub-tile's offsets are the same for q and k
+    there), the padding vector's columns where a call has one, nothing
+    at all below the diagonal: no iota, no compare, no ``where``."""
+    keep = None
+    if on_diagonal:
+        from tensorlink_tpu.nn.attention import band_keep
+
+        keep = band_keep(
+            jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1),
+            True, None,
+        )
+    if mask_ref is not None:
+        kv_keep = jnp.broadcast_to(mask_ref[0, :, cols] > 0, shape)
+        keep = kv_keep if keep is None else jnp.logical_and(keep, kv_keep)
+    return keep
+
+
+def _visit(body, *, mirror: bool, width: int, causal: bool,
+           window: int | None, block_q: int, block_k: int, qi, kj,
+           in_range, mask_ref):
+    """Run ``body(strip, [(span, keep | None), ...])`` over the score
+    tiles of grid step (qi, kj); a strip is q rows and a span k columns,
+    or the reverse under ``mirror`` (the dkv kernel). Whole-block body
+    (_diagonal_aware False): one tile, the block, if it is visible, with
+    the full band and padding mask. Else the block is recognised from
+    its indices, as _block_visible recognises a skipped one: below the
+    diagonal one unmasked tile, on it the tiles of _diagonal_tiles
+    (``width`` wide), above it nothing."""
+    strip_len, span_len = (block_k, block_q) if mirror else (block_q, block_k)
+    strip, span = pl.ds(0, strip_len), pl.ds(0, span_len)
+    sub = _sub_tile(causal, window, block_q, block_k, width)
+    if sub is None:
+        vis = _block_visible(causal, qi, kj, block_q, block_k, window)
+        if in_range is not True:
+            vis = jnp.logical_and(in_range, vis)
+
+        @pl.when(vis)
+        def _whole():
+            body(strip, [(span, _keep_mask(
+                mask_ref, causal, qi, kj, block_q, block_k,
+                (block_q, block_k), window,
+            ))])
+        return
+
+    def keep_of(strip, span, on_diagonal):
+        rows, cols = (span, strip) if mirror else (strip, span)
+        return _tile_keep(
+            mask_ref, cols, on_diagonal, (rows.size, cols.size))
+
+    @pl.when(kj < qi)
+    def _below():
+        body(strip, [(span, keep_of(strip, span, False))])
+
+    @pl.when(kj == qi)
+    def _diagonal():
+        for strip_, spans in _diagonal_tiles(strip_len, sub, mirror):
+            body(strip_, [
+                (span_, keep_of(strip_, span_, diag)) for span_, diag in spans
+            ])
+
+
+def _f32(ref, span):
+    """Rows ``span`` of a [1, 1, block, D] operand block, f32."""
+    return ref[0, 0, span, :].astype(jnp.float32)
+
+
+def _qk(a, b):
+    """a @ b.T with f32 accumulation."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    keep = _keep_mask(
-        mask_ref, causal, qi, kj, block_q, block_k, s.shape, window
-    )
-    lse = lse_ref[0, 0]  # [block_q, 1]
-    p = jnp.exp(s - lse)
+
+
+def _p_ds(qs, k, v, do, lse, delta, keep):
+    """Shared backward-side recompute of one score tile: p = exp(s -
+    lse) with s from the SCALED q, as the forward computes it (so it is
+    the forward's s to the bit), zero where ``keep`` says, and ds = p *
+    (dp - delta) WITHOUT the softmax scale: dk gets it through ``qs``,
+    dq once a q-block at its finalize. f32."""
+    p = jnp.exp(_qk(qs, k) - lse)
     if keep is not None:
         p = jnp.where(keep, p, 0.0)
-    return q, k, p
+    return p, p * (_qk(do, v) - delta)
 
 
 # --------------------------------------------------------------- forward
@@ -168,6 +303,11 @@ def _flash_fwd_kernel(
         else 0,
         j_grid, nk_full,
     )
+    # a row of a pure causal call sees key 0 in the first block it
+    # visits, so its running max is finite from then on and exp(NEG_INF
+    # - m) is an exact 0. Only padding or a band can leave a row of a
+    # visited block without a key: only there are masked p zeroed again
+    rezero = has_mask or window is not None
 
     @pl.when(j_grid == 0)
     def _init():
@@ -175,40 +315,39 @@ def _flash_fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    vis = _block_visible(causal, qi, kj, block_q, block_k, window)
-    if in_range is not True:
-        vis = jnp.logical_and(in_range, vis)
-
-    @pl.when(vis)
-    def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-
-        keep = _keep_mask(
-            mask_ref, causal, qi, kj, block_q, block_k, s.shape, window
-        )
-        if keep is not None:
-            s = jnp.where(keep, s, NEG_INF)
-
-        m_prev = m_scr[:, 0:1]  # [block_q, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        if keep is not None:
-            p = jnp.where(keep, p, 0.0)
+    def update(rows, tiles):
+        """One online-softmax step of q rows ``rows`` over the score
+        tiles ``[(k columns, keep | None), ...]``."""
+        q = _f32(q_ref, rows) * scale
+        ss = []
+        for cols, keep in tiles:
+            s = _qk(q, _f32(k_ref, cols))  # [rows, cols]
+            ss.append(s if keep is None else jnp.where(keep, s, NEG_INF))
+        m_prev = m_scr[rows, 0:1]  # [rows, 1]
+        m_new = m_prev
+        for s in ss:
+            m_new = jnp.maximum(m_new, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)  # rescale of old accumulators
+        l_new = alpha * l_scr[rows, 0:1]
+        acc = acc_scr[rows, :] * alpha
+        for s, (cols, keep) in zip(ss, tiles):
+            p = jnp.exp(s - m_new)
+            if rezero and keep is not None:
+                p = jnp.where(keep, p, 0.0)
+            l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc + jax.lax.dot_general(
+                p, _f32(v_ref, cols), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        acc_scr[rows, :] = acc
+        m_scr[rows, :] = jnp.broadcast_to(m_new, (rows.size, LANES))
+        l_scr[rows, :] = jnp.broadcast_to(l_new, (rows.size, LANES))
 
-        l_new = alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    _visit(
+        update, mirror=False, width=SUB_TILE_FWD, causal=causal,
+        window=window, block_q=block_q, block_k=block_k, qi=qi, kj=kj,
+        in_range=in_range, mask_ref=mask_ref,
+    )
 
     @pl.when(j_grid == nk - 1)
     def _finalize():
@@ -244,6 +383,15 @@ def _check_blocks(Tq, Tk, block_q, block_k):
         )
 
 
+# The two entry points are jitted so that a model's layers, which call
+# them with the same shapes and statics, share ONE trace and lowering of
+# each kernel: the unrolled sub-tile bodies take some 60 ms of Python to
+# trace, and a 24-layer step program traced them 72 times, every run,
+# before its compile-cache lookup (PERF.md, PR 28: 14 s of set-up).
+_STATICS = ("causal", "block_q", "block_k", "interpret", "window")
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
 def flash_attention_fwd_lse(
     q: jax.Array,  # [B, H, Tq, D]
     k: jax.Array,  # [B, Hkv, Tk, D] (Hkv divides H: GQA read via index map)
@@ -266,16 +414,22 @@ def flash_attention_fwd_lse(
     nk_full = Tk // block_k
     # windowed causal: only ceil((bq + window)/bk)+1 k-blocks can
     # intersect a q-block's band — restrict the GRID (and with it the
-    # k/v block DMA) to that range instead of predicating compute only.
-    # pl.when alone measured SLOWER than full causal at T=8192/W=1024 on
-    # v5e (0.65x): skipped blocks still paid their HBM fetch.
+    # k/v block DMA) to that range instead of predicating compute only:
+    # under pl.when alone a skipped block still pays its HBM fetch.
     win_nk = None
     if window is not None and causal and nk_full > 1:
         win_nk = min(nk_full, (block_q + window + block_k) // block_k + 1)
     grid_nk = win_nk if win_nk is not None else nk_full
     grid = (B, H, Tq // block_q, grid_nk)
 
+    diagonal = _diagonal_aware(causal, window, block_q, block_k)
+
     def kv_block(i, j):
+        if diagonal:
+            # a block above the diagonal runs nothing: aim its step at
+            # the diagonal block, which is already there, and Pallas
+            # skips the fetch
+            return jnp.minimum(j, i)
         if win_nk is None:
             return j
         return jnp.minimum(
@@ -370,32 +524,33 @@ def _flash_bwd_dq_kernel(
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    vis = _block_visible(causal, qi, kj, block_q, block_k, window)
-    if in_range is not True:
-        vis = jnp.logical_and(in_range, vis)
+    def accumulate(rows, tiles):
+        """dq of q rows ``rows`` from the score tiles ``[(k columns,
+        keep | None), ...]``."""
+        qs = _f32(q_ref, rows) * scale
+        do = _f32(do_ref, rows)
+        lse = lse_ref[0, 0, rows, :]  # [rows, 1]
+        delta = delta_ref[0, 0, rows, :]
+        dq = dq_scr[rows, :]
+        for cols, keep in tiles:
+            k = _f32(k_ref, cols)
+            _, ds = _p_ds(qs, k, _f32(v_ref, cols), do, lse, delta, keep)
+            dq = dq + jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        dq_scr[rows, :] = dq
 
-    @pl.when(vis)
-    def _accumulate():
-        _, k, p = _recompute_p(
-            q_ref, k_ref, lse_ref, mask_ref, qi, kj,
-            causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-            window=window,
-        )
-        do = do_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        delta = delta_ref[0, 0]  # [block_q, 1]
-
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-        ds = p * (dp - delta) * scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _visit(
+        accumulate, mirror=False, width=SUB_TILE_BWD, causal=causal,
+        window=window, block_q=block_q, block_k=block_k, qi=qi, kj=kj,
+        in_range=in_range, mask_ref=mask_ref,
+    )
 
     @pl.when(j_grid == nk - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        # the scale that ds lacks (_p_ds), once a q-block
+        dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 # dk/dv kernel: grid (B, H, nk, nq), q innermost; accumulates dk and dv
@@ -434,33 +589,37 @@ def _flash_bwd_dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    vis = _block_visible(causal, qi, kj, block_q, block_k, window)
-    if in_range is not True:
-        vis = jnp.logical_and(in_range, vis)
+    def accumulate(cols, tiles):
+        """dk, dv of k rows ``cols`` from the score tiles ``[(q rows,
+        keep | None), ...]``."""
+        k = _f32(k_ref, cols)
+        v = _f32(v_ref, cols)
+        dk = dk_scr[cols, :]
+        dv = dv_scr[cols, :]
+        for rows, keep in tiles:
+            qs = _f32(q_ref, rows) * scale
+            do = _f32(do_ref, rows)
+            p, ds = _p_ds(
+                qs, k, v, do, lse_ref[0, 0, rows, :],
+                delta_ref[0, 0, rows, :], keep,
+            )
+            # dv += p^T @ do; dk += ds^T @ (scale * q)
+            dv = dv + jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dk = dk + jax.lax.dot_general(
+                ds, qs, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        dk_scr[cols, :] = dk
+        dv_scr[cols, :] = dv
 
-    @pl.when(vis)
-    def _accumulate():
-        q, _, p = _recompute_p(
-            q_ref, k_ref, lse_ref, mask_ref, qi, kj,
-            causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-            window=window,
-        )
-        do = do_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        delta = delta_ref[0, 0]  # [block_q, 1]
-
-        # dv += p^T @ do
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * scale
-        # dk += ds^T @ q
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _visit(
+        accumulate, mirror=True, width=SUB_TILE_BWD, causal=causal,
+        window=window, block_q=block_q, block_k=block_k, qi=qi, kj=kj,
+        in_range=in_range, mask_ref=mask_ref,
+    )
 
     @pl.when(i_grid == nq - 1)
     def _finalize():
@@ -468,6 +627,7 @@ def _flash_bwd_dkv_kernel(
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_STATICS)
 def flash_attention_bwd(
     q: jax.Array,  # [B, H, Tq, D]
     k: jax.Array,  # [B, Hkv, Tk, D]
@@ -510,7 +670,11 @@ def flash_attention_bwd(
         if nq_full > 1:
             win_nq = min(nq_full, (block_k + window + block_q) // block_q + 1)
 
+    diagonal = _diagonal_aware(causal, window, block_q, block_k)
+
     def kv_block(i, j):  # dq grid: i = q-block, j = band offset
+        if diagonal:  # no fetch above the diagonal, as in the forward
+            return jnp.minimum(j, i)
         if win_nk is None:
             return j
         return jnp.minimum(
@@ -518,6 +682,8 @@ def flash_attention_bwd(
         )
 
     def q_block(j, i):  # dkv grid: j = k-block, i = band offset
+        if diagonal:  # nor for the q-blocks before the diagonal one
+            return jnp.minimum(jnp.maximum(i, j), nq_full - 1)
         if win_nq is None:
             return i
         return jnp.minimum((j * block_k) // block_q + i, nq_full - 1)

@@ -1202,12 +1202,12 @@ def test_flash_block_override_registry():
     clear_flash_block_overrides()
     try:
         assert flash_block_for(512) == 512  # heuristic default
-        assert flash_block_for(8192) == 512
+        assert flash_block_for(8192) == 1024  # capped (v5e, PR 28)
         set_flash_block_override(512, 256)
         set_flash_block_override(512, 128, batch=8)
         assert flash_block_for(512, 8) == 128  # exact (seq, batch) wins
         assert flash_block_for(512, 2) == 256  # any-batch next
-        assert flash_block_for(1024, 8) == 512  # untouched shapes keep
+        assert flash_block_for(1024, 8) == 1024  # untouched shapes keep
         with pytest.raises(ValueError, match="divide"):
             set_flash_block_override(512, 96)
     finally:
@@ -1233,6 +1233,227 @@ def test_flash_override_kernel_parity():
     finally:
         clear_flash_block_overrides()
     np.testing.assert_allclose(out, ref, atol=2e-5)
+
+
+# ------------------------- the causal diagonal in sub-tiles (ISSUE 28)
+def _kernels_vs_reference(q, k, v, g, block, *, causal=True, kv_mask=None,
+                          window=None, fwd_tol, bwd_tol):
+    """The three kernels (interpret mode) against the plain path and
+    its ``jax.vjp``: o, lse, dq, dk, dv. q, g: [B, T, H, D]; k, v may
+    have fewer heads (GQA). The reference computes in f32 from the same
+    (possibly bf16) values; rows that padding empties read 0 there, as
+    in the kernels (``_fallback_attn``)."""
+    from tensorlink_tpu.ops.flash import _fallback_attn
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        LSE_MASKED, flash_attention_bwd, flash_attention_fwd_lse,
+    )
+
+    qt, kt, vt, gt = (x.swapaxes(1, 2) for x in (q, k, v, g))
+    kw = dict(causal=causal, block_q=block, block_k=block, interpret=True,
+              window=window)
+    o, lse = flash_attention_fwd_lse(qt, kt, vt, kv_mask, **kw)
+    dq, dk, dv = flash_attention_bwd(qt, kt, vt, o, lse, gt, kv_mask, **kw)
+    assert o.dtype == q.dtype and lse.dtype == jnp.float32
+    assert (dq.dtype, dk.dtype, dv.dtype) == (q.dtype, k.dtype, v.dtype)
+
+    q32, k32, v32, g32 = (x.astype(jnp.float32) for x in (q, k, v, g))
+    ref, vjp = jax.vjp(
+        lambda q_, k_, v_: _fallback_attn(
+            q_, k_, v_, kv_mask, causal, window),
+        q32, k32, v32,
+    )
+    rq, rk, rv = vjp(g32)
+    # the reference's lse, from its own scores
+    T, rep = q.shape[1], q.shape[2] // k.shape[2]
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q32, jnp.repeat(k32, rep, axis=2)
+    ) * q.shape[-1] ** -0.5
+    from tensorlink_tpu.nn.attention import band_keep
+
+    keep = jnp.ones((1, 1, T, T), bool)
+    if causal or window is not None:
+        keep = band_keep(
+            jnp.arange(T)[:, None], jnp.arange(T)[None, :], causal, window
+        )[None, None]
+    if kv_mask is not None:
+        keep = jnp.logical_and(keep, (kv_mask > 0)[:, None, None, :])
+    rlse = jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1)
+    rlse = jnp.where(jnp.any(keep, axis=-1), rlse, LSE_MASKED)
+
+    def close(a, b, tol):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b), atol=tol, rtol=tol)
+
+    close(o.swapaxes(1, 2), ref, fwd_tol)
+    close(lse, rlse, fwd_tol)
+    for a, b in ((dq, rq), (dk, rk), (dv, rv)):
+        close(a.swapaxes(1, 2), b, bwd_tol)
+
+
+def _rand(shape, dtype, seed):
+    r = np.random.default_rng(seed)
+    return jnp.asarray(r.normal(size=shape), jnp.float32).astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize(
+    "T,block",
+    [(512, 512), (1024, 512), (1024, 256), (256, 128), (1024, 1024)],
+)
+def test_flash_causal_subtiles_match_reference(T, block, D, dtype):
+    """Pure causal calls take the diagonal-aware body: blocks below the
+    diagonal unmasked, the block on it in sub-tiles (one strip, several,
+    and with blocks below it), forward and backward."""
+    q, k, v, g = (_rand((1, T, 2, D), dtype, 28 + i) for i in range(4))
+    f32 = dtype == jnp.float32
+    _kernels_vs_reference(
+        q, k, v, g, block,
+        fwd_tol=2e-5 if f32 else 2e-2, bwd_tol=1e-4 if f32 else 2e-2,
+    )
+
+
+@pytest.mark.parametrize("case", [
+    "kv_mask_empties_rows", "kv_mask_tail", "window", "gqa", "non_causal",
+    "non_causal_kv_mask",
+])
+def test_flash_bodies_beside_the_diagonal_path(case):
+    """What must keep its behaviour: a padding mask keeps its ``where``
+    on every tile, also where it empties whole rows (keys 0..299 of one
+    sequence masked: its rows 0..299 see nothing and read 0); a window
+    and ``causal=False`` keep the whole-block body; GQA reads the
+    unrepeated heads through either."""
+    T, H, D, block = 512, 4, 64, 256
+    causal, kv_mask, window, hkv = True, None, None, H
+    if case == "kv_mask_empties_rows":
+        kv_mask = jnp.stack([jnp.arange(T) >= 300, jnp.arange(T) < 400])
+    elif case == "kv_mask_tail":
+        kv_mask = jnp.arange(T)[None, :] < jnp.array([[130], [512]])
+    elif case == "window":
+        window = 200
+    elif case == "gqa":
+        hkv = 2
+    elif case == "non_causal":
+        causal = False
+    elif case == "non_causal_kv_mask":
+        causal = False
+        kv_mask = jnp.arange(T)[None, :] < jnp.array([[130], [512]])
+    q, g = (_rand((2, T, H, D), jnp.float32, 5 + i) for i in range(2))
+    k, v = (_rand((2, T, hkv, D), jnp.float32, 7 + i) for i in range(2))
+    _kernels_vs_reference(
+        q, k, v, g, block, causal=causal, kv_mask=kv_mask, window=window,
+        fwd_tol=2e-5, bwd_tol=1e-4,
+    )
+
+
+@pytest.mark.parametrize("Tq,Tk", [(256, 512), (512, 256)])
+def test_flash_causal_unequal_lengths(Tq, Tk):
+    """Causal with more k-blocks than q-blocks (and the reverse): the
+    index maps that aim skipped steps at the diagonal block stay inside
+    both arrays, and the k-blocks no query reaches get zero dk, dv."""
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        flash_attention_bwd, flash_attention_fwd_lse,
+    )
+
+    q, g = (_rand((1, Tq, 2, 64), jnp.float32, 40 + i) for i in range(2))
+    k, v = (_rand((1, Tk, 2, 64), jnp.float32, 42 + i) for i in range(2))
+    qt, kt, vt, gt = (x.swapaxes(1, 2) for x in (q, k, v, g))
+    kw = dict(causal=True, block_q=128, block_k=128, interpret=True)
+    o, lse = flash_attention_fwd_lse(qt, kt, vt, None, **kw)
+    grads = flash_attention_bwd(qt, kt, vt, o, lse, gt, None, **kw)
+    ref, vjp = jax.vjp(
+        lambda q_, k_, v_: dot_product_attention(q_, k_, v_, causal=True),
+        q, k, v,
+    )
+    np.testing.assert_allclose(
+        np.asarray(o.swapaxes(1, 2)), np.asarray(ref), atol=2e-5)
+    for a, b in zip(grads, vjp(g)):
+        np.testing.assert_allclose(
+            np.asarray(a.swapaxes(1, 2)), np.asarray(b), atol=1e-4)
+
+
+def _branches(jaxpr):
+    """Every ``cond`` branch of a kernel body as the multiset of what it
+    holds: ``(primitive names, [dot_general (contracted size, result
+    elements)])``, nested jaxprs included."""
+    def walk(jp, names, dots):
+        for e in jp.eqns:
+            names.append(e.primitive.name)
+            if e.primitive.name == "dot_general":
+                (lc, _), _ = e.params["dimension_numbers"]
+                dots.append((
+                    e.invars[0].aval.shape[lc[0]],
+                    int(np.prod(e.outvars[0].aval.shape)),
+                ))
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub, names, dots)
+
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "cond":
+            for br in e.params["branches"]:
+                names, dots = [], []
+                walk(br.jaxpr, names, dots)
+                if dots:
+                    out.append((names, dots))
+    return out
+
+
+# bound on the diagonal block's computed share, by (block, forward?):
+# 128-wide sub-tiles in the forward (10 of 16, 36 of 64), 256-wide in
+# the backward kernels (3 of 4 at a 512-block, 10 of 16 at 1024)
+@pytest.mark.parametrize("block,fwd_share,bwd_share", [
+    (512, 5 / 8, 3 / 4), (1024, 5 / 8, 5 / 8),
+])
+def test_flash_causal_kernels_skip_tiles_above_the_diagonal(
+        block, fwd_share, bwd_share):
+    """The mechanism, from the kernels' traced bodies: on the diagonal
+    block the score-shaped matmuls (contracted over the head dim: S in
+    all three, dP in the two backward kernels) produce at most that
+    share of block x block elements; the body of a block below the
+    diagonal builds no position mask at all."""
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        flash_attention_bwd, flash_attention_fwd_lse,
+    )
+
+    B, H, T, D = 1, 1, 2 * block, 64
+    t4 = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((B, H, T), jnp.float32)
+    kw = dict(causal=True, block_q=block, block_k=block)
+    text = [
+        jax.make_jaxpr(lambda q, k, v: flash_attention_fwd_lse(
+            q, k, v, None, **kw))(t4, t4, t4),
+        jax.make_jaxpr(lambda q, k, v, o, l, g: flash_attention_bwd(
+            q, k, v, o, l, g, None, **kw))(t4, t4, t4, t4, lse, t4),
+    ]
+    kernels = {}
+
+    def find(jaxpr):  # the entry points are jitted: look inside
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                kernels[e.params["name"]] = e.params["jaxpr"]
+            for sub in jax.core.jaxprs_in_params(e.params):
+                find(sub)
+
+    for jp in text:
+        find(jp.jaxpr)
+    assert sorted(kernels) == [
+        "tl_flash_bwd_dkv", "tl_flash_bwd_dq", "tl_flash_fwd"]
+    for name, body in kernels.items():
+        below = [b for b in _branches(body) if "iota" not in b[0]]
+        diagonal = [b for b in _branches(body) if "iota" in b[0]]
+        assert len(below) == 1 and len(diagonal) == 1, name
+        kinds = 1 if name == "tl_flash_fwd" else 2  # S; S and dP
+        share = fwd_share if name == "tl_flash_fwd" else bwd_share
+
+        def scores(branch):
+            return sum(n for contracted, n in branch[1] if contracted == D)
+
+        assert scores(below[0]) == kinds * block * block, name
+        assert scores(diagonal[0]) <= share * kinds * block * block, name
+        for prim in ("iota", "select_n", "lt", "le", "ge", "gt"):
+            assert prim not in below[0][0], (name, prim)
 
 
 # ------------------------------------------- paged-decode kernel (ISSUE 20)

@@ -68,10 +68,13 @@ def _kernel_lines(text: str, name: str) -> list[str]:
 
 
 # BERT-base at seq 512 with its key-padding mask; a 128-wide-head
-# decoder at seq 2048, causal
+# decoder at seq 2048, causal (1024-blocks: one below the diagonal, two
+# on it in sub-tiles); GPT-2 medium's micro-batch at seq 1024, causal
+# (one 1024-block, all sub-tiles)
 FLASH_SHAPES = [
     pytest.param(8, 12, 512, 64, True, False, id="B8-H12-T512-D64-kvmask"),
     pytest.param(2, 32, 2048, 128, False, True, id="B2-H32-T2048-D128-causal"),
+    pytest.param(4, 16, 1024, 64, False, True, id="B4-H16-T1024-D64-causal"),
 ]
 
 
