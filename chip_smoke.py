@@ -12,7 +12,7 @@ process exits non-zero with no such line. Without an accelerator it
 stops at the device phase.
 
     python chip_smoke.py              # one chip: device, serve,
-                                      # serve-kernel, train, cache
+                                      # serve-kernel, train, kda, cache
     python chip_smoke.py --multichip  # four chips: device, multichip
 
 The compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else to
@@ -650,6 +650,101 @@ def train_phase(
     return facts, failures
 
 
+# --------------------------------------------------------------------- kda
+# kda_chunked hands its matmuls bf16 operands (q, k, v arrive in bf16 in
+# a bf16 step) and keeps sums, the solve and the state in float32; the
+# recurrence it is held to runs in float32 throughout. Relative to the
+# reference's norm. A state dropped, or decayed wrongly, between chunks
+# is off by KDA_CARRIED and more.
+KDA_TOL = 0.02
+KDA_CARRIED = 0.25
+
+
+def kda_phase(
+    *, rows: int = 2, seq: int = 4096, heads: int = 32, head_dim: int = 128,
+) -> tuple[dict, list[str]]:
+    """``ops/kda.py::kda_chunked`` against the token-by-token recurrence
+    (``benchmark/reference/kimi_linear.py::delta_rule``), forward and
+    every gradient, at Kimi-Linear's head shape, with the decay the
+    model's own initialisation gives: a rate exp(A_log) in 1..16 a head
+    and a step in 1e-3..1e-1 a channel, so a chunk of 64 tokens keeps
+    between e^-0.06 and e^-100 of the state, and most of an output comes
+    from what earlier chunks handed on. (The benchmark's seeded weights
+    decay by e^-45 a chunk: its check barely sees the hand-over, PERF.md
+    section 7.) ``state_carried`` says how much: the same call with the
+    state forgotten at every chunk's start, against the reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference.kimi_linear import delta_rule
+    from tensorlink_tpu.ops.kda import CHUNK, kda_chunked
+
+    B, T, H, d = rows, seq, heads, head_dim
+    ks = jax.random.split(jax.random.key(SEED), 8)
+
+    def unit(key):
+        x = jax.random.normal(key, (B, T, H, d))
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    rate = jax.random.uniform(ks[0], (H, 1), minval=1.0, maxval=16.0)
+    dt = jnp.exp(jax.random.uniform(
+        ks[1], (H, d), minval=np.log(1e-3), maxval=np.log(1e-1)))
+    args = (
+        unit(ks[2]) * d ** -0.5, unit(ks[3]),
+        jax.random.normal(ks[4], (B, T, H, d)),
+        # softplus(dt_bias + what the token adds), dt_bias = softplus^-1(dt)
+        -rate * jax.nn.softplus(
+            dt + jnp.log(-jnp.expm1(-dt))
+            + 0.5 * jax.random.normal(ks[5], (B, T, H, d))),
+        jax.nn.sigmoid(jax.random.normal(ks[6], (B, T, H))),
+    )
+    ct = jax.random.normal(ks[7], (B, T, H, d))
+
+    def chunked(q, k, v, g, beta):
+        return kda_chunked(
+            *(x.astype(jnp.bfloat16) for x in (q, k, v)), g, beta)
+
+    def forgetful(*xs):  # every chunk a sequence of its own
+        cut = (x.reshape(B * T // CHUNK, CHUNK, *x.shape[2:]) for x in xs)
+        return chunked(*cut).reshape(B, T, H, d)
+
+    def both(fn):
+        o, grads = jax.jit(jax.value_and_grad(
+            lambda *a: (lambda o: (jnp.sum(o * ct), o))(fn(*a)),
+            argnums=range(5), has_aux=True,
+        ))(*args)
+        return (o[1], *grads)
+
+    def gap(a, b):
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    want, got = both(delta_rule), both(chunked)
+    names = ("o", "dq", "dk", "dv", "dg", "dbeta")
+    gaps = {n: gap(a, b) for n, a, b in zip(names, got, want)}
+    carried = gap(jax.jit(forgetful)(*args), want[0])
+    g = args[3]
+    facts = {
+        "shape": f"{B} x {T} tokens, {H} heads of {d}, chunks of {CHUNK}",
+        # ln of what a chunk keeps of the state, by channel: least, most
+        "chunk_log_decay": [
+            round(float(CHUNK * g.mean((0, 1)).min()), 3),
+            round(float(CHUNK * g.mean((0, 1)).max()), 3),
+        ],
+        "gaps": {n: round(x, 6) for n, x in gaps.items()},
+        "state_carried": round(carried, 4),
+        "limits": {"gap": KDA_TOL, "state_carried_at_least": KDA_CARRIED},
+    }
+    failures = [
+        f"{n} stands {x:.4f} off the recurrence" for n, x in gaps.items()
+        if not x < KDA_TOL
+    ]
+    if not carried > KDA_CARRIED:
+        failures.append(
+            f"the state carries {carried:.4f} of the output: this decay "
+            "does not test the hand-over between chunks")
+    return facts, failures
+
+
 # --------------------------------------------------------------- multichip
 def multichip_phase(
     cfg, *, seq: int = 512, batch: int = 8, steps: int = 3,
@@ -840,6 +935,8 @@ def main(argv: list[str] | None = None) -> int:
         if k not in facts["step_kernels"]:
             failures.append(f"{k} is not in the compiled train step")
     finish("train", facts, failures)
+
+    finish("kda", *kda_phase())
 
     # a cold directory must have grown; a warm one (a second run on
     # the same machine) is expected to gain nothing for unchanged

@@ -1,7 +1,23 @@
 """Mixture-of-Experts feed-forward with expert parallelism.
 
-The reference has no MoE/expert parallelism at all (survey §2.3: "EP —
-absent"); this is TPU-native from scratch. Design:
+Two layers, two dispatches; which serves which caller:
+
+- ``MoEFeedForward`` (softmax router, top-k, a capacity factor, one-hot
+  dispatch tensors [B, T, E, C]): ``TransformerBlock`` with
+  ``moe_experts`` set, i.e. the Llama-shaped trunk's Mixtral preset, on
+  one device or sharded over a mesh axis, where the dense dispatch is
+  what the partitioner turns into collectives. It drops routes over
+  capacity and its tensors grow with E * C: right for 8 experts, not
+  buildable at 256.
+- ``HeldExpertsMoE`` (sigmoid router with a selection bias, top-k
+  renormalised and scaled, shared experts; sorted rows and a grouped
+  matmul): ``models/kimi_linear.py``. The layer is told which experts
+  it holds, routes over all of them and computes its own experts' part;
+  one chip's share of an expert-parallel layer runs it without the
+  exchange. It drops nothing and its cost follows the routed rows.
+
+``MoEFeedForward``. The reference has no MoE/expert parallelism at all
+(survey §2.3: "EP — absent"); this is TPU-native from scratch. Design:
 
 - Experts are ONE stacked param tree with a leading [E, ...] axis, sharded
   over the mesh's ``model`` axis (`P(model, ...)`) — expert parallelism is
@@ -40,6 +56,7 @@ from jax.sharding import PartitionSpec as P
 
 from tensorlink_tpu.nn.module import Module, register_module_type
 from tensorlink_tpu.nn.layers import _lecun_normal, _normal
+from tensorlink_tpu.runtime.tracing import scope
 
 
 def _auto_ambient_axes() -> tuple:
@@ -248,4 +265,173 @@ class MoEFeedForward(Module):
             "drop_fraction": 1.0 - kept / (B * T * self.top_k),
             "aux_loss": float(aux),
             "capacity_per_expert": self.capacity(T),
+        }
+
+
+class HeldExpertsMoE(Module):
+    """Sigmoid-routed experts with shared experts (DeepSeek-V3 / Kimi
+    style), told which experts it holds: [B, T, D] -> [B, T, D].
+
+        s = sigmoid(x W_r)                  float32, over all E experts
+        chosen = top-k of s + bias          (the bias chooses only, and
+                                             gets no gradient)
+        w_e = scale * s_e / sum_chosen s
+        y = sum_{e chosen and held} w_e E_e(x) + E_shared(x)
+
+    ``held = (first, count)``: the experts ``[first, first + count)``
+    live here (``None``: all of them); the expert weights are ``count``
+    deep, the router stays E wide. What an absent expert would add is
+    left out and nothing stands in for it. The routes to held experts
+    are sorted by expert into ``row_bound`` rows, gathered, put through
+    three grouped matmuls (``lax.ragged_dot``) and scattered back, so
+    the cost follows the rows and no [T, E, C] tensor exists. No route
+    to a held expert is dropped: ``row_bound`` None is every route there
+    could be, ``tokens * min(k, count)``. A smaller bound is the
+    caller's promise about its load; if the routes ever outnumber it the
+    output is NaN (a non-finite loss, the trainers' ``nonfinite``
+    flag), never a silently dropped route. ``routing_stats`` counts.
+    """
+
+    def __init__(
+        self,
+        dim: int,
+        hidden_dim: int,
+        num_experts: int,
+        top_k: int,
+        held: tuple[int, int] | None = None,
+        shared_experts: int = 1,
+        routed_scale: float = 1.0,
+        renormalize: bool = True,
+        select_bias: bool = True,
+        row_bound: int | None = None,
+    ):
+        super().__init__()
+        from tensorlink_tpu.nn.transformer import FeedForward
+
+        first, count = held or (0, num_experts)
+        if not 0 <= first <= first + count <= num_experts or count < 1:
+            raise ValueError(f"held {held} is not a range of {num_experts}")
+        self.dim, self.hidden_dim = dim, hidden_dim
+        self.num_experts, self.top_k = num_experts, top_k
+        self.held = (first, count)
+        self.shared_experts, self.routed_scale = shared_experts, routed_scale
+        self.renormalize, self.select_bias = renormalize, select_bias
+        self.row_bound = row_bound
+        if shared_experts:
+            self.child("shared", FeedForward(
+                dim, hidden_dim * shared_experts, activation="silu",
+                use_bias=False, gated=True,
+            ))
+
+    def init(self, key):
+        D, F, E = self.dim, self.hidden_dim, self.num_experts
+        n = self.held[1]
+        kr, ku, kg, kd, ks = jax.random.split(key, 5)
+        params = {
+            "router": {"w": _normal(kr, (D, E))},
+            # fan-in first, the held experts second: [D, n, F], [F, n, D]
+            "experts": {
+                "up": {"w": _lecun_normal(ku, (D, n, F))},
+                "gate": {"w": _lecun_normal(kg, (D, n, F))},
+                "down": {"w": _lecun_normal(kd, (F, n, D))},
+            },
+        }
+        if self.select_bias:
+            params["router"]["bias"] = jnp.zeros((E,))
+        if self.shared_experts:
+            params["shared"] = self.children["shared"].init(ks)
+        return params
+
+    def param_spec(self, model_axis: str = "model"):
+        spec = {
+            "router": {"w": P()},
+            "experts": {n: {"w": P()} for n in ("up", "gate", "down")},
+        }
+        if self.select_bias:
+            spec["router"]["bias"] = P()
+        if self.shared_experts:
+            spec["shared"] = self.children["shared"].param_spec(model_axis)
+        return spec
+
+    def rows(self, tokens: int) -> int:
+        """Rows the sorted dispatch is built for at ``tokens`` tokens."""
+        most = tokens * min(self.top_k, self.held[1])
+        return most if self.row_bound is None else min(self.row_bound, most)
+
+    def _route(self, params, xf):
+        """xf [N, D] -> (tok [R] token of each row, w [R] its weight, 0
+        on an empty row, sizes [count] rows of each held expert, routes:
+        how many routes to held experts there were)."""
+        N, k = xf.shape[0], self.top_k
+        first, count = self.held
+        R = self.rows(N)
+        f32 = jnp.float32
+        s = jax.nn.sigmoid(xf.astype(f32) @ params["router"]["w"].astype(f32))
+        pick = s
+        if self.select_bias:
+            pick = s + params["router"]["bias"].astype(f32)
+        _, idx = jax.lax.top_k(jax.lax.stop_gradient(pick), k)  # [N, k]
+        w = jnp.take_along_axis(s, idx, -1)
+        if self.renormalize:
+            w = w / jnp.sum(w, -1, keepdims=True)
+        w = w * self.routed_scale
+        local = idx - first
+        mine = (local >= 0) & (local < count)
+        # routes by held expert, the absent experts' after them all
+        key = jnp.where(mine, local, count).reshape(N * k)
+        order = jnp.argsort(key, stable=True)[:R]
+        routes = jnp.sum(mine)
+        live = jnp.arange(R) < routes
+        ends = jnp.minimum(
+            jnp.cumsum(jnp.bincount(key, length=count + 1)[:count]), R
+        )
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        return (
+            order // k, jnp.where(live, w.reshape(N * k)[order], 0.0),
+            sizes, routes,
+        )
+
+    def apply(self, params, x, **_):
+        B, T, D = x.shape
+        xf = x.reshape(B * T, D)
+        with scope("moe.route"):
+            tok, w, sizes, routes = self._route(params, xf)
+        with scope("moe.experts"):
+            ex = params["experts"]
+            up, gate, down = (
+                ex[n]["w"].astype(x.dtype).swapaxes(0, 1)
+                for n in ("up", "gate", "down")
+            )
+            # past the routes the grouped matmul writes nothing: such a
+            # row holds whatever memory held, forward and backward (NaN
+            # on a v5e, PR 29), so every operand and result of it is
+            # cleared there, which clears its cotangents too
+            live = (w > 0)[:, None]
+
+            def clear(r):
+                return jnp.where(live, r, 0)
+
+            def grouped(lhs, rhs):
+                return clear(jax.lax.ragged_dot(clear(lhs), rhs, sizes))
+
+            rows = xf[tok]
+            h = jax.nn.silu(grouped(rows, gate)) * grouped(rows, up)
+            out = grouped(h, down) * w[:, None].astype(x.dtype)
+            y = jnp.zeros_like(xf).at[tok].add(out)
+            y = jnp.where(routes > w.shape[0], jnp.nan, y)
+        y = y.reshape(B, T, D)
+        if self.shared_experts:
+            y = y + self.children["shared"].apply(params["shared"], x)
+        return y
+
+    def routing_stats(self, params, x) -> dict:
+        """Routes to held experts, the rows built for them, and the
+        routes past the bound (0, or the output is NaN)."""
+        xf = x.reshape(-1, x.shape[-1])
+        _, _, sizes, routes = self._route(params, xf)
+        R = self.rows(xf.shape[0])
+        return {
+            "routes": int(routes), "rows": R,
+            "overflow": max(int(routes) - R, 0),
+            "per_expert": [int(n) for n in sizes],
         }

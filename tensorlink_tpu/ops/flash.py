@@ -36,10 +36,12 @@ def _tile_ok(T: int) -> bool:
     return T % 128 == 0 or T in (8, 16, 32, 64)
 
 
-def _pick_block(T: int) -> int:
+def _pick_block(T: int, head_dim: int = 128) -> int:
     """Default block size by sequence length: the largest power-of-two
-    block dividing T, capped at 1024. Per-shape overrides
-    (``set_flash_block_override``) win over this heuristic.
+    block dividing T, capped at 1024 (at 512 for heads wider than 128:
+    at q, k 192 wide the dq kernel's 1024-block needs 17.2 MB of the 16
+    MB of VMEM a kernel may use, PR 29's compile for a v5e). Per-shape
+    overrides (``set_flash_block_override``) win over this heuristic.
 
     Measured on a v5e (PR 28, bf16, device time of the three kernels a
     call, 1024-blocks against 512-blocks): a grid step costs about as
@@ -52,7 +54,7 @@ def _pick_block(T: int) -> int:
     blocks cut it coarser). 2048-blocks are out: one block's scores
     would be 16 MB of f32."""
     for b in (1024, 512, 256, 128):
-        if T % b == 0:
+        if T % b == 0 and (b <= 512 or head_dim <= 128):
             return b
     return T  # T in (8, 16, 32, 64): single block
 
@@ -115,9 +117,12 @@ def flash_block_overrides() -> list[tuple[int, int | None, int]]:
     )
 
 
-def flash_block_for(seq: int, batch: int | None = None) -> int:
+def flash_block_for(
+    seq: int, batch: int | None = None, head_dim: int = 128
+) -> int:
     """Resolved block size for a (seq, batch) shape: exact-batch
-    override, then any-batch override, then the heuristic."""
+    override, then any-batch override, then the heuristic (which alone
+    looks at the head's width)."""
     if batch is not None:
         b = _BLOCK_OVERRIDES.get((seq, int(batch)))
         if b is not None:
@@ -125,20 +130,21 @@ def flash_block_for(seq: int, batch: int | None = None) -> int:
     b = _BLOCK_OVERRIDES.get((seq, None))
     if b is not None:
         return b
-    return _pick_block(seq)
+    return _pick_block(seq, head_dim)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
                     interpret: bool = False, window: int | None = None):
-    """q: [B, T, H, D]; k, v: [B, T, Hkv, D] (Hkv divides H — GQA is read
+    """q: [B, T, H, D]; k: [B, T, Hkv, D]; v: [B, T, Hkv, Dv], Dv = D
+    or a head size of its own (MLA) (Hkv divides H — GQA is read
     in-kernel, no repeat); kv_mask: [B, Tk] bool/float (nonzero=attend);
     window: sliding-window band — in-kernel masking plus whole-block
     skipping, so long-seq windowed attention costs O(T*window).
     causal (no window): blocks above the diagonal are skipped, the block
     on it is computed in 128/256-wide sub-tiles of which only those the
     diagonal crosses are masked (ops/pallas/flash_attention.py).
-    -> [B, T, H, D]."""
+    -> [B, T, H, Dv]."""
     return _fwd(q, k, v, kv_mask, causal, interpret, window)[0]
 
 
@@ -194,8 +200,8 @@ def _fwd(q, k, v, kv_mask, causal, interpret, window=None):
         qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))  # [B,H,T,D]
         out, lse = flash_attention_fwd_lse(
             qt, kt, vt, kv_mask, causal=causal,
-            block_q=flash_block_for(q.shape[1], q.shape[0]),
-            block_k=flash_block_for(k.shape[1], q.shape[0]),
+            block_q=flash_block_for(q.shape[1], q.shape[0], q.shape[3]),
+            block_k=flash_block_for(k.shape[1], q.shape[0], q.shape[3]),
             interpret=interpret, window=window,
         )
         return out.swapaxes(1, 2), (q, k, v, kv_mask, out, lse)
@@ -210,8 +216,8 @@ def _bwd(causal, interpret, window, res, g):
         dq, dk, dv = flash_attention_bwd(
             qt, kt, vt, out_t, lse, g.swapaxes(1, 2), kv_mask,
             causal=causal,
-            block_q=flash_block_for(q.shape[1], q.shape[0]),
-            block_k=flash_block_for(k.shape[1], q.shape[0]),
+            block_q=flash_block_for(q.shape[1], q.shape[0], q.shape[3]),
+            block_k=flash_block_for(k.shape[1], q.shape[0], q.shape[3]),
             interpret=interpret, window=window,
         )
         dq, dk, dv = (x.swapaxes(1, 2) for x in (dq, dk, dv))

@@ -366,13 +366,15 @@ def _flash_fwd_kernel(
 def _check_shapes(q, k, v, kv_mask):
     B, H, Tq, D = q.shape
     Bk, Hkv, Tk, Dk = k.shape
-    if k.shape != v.shape or Bk != B or Dk != D:
+    # v may have a head size of its own (MLA: q, k 192 wide, v 128):
+    # the kernels' bodies take every width from their operands
+    if k.shape[:3] != v.shape[:3] or Bk != B or Dk != D:
         raise ValueError(f"bad kv shapes q={q.shape} k={k.shape} v={v.shape}")
     if H % Hkv:
         raise ValueError(f"H={H} not a multiple of Hkv={Hkv}")
     if kv_mask is not None and kv_mask.shape != (B, Tk):
         raise ValueError(f"kv_mask {kv_mask.shape} != {(B, Tk)}")
-    return B, H, Hkv, Tq, Tk, D
+    return B, H, Hkv, Tq, Tk, D, v.shape[3]
 
 
 def _check_blocks(Tq, Tk, block_q, block_k):
@@ -404,8 +406,8 @@ def flash_attention_fwd_lse(
     interpret: bool = False,
     window: int | None = None,  # sliding-window band (see _band_keep)
 ) -> tuple[jax.Array, jax.Array]:
-    """-> (o [B,H,Tq,D], lse [B,H,Tq] f32)."""
-    B, H, Hkv, Tq, Tk, D = _check_shapes(q, k, v, kv_mask)
+    """-> (o [B,H,Tq,Dv], lse [B,H,Tq] f32)."""
+    B, H, Hkv, Tq, Tk, D, Dv = _check_shapes(q, k, v, kv_mask)
     group = H // Hkv
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
@@ -451,7 +453,7 @@ def flash_attention_fwd_lse(
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, block_k, D),
                      lambda b, h, i, j: (b, h // group, kv_block(i, j), 0)),
-        pl.BlockSpec((1, 1, block_k, D),
+        pl.BlockSpec((1, 1, block_k, Dv),
                      lambda b, h, i, j: (b, h // group, kv_block(i, j), 0)),
     ]
     args = [q, k, v]
@@ -465,19 +467,19 @@ def flash_attention_fwd_lse(
     o, lse = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((B, H, Tq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
         ),
         grid=grid,
         in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="tl_flash_fwd",
@@ -632,7 +634,7 @@ def flash_attention_bwd(
     q: jax.Array,  # [B, H, Tq, D]
     k: jax.Array,  # [B, Hkv, Tk, D]
     v: jax.Array,
-    o: jax.Array,  # forward output [B, H, Tq, D]
+    o: jax.Array,  # forward output [B, H, Tq, Dv]
     lse: jax.Array,  # [B, H, Tq] f32 from flash_attention_fwd_lse
     do: jax.Array,  # upstream cotangent of o
     kv_mask: jax.Array | None = None,
@@ -643,9 +645,9 @@ def flash_attention_bwd(
     interpret: bool = False,
     window: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Blockwise dq [B,H,Tq,D], dk/dv [B,Hkv,Tk,D]. f32 accumulation,
-    outputs in input dtype; GQA groups summed here."""
-    B, H, Hkv, Tq, Tk, D = _check_shapes(q, k, v, kv_mask)
+    """Blockwise dq [B,H,Tq,D], dk [B,Hkv,Tk,D], dv [B,Hkv,Tk,Dv]. f32
+    accumulation, outputs in input dtype; GQA groups summed here."""
+    B, H, Hkv, Tq, Tk, D, Dv = _check_shapes(q, k, v, kv_mask)
     group = H // Hkv
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
@@ -688,16 +690,22 @@ def flash_attention_bwd(
             return i
         return jnp.minimum((j * block_k) // block_q + i, nq_full - 1)
 
-    qspec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    kspec = pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j: (b, h // group, kv_block(i, j), 0))
+    def qspec_of(d):
+        return pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0))
+
+    def kspec_of(d):
+        return pl.BlockSpec(
+            (1, 1, block_k, d),
+            lambda b, h, i, j: (b, h // group, kv_block(i, j), 0))
+
+    qspec = qspec_of(D)
     rowq = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
     common = dict(
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         has_mask=kv_mask is not None, window=window,
     )
     args = [q, k, v, do, lse, delta]
-    in_specs = [qspec, kspec, kspec, qspec, rowq, rowq]
+    in_specs = [qspec, kspec_of(D), kspec_of(Dv), qspec_of(Dv), rowq, rowq]
     if kv_mask is not None:
         args.append(kv_mask.astype(jnp.float32)[:, None, :])
         in_specs.append(pl.BlockSpec(
@@ -719,13 +727,20 @@ def flash_attention_bwd(
     )(*args)
 
     # dkv grid swaps the outer two block axes: (b, h, kj, qi)
-    qspec2 = pl.BlockSpec((1, 1, block_q, D),
-                          lambda b, h, j, i: (b, h, q_block(j, i), 0))
-    kspec2 = pl.BlockSpec((1, 1, block_k, D), lambda b, h, j, i: (b, h // group, j, 0))
-    hspec2 = pl.BlockSpec((1, 1, block_k, D), lambda b, h, j, i: (b, h, j, 0))
+    def qspec2(d):
+        return pl.BlockSpec(
+            (1, 1, block_q, d), lambda b, h, j, i: (b, h, q_block(j, i), 0))
+
+    def kspec2(d):
+        return pl.BlockSpec(
+            (1, 1, block_k, d), lambda b, h, j, i: (b, h // group, j, 0))
+
+    def hspec2(d):
+        return pl.BlockSpec((1, 1, block_k, d), lambda b, h, j, i: (b, h, j, 0))
+
     rowq2 = pl.BlockSpec((1, 1, block_q, 1),
                          lambda b, h, j, i: (b, h, q_block(j, i), 0))
-    in_specs2 = [qspec2, kspec2, kspec2, qspec2, rowq2, rowq2]
+    in_specs2 = [qspec2(D), kspec2(D), kspec2(Dv), qspec2(Dv), rowq2, rowq2]
     if kv_mask is not None:
         in_specs2.append(pl.BlockSpec((1, 1, block_k), lambda b, h, j, i: (b, 0, j)))
     dk, dv = pl.pallas_call(
@@ -735,19 +750,19 @@ def flash_attention_bwd(
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, Tk, Dv), v.dtype),
         ),
         grid=(B, H, nk_full, win_nq if win_nq is not None else nq_full),
         in_specs=in_specs2,
-        out_specs=(hspec2, hspec2),
+        out_specs=(hspec2(D), hspec2(Dv)),
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="tl_flash_bwd_dkv",
     )(*args)
     if group > 1:  # sum each GQA group back to its kv head
         dk = dk.reshape(B, Hkv, group, Tk, D).sum(axis=2)
-        dv = dv.reshape(B, Hkv, group, Tk, D).sum(axis=2)
+        dv = dv.reshape(B, Hkv, group, Tk, Dv).sum(axis=2)
     return dq, dk, dv

@@ -133,6 +133,12 @@ VOCABULARY: dict[str, tuple[str, str, str]] = {
     "embed": ("scope", "model embedding", "token/position lookup and embedding dropout"),
     "attn": ("scope", "nn/attention.py", "a block's attention half: its norm, projections, attention, residual"),
     "mlp": ("scope", "nn/transformer.py", "a block's feed-forward half (dense or MoE): its norm, matmuls, residual"),
+    "kda": ("scope", "nn/kda.py", "a Kimi-Linear block's KDA half: norm, projections, short convolutions, gates, head norm, residual"),
+    "kda.scan": ("scope", "ops/kda.py", "the chunked gated delta-rule recurrence inside tl.kda, forward and backward"),
+    "mla": ("scope", "nn/mla.py", "a Kimi-Linear block's latent-attention half: norm, projections, the flash kernels, residual"),
+    "moe": ("scope", "nn/moe.py", "a Kimi-Linear block's expert half (HeldExpertsMoE): norm, shared expert, residual"),
+    "moe.route": ("scope", "nn/moe.py", "inside tl.moe: router scores, top-k, the sort of routes into rows"),
+    "moe.experts": ("scope", "nn/moe.py", "inside tl.moe: gather of rows, the grouped matmuls of the held experts, scatter back"),
     "head": ("scope", "model head", "final norm and the unembedding matmul"),
     "loss": ("scope", "train/trainer.py", "the loss from logits"),
     "train.cast": ("scope", "train/trainer.py", "the dtype policy: master weights to the compute dtype, and the gradients' way back"),

@@ -123,6 +123,38 @@ def test_train_phase_tiny():
     assert facts["losses"][-1] < facts["losses"][0]
 
 
+def test_kda_phase_tiny():
+    facts, failures = chip_smoke.kda_phase(
+        rows=2, seq=256, heads=2, head_dim=16)
+    assert not failures, failures
+    assert set(facts["gaps"]) == {"o", "dq", "dk", "dv", "dg", "dbeta"}
+    # the slowest channel keeps most of a chunk's state, the fastest none
+    fastest, slowest = facts["chunk_log_decay"]
+    assert fastest < -20 and slowest > -2
+    assert facts["state_carried"] > chip_smoke.KDA_CARRIED
+
+
+def test_kda_phase_catches_a_lost_state(monkeypatch):
+    """A recurrence that forgets its state between chunks (what the
+    benchmark's fast-decaying seeded weights would let through) stands
+    far off the reference here, in the output and in every gradient."""
+    import tensorlink_tpu.ops.kda as ops_kda
+
+    real = ops_kda.kda_chunked
+
+    def forgetful(q, k, v, g, beta):
+        B, T = q.shape[:2]
+        cut = (
+            x.reshape(B * T // ops_kda.CHUNK, ops_kda.CHUNK, *x.shape[2:])
+            for x in (q, k, v, g, beta)
+        )
+        return real(*cut).reshape(B, T, *v.shape[2:])
+
+    monkeypatch.setattr(ops_kda, "kda_chunked", forgetful)
+    _, failures = chip_smoke.kda_phase(rows=2, seq=256, heads=2, head_dim=16)
+    assert len(failures) >= 6 and "o stands" in failures[0]
+
+
 def test_multichip_phase_tiny(devices):
     """The four-chip path on virtual devices: the sharded trainer
     against the plain one, the model=4 engine against the one-device

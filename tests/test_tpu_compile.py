@@ -120,6 +120,37 @@ def test_flash_backward_compiles(compile_for_chip, B, H, T, D, masked, causal):
     assert _kernel_lines(text, "tl_flash_bwd_dkv")
 
 
+def test_flash_with_a_narrower_v_compiles(compile_for_chip):
+    """MLA without rotary at Kimi-Linear's widths and the benchmark
+    cell's shape: q, k 128 + 64 wide, v 128, 4 x 4,096 tokens, causal.
+    ``flash_block_for`` has to give blocks the dq kernel's VMEM holds."""
+    from tensorlink_tpu.ops.flash import flash_block_for
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        flash_attention_bwd,
+        flash_attention_fwd_lse,
+    )
+
+    B, H, T, D, Dv = 4, 32, 4096, 192, 128
+    blk = flash_block_for(T, B, D)
+    assert blk == 512 and flash_block_for(T, B) == 1024
+    wide, narrow = ((B, H, T, D), BF16), ((B, H, T, Dv), BF16)
+
+    def fwd(q, k, v):
+        return flash_attention_fwd_lse(
+            q, k, v, causal=True, block_q=blk, block_k=blk)
+
+    def bwd(q, k, v, o, lse, do):
+        return flash_attention_bwd(
+            q, k, v, o, lse, do, causal=True, block_q=blk, block_k=blk)
+
+    assert _kernel_lines(compile_for_chip(fwd, wide, wide, narrow), "tl_flash_fwd")
+    text = compile_for_chip(
+        bwd, wide, wide, narrow, narrow, ((B, H, T), jnp.float32), narrow
+    )
+    assert _kernel_lines(text, "tl_flash_bwd_dq")
+    assert _kernel_lines(text, "tl_flash_bwd_dkv")
+
+
 @pytest.mark.parametrize("T", [1, 4])
 @pytest.mark.parametrize("pools", ["bf16", "int8"])
 def test_paged_decode_compiles(compile_for_chip, monkeypatch, pools, T):

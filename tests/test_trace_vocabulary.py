@@ -274,6 +274,81 @@ def test_every_scope_in_the_train_program_is_in_the_table(train_step_text):
     assert found and all(known(n) for n in found), found
 
 
+# ------------------------------------------- a model of unlike layers
+KIMI_SCOPES = [
+    "tl.kda", "tl.kda.scan", "tl.mla", "tl.moe", "tl.moe.route",
+    "tl.moe.experts",
+]
+
+
+@pytest.fixture(scope="module")
+def kimi_step_text():
+    """Kimi-Linear's tiny preset through ``Trainer``: KDA and MLA
+    mixers, a dense and four expert feed-forwards, blocks recomputed."""
+    import dataclasses
+
+    from tensorlink_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
+
+    model = KimiLinear(dataclasses.replace(KimiLinearConfig.tiny(), remat=True))
+
+    def loss(module, params, batch, rng):
+        return softmax_cross_entropy(
+            module.apply(params, batch["input_ids"]), batch["labels"]
+        )
+
+    tr = Trainer(model, loss, TrainConfig(
+        batch_size=2, micro_batches=1, learning_rate=1e-3, optimizer="adam",
+        grad_clip_norm=1.0,
+    ))
+    ids = np.arange(2 * 33).reshape(2, 33) % 128
+    batch = {
+        "input_ids": jnp.asarray(ids[:, :-1]), "labels": jnp.asarray(ids[:, 1:])
+    }
+    state = jax.eval_shape(tr.init_state, KEY)
+    return tr.audit_programs(state, batch, KEY)[0]["lower"]().as_text(
+        debug_info=True
+    )
+
+
+@pytest.mark.parametrize("name", KIMI_SCOPES + ["tl.mlp", "tl.embed", "tl.head"])
+def test_kimi_train_program_holds_the_scope(kimi_step_text, name):
+    assert known(name) and tracing.VOCABULARY[name[3:]][0] == "scope"
+    assert re.search(rf"[/(]{re.escape(name)}[/)]", kimi_step_text)
+    if name in ("tl.embed", "tl.head"):
+        return
+    # backward instructions keep the scope, under the block's remat too:
+    # jit(tl_train_step)/transpose(jvp(...))/checkpoint/.../tl.kda/...
+    paths = set(re.findall(r'"(jit\(tl_train_step\)[^"]*)"', kimi_step_text))
+    assert any(
+        "transpose(" in p and re.search(rf"/{re.escape(name)}(/|$)", p)
+        for p in paths
+    )
+
+
+def test_every_scope_in_the_kimi_program_is_in_the_table(kimi_step_text):
+    found = set(re.findall(r"tl\.[a-z_.]*[a-z]", kimi_step_text))
+    assert set(KIMI_SCOPES) <= found and all(known(n) for n in found), found
+    assert "tl.attn" not in found  # no block of the uniform trunk here
+
+
+def test_a_kimi_block_instruction_reads_one_half(kimi_step_text):
+    """Inside a block every instruction lies under the scope of the
+    half it belongs to: the nested scopes only inside their parent, and
+    no instruction under two halves."""
+    halves = ("tl.kda", "tl.mla", "tl.moe", "tl.mlp")
+    for path in set(re.findall(r'"(jit\(tl_train_step\)[^"]*)"', kimi_step_text)):
+        scopes = re.findall(r"tl\.[a-z_.]*[a-z]", path)
+        inner = [s for s in scopes if s.startswith(halves)]
+        if not inner:
+            continue
+        assert len({s.split(".")[1] for s in inner}) == 1, path
+        for child, parent in (("tl.kda.scan", "tl.kda"),
+                              ("tl.moe.route", "tl.moe"),
+                              ("tl.moe.experts", "tl.moe")):
+            if child in inner:
+                assert parent in inner[:inner.index(child)], path
+
+
 def test_sharded_trainer_names_its_program_and_span(rec):
     from tensorlink_tpu.parallel.engine import ShardedTrainer
 
