@@ -29,6 +29,7 @@ from tensorlink_tpu.nn.mla import LatentAttention
 from tensorlink_tpu.nn.moe import HeldExpertsMoE
 from tensorlink_tpu.nn.module import Module, Sequential
 from tensorlink_tpu.nn.transformer import FeedForward
+from tensorlink_tpu.ops.kda import KEPT
 from tensorlink_tpu.runtime.tracing import scope
 
 
@@ -66,7 +67,8 @@ class KimiLinearConfig:
     moe_row_bound: int | None = None
     rms_eps: float = 1e-5
     # recompute each block in the backward pass instead of keeping its
-    # activations
+    # activations, but for a KDA block's scan output (ops/kda.py::KEPT,
+    # float32 [B,T,H,d]): the recompute then holds no pass of the scan
     remat: bool = False
 
     @classmethod
@@ -171,7 +173,10 @@ class KimiLinear(Module):
         for name, block in ch["blocks"].children.items():
             run = block.apply
             if self.cfg_obj.remat:
-                run = jax.checkpoint(run)
+                run = jax.checkpoint(
+                    run,
+                    policy=jax.checkpoint_policies.save_only_these_names(KEPT),
+                )
             x = run(params["blocks"][name], x)
         with scope("head"):
             x = ch["norm_f"].apply(params["norm_f"], x)

@@ -12,9 +12,11 @@ gated per-head norm on the way out:
     y = (RMSNorm_head(o) * sigmoid((x W_ga) W_gb)) W_o
 
 The recurrence runs in chunks (``ops/kda.py``) under the scope
-``tl.kda.scan``; the caller's scope (``tl.kda``) holds the rest. The
-state is float32 and lives inside the call: there is no cache, so no
-decode path (``cache=`` is refused).
+``tl.kda.scan``; the caller's scope (``tl.kda``) holds the rest. Its
+output o carries the name ``ops/kda.py::KEPT``, so a block remat that
+saves that name recomputes everything here but the scan. The state is
+float32 and lives inside the call: there is no cache, so no decode path
+(``cache=`` is refused).
 """
 
 from __future__ import annotations

@@ -42,7 +42,13 @@ solve and S in float32.
 Memory: a row of 4,096 tokens at 32 heads of 128 holds some 1.2 GB of
 these intermediates in float32, so the batch goes through a row at a
 time (``lax.map``), each row recomputed in the backward pass: what is
-kept between the passes is q, k, v, g and beta.
+kept between the passes is q, k, v, g and beta. The result carries the
+name ``KEPT`` (``checkpoint_name``), an identity unless an enclosing
+``jax.checkpoint`` has a policy that saves it: under such a one
+(``models/kimi_linear.py``'s block remat) o is kept too, 268 MB a layer
+at that shape, and the block's recompute holds no pass of the scan. A
+layer then runs the forward F, the row's F and the backward B; under a
+plain ``jax.checkpoint`` around the caller it would run F three times.
 """
 
 from __future__ import annotations
@@ -51,9 +57,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 CHUNK = 64
 SUB = 16
+# the name kda_chunked's result carries, for a remat policy to save
+KEPT = "kda_scan_o"
 
 
 def _mm(spec, a, b, dtype):
@@ -97,7 +106,7 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = CHUNK, sub: int = SUB):
     o = jax.lax.map(lambda x: row(*x), tuple(
         x.reshape(B, 1, *x.shape[1:]) for x in (q, k, v, g, beta)
     ))
-    return o.reshape(B, *o.shape[2:])
+    return checkpoint_name(o.reshape(B, *o.shape[2:]), KEPT)
 
 
 def _chunked(q, k, v, g, beta, *, chunk: int, sub: int):

@@ -46,6 +46,13 @@ def loss_fn(module, params, batch, rng):
     )
 
 
+def _flat(tree):
+    return {
+        weights.path_str(p): x
+        for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]
+    }
+
+
 @pytest.fixture(scope="module")
 def both():
     """Program and reference on one seeded tree and batch: logits, loss
@@ -62,13 +69,9 @@ def both():
         ref_loss, ref_grads = ref.loss_and_grads(params, ids, REF_CFG, 1)
         logits = model.apply(params, batch["input_ids"])
         ref_logits = ref.logits_fn(params, batch["input_ids"], REF_CFG)
-    flat = lambda t: {  # noqa: E731
-        weights.path_str(p): x
-        for p, x in jax.tree_util.tree_flatten_with_path(t)[0]
-    }
     return {
         "logits": (logits, ref_logits), "loss": (loss, ref_loss),
-        "grads": (flat(grads), flat(ref_grads)), "model": model,
+        "grads": (_flat(grads), _flat(ref_grads)), "model": model,
         "params": params, "batch": batch,
     }
 
@@ -173,6 +176,71 @@ def test_trains_through_the_trainer(remat):
     losses = _four_steps(remat)
     assert losses[-1] < losses[0] - 0.05, losses
     assert losses[0] == pytest.approx(_four_steps(False)[0], rel=1e-6)
+
+
+def _grad_of(both, remat):
+    model = KimiLinear(dataclasses.replace(TINY, remat=remat))
+    return jax.grad(lambda p: loss_fn(model, p, both["batch"], None))
+
+
+def _count(jaxpr, primitive):
+    """Equations of that primitive, nested jaxprs included (a scan's
+    body once, however many steps it takes)."""
+    return sum(
+        (e.primitive.name == primitive) + sum(
+            _count(sub, primitive)
+            for sub in jax.core.jaxprs_in_params(e.params))
+        for e in jaxpr.eqns
+    )
+
+
+def _plain_checkpoint(monkeypatch):
+    """``KimiLinear``'s block remat as a plain ``jax.checkpoint``: a
+    policy of None is its default, which keeps nothing."""
+    monkeypatch.setattr(
+        jax.checkpoint_policies, "save_only_these_names", lambda *_: None)
+
+
+@pytest.mark.parametrize("blocks,solves", [
+    ("kept", 16), ("recomputed whole", 16), ("plain checkpoint", 20),
+])
+def test_block_remat_adds_no_pass_of_the_scan(both, monkeypatch, blocks, solves):
+    """The scan's passes, read off the gradient's jaxpr by its solve. A
+    KDA layer holds four (forward, the row's recompute, two in its
+    backward), with or without ``remat``: the block's recompute finds
+    the scan's output kept. A plain ``jax.checkpoint`` of each block,
+    which keeps no name, runs the forward once more a layer."""
+    if blocks == "plain checkpoint":
+        _plain_checkpoint(monkeypatch)
+    grad = _grad_of(both, remat=blocks != "recomputed whole")
+    text = jax.make_jaxpr(grad)(both["params"])
+    assert _count(text.jaxpr, "triangular_solve") == solves
+
+
+@pytest.fixture(scope="module")
+def remat_grads(both):
+    return _flat(_grad_of(both, True)(both["params"]))
+
+
+@pytest.mark.parametrize("against,rel", [
+    ("plain checkpoint", 0.0), ("recomputed nothing", 1e-5),
+])
+def test_block_remat_changes_no_gradient(
+        both, remat_grads, monkeypatch, against, rel):
+    """float32 on the CPU, op by op. What the block's recompute reads
+    back is the value it would have computed: every leaf's gradient
+    equals, bit for bit, that of a plain ``jax.checkpoint`` of each
+    block. Against no remat at all (the fixture's) it stands where that
+    one does, 3e-6 of a leaf's norm at the most: a checkpoint's body is
+    compiled as one program, which XLA fuses."""
+    if against == "plain checkpoint":
+        _plain_checkpoint(monkeypatch)
+        want = _flat(_grad_of(both, True)(both["params"]))
+    else:
+        want = both["grads"][0]
+    for leaf in LEAVES:
+        gap = float(jnp.linalg.norm(remat_grads[leaf] - want[leaf]))
+        assert gap <= rel * float(jnp.linalg.norm(want[leaf])), (leaf, gap)
 
 
 def test_too_many_routes_for_the_rows_is_a_nonfinite_step(both):
