@@ -37,12 +37,9 @@ from pathlib import Path
 
 import numpy as np
 
+from benchmark.harness import gate_reasons, kernels_in
+
 SEED = 0
-# the names the repo's Pallas kernels carry in a compiled program
-KERNELS = (
-    "tl_paged_decode", "tl_decode_glue",
-    "tl_flash_fwd", "tl_flash_bwd_dq", "tl_flash_bwd_dkv",
-)
 # bf16 keeps 8 bits of mantissa: one ulp is 2**-8 of the value. The
 # kernels feed the MXU bf16 (flash rounds its probabilities to bf16
 # before the PV product) and round their result to bf16; the
@@ -78,30 +75,12 @@ def finish(phase: str, facts: dict, failures: list[str]) -> dict:
     return facts
 
 
-def kernels_in(compiled_text: str) -> list[str]:
-    """The repo's kernels present as TPU custom calls in a program."""
-    return sorted({
-        k for line in compiled_text.splitlines()
-        if "tpu_custom_call" in line for k in KERNELS if k in line
-    })
-
-
 def decode_kernels(sched) -> list[str]:
     """The kernels in a serving engine's compiled decode program (a
     second lower + compile of the program the engine runs: with the
     persistent cache on, a read)."""
     decode = next(p for p in sched.audit_programs() if p["name"] == "decode")
     return kernels_in(decode["lower"]().compile().as_text())
-
-
-def gate_reasons() -> list[str]:
-    """Why kernel gates closed so far (ops/pallas gate_closed events)."""
-    from tensorlink_tpu.runtime.flight import default_recorder
-
-    return sorted({
-        f"{e['attrs']['kernel']}: {e['attrs']['reason']}"
-        for e in default_recorder().events(kind="kernel.gate_closed")
-    })
 
 
 def greedy_consistency(model, params, prompts, outs) -> dict:

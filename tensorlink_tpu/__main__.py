@@ -153,7 +153,7 @@ async def _run_role(role: str, args) -> None:
         await node.stop()
 
 
-def _cmd_info() -> int:
+def _cmd_info(args) -> int:
     import jax
 
     from tensorlink_tpu.runtime.mesh import local_device_info
@@ -169,7 +169,7 @@ def _cmd_info() -> int:
     return 0
 
 
-async def _cmd_demo() -> int:
+async def _demo() -> int:
     """Minimum end-to-end slice (SURVEY §7.4) in one process."""
     import jax
     import jax.numpy as jnp
@@ -238,6 +238,38 @@ async def _cmd_demo() -> int:
     return 0
 
 
+def _cmd_demo(args) -> int:
+    return asyncio.run(_demo())
+
+
+def _cmd_keygen(args) -> int:
+    from tensorlink_tpu.p2p.crypto import Identity
+
+    for role in args.roles.split(","):
+        ident = Identity.load_or_generate(args.key_dir, role.strip())
+        print(f"{role.strip()}: {ident.node_id}")
+    return 0
+
+
+def _cmd_role(args) -> int:
+    try:
+        asyncio.run(_run_role(args.cmd, args))
+    except KeyboardInterrupt:
+        pass
+    return 0
+
+
+# every subcommand, and the function of this module that runs it
+COMMANDS = {
+    "worker": _cmd_role,
+    "validator": _cmd_role,
+    "user": _cmd_role,
+    "info": _cmd_info,
+    "demo": _cmd_demo,
+    "keygen": _cmd_keygen,
+}
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(prog="tensorlink_tpu")
     sub = ap.add_subparsers(dest="cmd", required=True)
@@ -259,7 +291,6 @@ def main(argv: list[str] | None = None) -> int:
             )
     sub.add_parser("info", help="local devices and capacity")
     sub.add_parser("demo", help="in-process end-to-end training demo")
-    sub.add_parser("bench", help="run the repo benchmark (prints one JSON line)")
     kp = sub.add_parser(
         "keygen",
         help="pre-generate per-role RSA identities (the reference does this "
@@ -270,34 +301,7 @@ def main(argv: list[str] | None = None) -> int:
     kp.add_argument("--roles", default="worker,validator,user",
                     help="comma-separated roles to generate keys for")
     args = ap.parse_args(argv)
-
-    if args.cmd == "keygen":
-        from tensorlink_tpu.p2p.crypto import Identity
-
-        for role in args.roles.split(","):
-            ident = Identity.load_or_generate(args.key_dir, role.strip())
-            print(f"{role.strip()}: {ident.node_id}")
-        return 0
-    if args.cmd == "info":
-        return _cmd_info()
-    if args.cmd == "demo":
-        return asyncio.run(_cmd_demo())
-    if args.cmd == "bench":
-        import runpy
-        import os
-
-        sys.argv = ["bench.py"]
-        runpy.run_path(
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "bench.py"),
-            run_name="__main__",
-        )
-        return 0
-    try:
-        asyncio.run(_run_role(args.cmd, args))
-    except KeyboardInterrupt:
-        pass
-    return 0
+    return COMMANDS[args.cmd](args)
 
 
 if __name__ == "__main__":

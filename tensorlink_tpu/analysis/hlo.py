@@ -143,11 +143,12 @@ COLLECTIVE_KINDS = (
 )
 
 # one optimized-HLO instruction: `%name = <type>[dims]{layout} op(...)`
-# (tuple results open with '('; the FIRST element type is captured)
+# (tuple results open with '('; the FIRST element type is captured; a
+# tuple of more than five elements carries `/*index=5*/` marks)
 _HLO_OP_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s+=\s+\(?\(?\s*"
     r"([a-z][a-z0-9]*)\[([0-9,]*)\]"     # result element type + dims
-    r"[^=]*?"
+    r"(?:[^=]|/\*index=\d+\*/)*?"
     r"\s([a-z][a-z0-9\-]*)\("            # op mnemonic
 )
 

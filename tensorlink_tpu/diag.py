@@ -4,12 +4,10 @@
 ``/healthz``, ``/metrics`` (JSON + Prometheus), ``/spans``, ``/events``,
 and ``/node`` from a list of node status ports into ONE diagnostic
 bundle, prints a cluster health table (dead/unhealthy nodes, stale
-heartbeats, stragglers, anomaly counts), and diffs ``BENCH_r*.json``
-pairs for step-time/throughput regressions:
+heartbeats, stragglers, anomaly counts), and diffs two tlhlo manifests:
 
     tldiag scrape 127.0.0.1:8080 worker-1:8080 -o bundle.json
     tldiag table bundle.json
-    tldiag bench-diff BENCH_r04.json BENCH_r05.json --threshold 0.05
     tldiag manifest-diff hlo.manifest.json /tmp/new-manifest.json
 
 ``manifest-diff`` reviews a tlhlo (analysis/hlo.py) manifest
@@ -30,7 +28,6 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
-import re
 import sys
 import time
 from typing import Any
@@ -362,82 +359,14 @@ def render_table(rows: list[dict[str, Any]]) -> str:
     return "\n".join(lines)
 
 
-# ------------------------------------------------------- bench diffing
-# key fragments that say which way "good" points; everything else is
-# reported as a delta without a regression verdict
-_HIGHER_BETTER = (
-    "samples_per_sec", "tokens_per_sec", "mfu", "speedup", "throughput",
-    "fraction_attained", "vs_baseline", "tick_over_dispatch",
-    # continuous-vs-static serving ratio: 1.0 = parity, higher = the
-    # scheduler beats the static batch
-    "vs_static",
-    # paged KV cache: prefix sharing served MORE prompt tokens from
-    # resident blocks
-    "hit_rate",
-    # speculative decoding: more accepted draft tokens per target
-    # weight pass / higher acceptance = more tokens per weight read
-    # (the decode-roofline lever); vs_nonspec is spec-over-baseline
-    "tokens_per_weight_pass", "acceptance_rate", "vs_nonspec",
-    # adaptive speculation: the controller's wall-clock win over the
-    # best hand-tuned static K on the same mixed workload (> 1.0 =
-    # the measure->adapt loop pays)
-    "vs_best_static",
-    # device-time telemetry: model-bandwidth utilization and the
-    # measured chip HBM bandwidth (capability_hbm_gbps) — more of
-    # either is strictly better ("mfu" already matches above)
-    "mbu", "gbps",
-    # disaggregated serving: tokens/s of the split prefill/decode path
-    # over the colocated baseline (1.0 = parity; the wire-byte TOTAL
-    # stays deliberately directionless — payload size is a property of
-    # the workload — but per-token wire bytes and the KV footprint
-    # ratios are regression axes now that int8 pools exist to shrink
-    # them, see _LOWER_BETTER_RE)
-    "vs_colocated",
-    # pipeline-sharded serving: chain tokens/s over the single-node
-    # paged baseline on the same traffic (1.0 = parity; > 1.0 = the
-    # in-flight microbatching hides the hop latency)
-    "vs_single_node",
-)
-_LOWER_BETTER_RE = re.compile(
-    r"(_s$|_s_per_call$|seconds|latency|bubble_frac|drop_fraction"
-    # serving latency percentiles (TTFT/TPOT histograms) and the int8
-    # quality KL: smaller is better even where the unit suffix differs
-    r"|ttft|tpot|(^|_)kl(_|$)"
-    # paged KV cache at fixed bench traffic: fewer blocks / lower pool
-    # pressure / fewer re-prefilled tokens = the sharing is working
-    r"|kv_blocks|kv_pool_utilization|prefilled_tokens|cow_copies"
-    # ISSUE 20 (int8 KV blocks): at fixed traffic, a smaller paged-
-    # over-contiguous footprint ratio and fewer wire bytes per token
-    # are the quantization win (the _total wire key stays undirected —
-    # it scales with workload). decode_mbu_* and the kernel-vs-xla
-    # tokens/sec ratio ride the existing higher-better fragments.
-    r"|kv_footprint|kv_wire_bytes_per_token"
-    # speculation at fixed traffic: fewer n-gram misses = the lookup
-    # is finding real recurrences
-    r"|preempt|spec_fallback"
-    # overload robustness (serving_under_load round): shed load and
-    # missed deadlines at fixed offered traffic are pure degradation,
-    # as is INTERACTIVE p99 growing over its uncontended baseline
-    r"|shed_rate|shed_total|deadline_miss|p99_degradation"
-    # device-time telemetry: host-gap (pipeline bubble) fraction and
-    # the measured always-on timing overhead — both pure waste
-    r"|host_gap|overhead_frac"
-    # work-receipt auditing (runtime/ledger.py): flagged/rejected
-    # receipts at fixed traffic are integrity failures, not volume
-    r"|anomal)"
-)
-
-
-def _direction(key: str) -> str | None:
-    k = key.lower()
-    leaf = k.rsplit(".", 1)[-1]
-    if leaf == "value" or any(t in k for t in _HIGHER_BETTER):
-        return "higher"
-    if _LOWER_BETTER_RE.search(leaf):
-        return "lower"
-    return None
-
-
+# ---------------------------------------------------- manifest diffing
+# tlhlo's hlo.manifest.json (analysis/hlo.py) pins per-program compiled
+# facts; this diff says which way each one MOVED between two manifests —
+# the review tool for a --write-manifest regeneration ("what did my
+# change do to the compiled programs?"). Memory and collective bytes
+# are measurements (lower is better, judged at a threshold); alias /
+# donated / program-set facts are EXACT — any change is a verdict, and
+# a SHRUNK alias count is always a regression (a dropped donation).
 def _flatten_numeric(d: Any, prefix: str = "") -> dict[str, float]:
     out: dict[str, float] = {}
     if isinstance(d, dict):
@@ -450,94 +379,6 @@ def _flatten_numeric(d: Any, prefix: str = "") -> dict[str, float]:
     return out
 
 
-def _bench_payload(rec: dict) -> dict:
-    """Committed BENCH_r*.json wraps the bench's JSON line under
-    ``parsed`` (driver metadata around it); accept the wrapper, the raw
-    bench output, and — when ``parsed`` is null — the bench line
-    embedded in the captured ``tail`` text."""
-    inner = rec.get("parsed")
-    if isinstance(inner, dict):
-        return inner
-    tail = rec.get("tail")
-    if isinstance(tail, str):
-        for line in reversed(tail.strip().splitlines()):
-            i = line.find('{"metric"')
-            if i >= 0:
-                try:
-                    return json.loads(line[i:])
-                except ValueError:
-                    break  # front-truncated tail: unrecoverable
-    return rec
-
-
-def bench_diff(
-    old: dict, new: dict, threshold: float = 0.05
-) -> dict[str, Any]:
-    """Per-key relative deltas between two bench records (BENCH_r*.json
-    shape). A key regresses when it moved AGAINST its direction by more
-    than ``threshold`` (5% default); direction-less keys only report.
-    This is a report, never a failure — CI policy belongs to the
-    caller."""
-    a = _flatten_numeric(_bench_payload(old))
-    b = _flatten_numeric(_bench_payload(new))
-    keys: dict[str, Any] = {}
-    regressions: list[str] = []
-    improvements: list[str] = []
-    for k in sorted(set(a) & set(b)):
-        if a[k] == 0:
-            continue  # no meaningful relative delta
-        delta = (b[k] - a[k]) / abs(a[k])
-        direction = _direction(k)
-        rec = {
-            "old": a[k],
-            "new": b[k],
-            "delta_frac": round(delta, 4),
-            "direction": direction,
-        }
-        if direction is not None and abs(delta) > threshold:
-            worse = delta < 0 if direction == "higher" else delta > 0
-            rec["regression"] = worse
-            (regressions if worse else improvements).append(k)
-        keys[k] = rec
-    return {
-        "threshold": threshold,
-        "keys": keys,
-        "regressions": regressions,
-        "improvements": improvements,
-        "only_old": sorted(set(a) - set(b)),
-        "only_new": sorted(set(b) - set(a)),
-    }
-
-
-def render_bench_diff(diff: dict) -> str:
-    lines = [
-        f"bench diff (threshold {diff['threshold']:.0%}): "
-        f"{len(diff['regressions'])} regression(s), "
-        f"{len(diff['improvements'])} improvement(s)"
-    ]
-    for k in diff["regressions"]:
-        r = diff["keys"][k]
-        lines.append(
-            f"  REGRESSION {k}: {r['old']:g} -> {r['new']:g} "
-            f"({r['delta_frac']:+.1%})"
-        )
-    for k in diff["improvements"]:
-        r = diff["keys"][k]
-        lines.append(
-            f"  improved   {k}: {r['old']:g} -> {r['new']:g} "
-            f"({r['delta_frac']:+.1%})"
-        )
-    return "\n".join(lines)
-
-
-# ---------------------------------------------------- manifest diffing
-# tlhlo's hlo.manifest.json (analysis/hlo.py) pins per-program compiled
-# facts; this diff says which way each one MOVED between two manifests —
-# the review tool for a --write-manifest regeneration ("what did my
-# change do to the compiled programs?"). Memory and collective bytes
-# are measurements (lower is better, judged at a threshold); alias /
-# donated / program-set facts are EXACT — any change is a verdict, and
-# a SHRUNK alias count is always a regression (a dropped donation).
 _MANIFEST_LOWER_BETTER = (
     "temp_bytes", "argument_bytes", "output_bytes",
     "f32_dot", "f32_convert", "host_calls",
@@ -747,38 +588,6 @@ def render_proto_diff(diff: dict) -> str:
             "  rolling upgrade: UNSAFE — drain the fleet or version-gate"
         )
     return "\n".join(lines)
-
-
-def latest_bench_record(root: str) -> tuple[str, dict] | None:
-    """Newest USABLE committed BENCH_r*.json under ``root`` (descending
-    round order; a round whose payload has no headline value or recorded
-    an error — failed run, truncated capture — is skipped so bench.py
-    never diffs a real run against noise). Returns (filename, record)
-    or None."""
-    import os
-
-    try:
-        names = os.listdir(root)
-    except OSError:
-        return None
-    rounds = sorted(
-        (
-            (int(m.group(1)), name)
-            for name in names
-            if (m := re.fullmatch(r"BENCH_r(\d+)\.json", name))
-        ),
-        reverse=True,
-    )
-    for _, name in rounds:
-        try:
-            with open(os.path.join(root, name)) as f:
-                rec = json.load(f)
-        except (OSError, ValueError):
-            continue
-        payload = _bench_payload(rec)
-        if payload.get("value") and "error" not in payload:
-            return name, rec
-    return None
 
 
 # ------------------------------------------------------- /profile pull
@@ -1219,16 +1028,6 @@ def main(argv: list[str] | None = None) -> int:
     tb.add_argument("bundle", help="bundle JSON from `tldiag scrape -o`")
     tb.add_argument("--stale-heartbeat-s", type=float, default=30.0)
     tb.add_argument("--skew-threshold", type=float, default=1.5)
-    bd = sub.add_parser(
-        "bench-diff", help="flag regressions between two BENCH_r*.json"
-    )
-    bd.add_argument("old")
-    bd.add_argument("new")
-    bd.add_argument("--threshold", type=float, default=0.05,
-                    help="relative delta beyond which a directional key "
-                         "counts as moved (default 5%%)")
-    bd.add_argument("--json", action="store_true", dest="as_json",
-                    help="print the full diff as JSON")
     pf = sub.add_parser(
         "profile",
         help="trigger a bounded jax.profiler capture on one node "
@@ -1332,14 +1131,6 @@ def main(argv: list[str] | None = None) -> int:
         print(render_table(cluster_table(
             bundle, args.stale_heartbeat_s, args.skew_threshold
         )))
-        return 0
-    if args.cmd == "bench-diff":
-        with open(args.old) as f:
-            old = json.load(f)
-        with open(args.new) as f:
-            new = json.load(f)
-        diff = bench_diff(old, new, args.threshold)
-        print(json.dumps(diff) if args.as_json else render_bench_diff(diff))
         return 0
     if args.cmd == "profile":
         rec = asyncio.run(fetch_profile(args.target, args.ms, args.timeout))

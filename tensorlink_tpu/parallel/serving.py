@@ -559,11 +559,17 @@ class ContinuousBatchingEngine:
 
             state = jax.tree.map(shard, state)
         else:
-            # COMMIT the fresh state: uncommitted jnp.zeros avals differ
-            # from the committed arrays every program emits, so the very
-            # first dispatch would trace a second copy of each program
-            state = jax.tree.map(jax.device_put, state)
+            state = self._on_mesh(state)
         return state
+
+    def _on_mesh(self, state):
+        """Fresh state placed as every program's output is: on the
+        engine's mesh. A ``jnp.zeros`` carries no mesh in its type and
+        a program's output does, so a program whose first call took
+        fresh state was traced and compiled a second time on its next
+        (``jax.device_put`` without a target places and commits
+        nothing)."""
+        return jax.device_put(state, NamedSharding(self.engine.mesh, P()))
 
     def _add_spec_state(self, state: dict) -> None:
         """Speculation state riding the donated serving tree: a per-slot
@@ -2505,9 +2511,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # CONTIGUOUS per slot even here (the draft is small — paging it
         # would buy little and cost a second block-table program)
         self._add_spec_state(state)
-        # commit (see the contiguous _init_state): fresh-vs-committed
-        # aval mismatch would double-trace every block-table program
-        return jax.tree.map(jax.device_put, state)
+        return self._on_mesh(state)
 
     # ------------------------------------------------------------- programs
     def _build_prefill_chunk(self):

@@ -2,9 +2,8 @@
 
 The reference's observability is per-peer message counters and debug prints
 (src/p2p/smart_node.py:855-876). Here: structured per-step metrics — loss,
-samples/sec/chip, pipeline-bubble %, step latency — the BASELINE.json
-metric set — plus a lightweight rolling aggregator a node can publish over
-its HTTP status endpoint.
+pipeline-bubble %, step latency — plus a lightweight rolling aggregator a
+node can publish over its HTTP status endpoint.
 """
 
 from __future__ import annotations
@@ -210,8 +209,3 @@ class Metrics:
             lines.append(f"{p}_sum {h.sum}")
             lines.append(f"{p}_count {h.n}")
         return "\n".join(lines) + "\n"
-
-
-def throughput(samples: int, seconds: float, chips: int = 1) -> float:
-    """samples/sec/chip — headline metric per BASELINE.json."""
-    return samples / seconds / max(chips, 1) if seconds > 0 else math.nan

@@ -41,45 +41,6 @@ def trace(log_dir: str = "/tmp/tensorlink_tpu_trace") -> Iterator[str]:
         yield log_dir
 
 
-def roofline(
-    *,
-    flops_per_step: float,
-    hbm_bytes_per_step: float,
-    peak_tflops: float,
-    hbm_gbps: float,
-    measured_step_s: float | None = None,
-) -> dict:
-    """Two-line roofline: which wall does this program lean on?
-
-    ``hbm_bytes_per_step`` should be the program's main-memory traffic
-    (XLA cost_analysis 'bytes accessed' of the step, or an analytic
-    params+activations+optimizer estimate). Returns the compute-bound
-    and bandwidth-bound time floors, the arithmetic intensity vs the
-    machine's ridge point, and — when a measured step time is given —
-    the fraction of the BINDING floor actually achieved (a principled
-    "is the residual bandwidth?" answer, VERDICT r3 weak: publish the
-    profile or the ceiling)."""
-    t_compute = flops_per_step / (peak_tflops * 1e12)
-    t_memory = hbm_bytes_per_step / (hbm_gbps * 1e9)
-    intensity = flops_per_step / max(hbm_bytes_per_step, 1.0)
-    ridge = peak_tflops * 1e12 / (hbm_gbps * 1e9)  # FLOP/byte at the knee
-    floor = max(t_compute, t_memory)
-    out = {
-        "t_compute_floor_s": t_compute,
-        "t_memory_floor_s": t_memory,
-        "arithmetic_intensity_flop_per_byte": intensity,
-        "ridge_flop_per_byte": ridge,
-        "bound": "compute" if t_compute >= t_memory else "memory",
-        # the MFU ceiling the floors imply — independent of any
-        # measurement, useful for pre-run planning
-        "attainable_mfu_at_floor": flops_per_step / floor / (peak_tflops * 1e12),
-    }
-    if measured_step_s is not None:
-        out["measured_step_s"] = measured_step_s
-        out["fraction_of_binding_floor"] = floor / measured_step_s
-    return out
-
-
 def parse_op_breakdown(trace_events: list, lane: str = "XLA Ops") -> dict:
     """Aggregate a Chrome-trace event list (the ``trace.json.gz`` a
     jax.profiler capture writes) into per-HLO-category device time.

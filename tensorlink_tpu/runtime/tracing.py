@@ -34,8 +34,6 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-import functools
-import inspect
 import threading
 import time
 import uuid
@@ -254,9 +252,6 @@ class Tracer:
         with tracer.span("train_step", {"step": 3}):
             ...                        # child spans nest automatically
 
-        @tracer.trace("recruit")
-        async def recruit(...): ...    # decorator (sync or async)
-
     A span opened while another is active becomes its child (same
     trace_id); ``remote=`` instead parents onto a wire context received
     from a peer, which is how cross-node chains stitch.
@@ -306,32 +301,6 @@ class Tracer:
         """A recorded span that is also a profiler annotation: see
         :func:`region`."""
         return region(name, self, remote=remote, **(attrs or {}))
-
-    def trace(
-        self, name: str | None = None, attrs: dict | None = None
-    ) -> Callable:
-        """Decorator form of :meth:`span`; works on sync and async
-        callables, span named after the function unless given."""
-
-        def deco(fn):
-            label = name or fn.__qualname__
-            if inspect.iscoroutinefunction(fn):
-
-                @functools.wraps(fn)
-                async def awrap(*a, **kw):
-                    with self.span(label, attrs):
-                        return await fn(*a, **kw)
-
-                return awrap
-
-            @functools.wraps(fn)
-            def wrap(*a, **kw):
-                with self.span(label, attrs):
-                    return fn(*a, **kw)
-
-            return wrap
-
-        return deco
 
     def finish_span(self, s: Span, status: str = "ok") -> Span:
         """Close and record a span obtained from :meth:`start_span`

@@ -6,6 +6,9 @@ is an 8-device virtual CPU mesh so DP/PP/TP/SP paths run hermetically.
 The environment is set before jax is imported; nothing else is needed.
 """
 
+import collections
+import contextlib
+import functools
 import os
 
 # tests run on the CPU whatever the machine holds; set before jax is
@@ -48,6 +51,54 @@ def devices():
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+class ProgramCounts:
+    """How often each jitted program was traced and compiled, by the
+    function's name (``tl_decode``, ``tl_spec_chunk``, ...).
+
+    ``traces`` counts runs of a program's Python body: :meth:`jit`
+    stands in for ``jax.jit`` and bumps the counter from inside the
+    traced function. ``compiles`` counts XLA compilations as
+    ``jax.monitoring`` reports them (:meth:`on_event`). A jitted
+    call's ``_cache_size()`` is neither: it counts dispatch signatures,
+    and two placements of one argument are two of those at no trace and
+    no compile."""
+
+    def __init__(self, jit):
+        self.traces = collections.Counter()
+        self.compiles = collections.Counter()
+        self._jit = jit
+
+    def jit(self, fn, **kw):
+        @functools.wraps(fn)
+        def traced(*a, **k):
+            self.traces[fn.__name__] += 1
+            return fn(*a, **k)
+
+        return self._jit(traced, **kw)
+
+    def on_event(self, event, duration, fun_name="", **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compiles[fun_name.removeprefix("jit(").removesuffix(")")] += 1
+
+    def clear(self):
+        self.traces.clear()
+        self.compiles.clear()
+
+
+@contextlib.contextmanager
+def counting_programs():
+    """Count traces and compiles of every program jitted through
+    ``jax.jit`` inside the block: see :class:`ProgramCounts`."""
+    counts = ProgramCounts(jax.jit)
+    jax.monitoring.register_event_duration_secs_listener(counts.on_event)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "jit", counts.jit)
+        try:
+            yield counts
+        finally:
+            jax.monitoring.unregister_event_duration_listener(counts.on_event)
 
 
 def pytest_configure(config):

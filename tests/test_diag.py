@@ -1,4 +1,4 @@
-"""tldiag (tensorlink_tpu/diag.py): bench diffing, cluster health table,
+"""tldiag (tensorlink_tpu/diag.py): cluster health table, manifest diffing,
 and the end-to-end acceptance scenario — kill a worker mid-job and watch
 the black box light up on every surviving node."""
 
@@ -12,162 +12,13 @@ import pytest
 
 from tensorlink_tpu.config import NodeConfig
 from tensorlink_tpu.diag import (
-    bench_diff,
     cluster_table,
-    latest_bench_record,
     main,
     node_row,
-    render_bench_diff,
     render_table,
     scrape_cluster,
     scrape_node,
 )
-
-# ------------------------------------------------------------ bench diff
-
-
-def test_bench_diff_directions_and_threshold():
-    old = {
-        "value": 1000.0, "mfu": 0.50, "decode_tokens_per_sec": 10000.0,
-        "step_seconds": 0.10, "flops_per_step_xla": 1e12,
-        "roofline": {"t_compute_floor_s": 0.02},
-    }
-    new = {
-        "value": 900.0,              # -10% throughput -> regression
-        "mfu": 0.51,                 # +2% -> inside threshold, no verdict
-        "decode_tokens_per_sec": 12000.0,  # +20% -> improvement
-        "step_seconds": 0.13,        # +30% time -> regression
-        "flops_per_step_xla": 2e12,  # direction-less -> report only
-        "roofline": {"t_compute_floor_s": 0.02},
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {"value", "step_seconds"}
-    assert d["improvements"] == ["decode_tokens_per_sec"]
-    assert d["keys"]["value"]["delta_frac"] == pytest.approx(-0.1)
-    assert d["keys"]["flops_per_step_xla"]["direction"] is None
-    assert "regression" not in d["keys"]["mfu"]
-    text = render_bench_diff(d)
-    assert "REGRESSION value" in text and "improved" in text
-
-
-def test_bench_diff_serving_and_quality_key_directions():
-    """The ISSUE-5 serving/quality keys carry the right verdict
-    direction: tok/s and the continuous-vs-static ratio are
-    higher-better; TTFT/TPOT latencies and the int8 logit KL are
-    lower-better (a 'bigger KL' improvement verdict would bless a
-    quality regression)."""
-    old = {
-        "serving_continuous_tokens_per_sec": 10000.0,
-        "serving_continuous_vs_static": 0.95,
-        "serving_ttft_p50_s": 0.030,
-        "serving_tpot_p99_s": 0.004,
-        "int8_quality": {"logit_kl_mean": 0.001},
-        "seq512_mfu_xla": 0.40,
-    }
-    new = {
-        "serving_continuous_tokens_per_sec": 8000.0,   # -20% -> regression
-        "serving_continuous_vs_static": 1.05,          # +10% -> improvement
-        "serving_ttft_p50_s": 0.050,                   # +67% -> regression
-        "serving_tpot_p99_s": 0.003,                   # -25% -> improvement
-        "int8_quality": {"logit_kl_mean": 0.01},       # 10x KL -> regression
-        "seq512_mfu_xla": 0.50,                        # +25% -> improvement
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {
-        "serving_continuous_tokens_per_sec",
-        "serving_ttft_p50_s",
-        "int8_quality.logit_kl_mean",
-    }
-    assert set(d["improvements"]) == {
-        "serving_continuous_vs_static",
-        "serving_tpot_p99_s",
-        "seq512_mfu_xla",
-    }
-
-
-def test_bench_diff_paged_kv_key_directions():
-    """ISSUE-6 paged-KV keys: prefix hit rate is higher-better; blocks
-    in use / pool utilization / re-prefilled tokens are lower-better at
-    fixed bench traffic (a 'more blocks' improvement verdict would
-    bless a sharing regression)."""
-    old = {
-        "prefix_cache_hit_rate": 0.5,
-        "kv_blocks_in_use": 100,
-        "kv_pool_utilization": 0.40,
-        "serving_paged_prefilled_tokens": 800,
-        "serving_paged_tokens_per_sec": 9000.0,
-    }
-    new = {
-        "prefix_cache_hit_rate": 0.3,               # -40% -> regression
-        "kv_blocks_in_use": 80,                     # -20% -> improvement
-        "kv_pool_utilization": 0.50,                # +25% -> regression
-        "serving_paged_prefilled_tokens": 600,      # -25% -> improvement
-        "serving_paged_tokens_per_sec": 10000.0,    # +11% -> improvement
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {
-        "prefix_cache_hit_rate", "kv_pool_utilization",
-    }
-    assert set(d["improvements"]) == {
-        "kv_blocks_in_use", "serving_paged_prefilled_tokens",
-        "serving_paged_tokens_per_sec",
-    }
-
-
-def test_bench_diff_speculation_key_directions():
-    """ISSUE-7 speculation keys: accepted tokens per weight pass,
-    acceptance rate, spec tok/s, and the spec-vs-nonspec ratio are
-    higher-better; n-gram fallbacks at fixed traffic are lower-better
-    (a 'more misses' improvement verdict would bless a lookup
-    regression)."""
-    old = {
-        "accepted_tokens_per_weight_pass": 2.0,
-        "spec_acceptance_rate": 0.6,
-        "spec_tokens_per_sec": 9000.0,
-        "spec_vs_nonspec": 1.5,
-        "spec_fallback_total": 100,
-    }
-    new = {
-        "accepted_tokens_per_weight_pass": 1.5,  # -25% -> regression
-        "spec_acceptance_rate": 0.7,             # +17% -> improvement
-        "spec_tokens_per_sec": 8000.0,           # -11% -> regression
-        "spec_vs_nonspec": 1.8,                  # +20% -> improvement
-        "spec_fallback_total": 80,               # -20% -> improvement
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {
-        "accepted_tokens_per_weight_pass", "spec_tokens_per_sec",
-    }
-    assert set(d["improvements"]) == {
-        "spec_acceptance_rate", "spec_vs_nonspec", "spec_fallback_total",
-    }
-
-
-def test_bench_diff_adaptive_speculation_key_directions():
-    """ISSUE-12 adaptive-speculation keys: adaptive tok/s and the
-    adaptive-over-best-static ratio are higher-better; the autotune
-    warm start is a latency (lower-better); the mean dispatched K is a
-    workload property, not a quality axis — it must carry NO direction
-    (a 'K went down' regression verdict would punish the controller
-    for correctly adapting to rejection-heavy traffic)."""
-    old = {
-        "spec_adaptive_tokens_per_sec": 9000.0,
-        "spec_adaptive_vs_best_static": 1.2,
-        "autotune_warm_start_s": 0.010,
-        "spec_k_mean": 3.2,
-    }
-    new = {
-        "spec_adaptive_tokens_per_sec": 8000.0,   # -11% -> regression
-        "spec_adaptive_vs_best_static": 0.9,      # -25% -> regression
-        "autotune_warm_start_s": 0.100,           # 10x   -> regression
-        "spec_k_mean": 1.1,                       # no direction
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {
-        "spec_adaptive_tokens_per_sec", "spec_adaptive_vs_best_static",
-        "autotune_warm_start_s",
-    }
-    assert d["keys"]["spec_k_mean"]["direction"] is None
 
 
 def test_node_row_self_healed_replaces_low_accept():
@@ -207,69 +58,6 @@ def test_node_row_self_healed_replaces_low_accept():
     assert "SELF-HEALED(nonspec)" in off["flags"]
     text = render_table([healed, off])
     assert "SELF-HEALED" in text
-
-
-def test_bench_diff_serving_load_key_directions():
-    """The serving_under_load round's keys (ISSUE 14): per-priority
-    TTFT/TPOT p99s, shed rate, deadline misses, and the INTERACTIVE
-    p99 degradation ratio are all lower-better; throughput under load
-    is higher-better; the retry-after honesty ratio is a calibration
-    number (closer to 1 is better in BOTH directions), so it must stay
-    direction-less."""
-    old = {
-        "serving_load_interactive_ttft_p99_s": 0.05,
-        "serving_load_batch_tpot_p99_s": 0.002,
-        "serving_load_shed_rate": 0.20,
-        "serving_load_deadline_miss_total": 4,
-        "serving_load_interactive_p99_degradation": 1.5,
-        "serving_load_tokens_per_sec": 900.0,
-        "serving_load_retry_after_honesty": 1.1,
-        "serving_load_admission_overhead_frac": 0.004,
-    }
-    new = {
-        "serving_load_interactive_ttft_p99_s": 0.08,   # worse
-        "serving_load_batch_tpot_p99_s": 0.001,        # better
-        "serving_load_shed_rate": 0.35,                # worse
-        "serving_load_deadline_miss_total": 1,         # better
-        "serving_load_interactive_p99_degradation": 2.5,  # worse
-        "serving_load_tokens_per_sec": 700.0,          # worse
-        "serving_load_retry_after_honesty": 2.0,       # report only
-        "serving_load_admission_overhead_frac": 0.02,  # worse
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {
-        "serving_load_interactive_ttft_p99_s",
-        "serving_load_shed_rate",
-        "serving_load_interactive_p99_degradation",
-        "serving_load_tokens_per_sec",
-        "serving_load_admission_overhead_frac",
-    }
-    assert set(d["improvements"]) == {
-        "serving_load_batch_tpot_p99_s",
-        "serving_load_deadline_miss_total",
-    }
-    assert d["keys"]["serving_load_retry_after_honesty"]["direction"] is None
-
-
-def test_bench_diff_observability_key_directions():
-    """ISSUE-16 observability keys: the telemetry tax
-    (observability_overhead_frac) and the validator /fleet scrape
-    latency (fleet_scrape_s) are both lower-better — a 'more overhead'
-    improvement verdict would bless the sampler eating the serving
-    budget it is supposed to watch."""
-    old = {
-        "observability_overhead_frac": 0.004,
-        "fleet_scrape_s": 0.010,
-    }
-    new = {
-        "observability_overhead_frac": 0.020,  # worse
-        "fleet_scrape_s": 0.005,               # better
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {"observability_overhead_frac"}
-    assert set(d["improvements"]) == {"fleet_scrape_s"}
-    assert d["keys"]["observability_overhead_frac"]["direction"] == "lower"
-    assert d["keys"]["fleet_scrape_s"]["direction"] == "lower"
 
 
 def test_sparkline_and_check_render():
@@ -371,56 +159,6 @@ def test_node_row_flags_kv_pool_pressure():
     assert calm["kv_pool_pct"] == 10.0 and calm["flags"] == []
     text = render_table([hot, calm])
     assert "KV%" in text and "KV-PRESSURE" in text
-
-
-def test_bench_diff_unwraps_committed_wrapper():
-    """BENCH_r*.json wraps the bench line under `parsed` (or, when the
-    driver failed to parse, leaves it in the captured `tail`)."""
-    payload = {"metric": "m", "value": 100.0}
-    wrapped = {"n": 4, "rc": 0, "parsed": payload}
-    tailed = {
-        "n": 5, "rc": 0, "parsed": None,
-        "tail": "noise line\n" + json.dumps({"metric": "m", "value": 80.0}),
-    }
-    d = bench_diff(wrapped, tailed, threshold=0.05)
-    assert d["keys"]["value"]["old"] == 100.0
-    assert d["keys"]["value"]["new"] == 80.0
-    assert d["regressions"] == ["value"]
-
-
-def test_latest_bench_record_skips_unusable(tmp_path):
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"parsed": {"metric": "m", "value": 50.0}})
-    )
-    # newer but unusable: errored run, then a zero-value run
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps({"parsed": {"value": 0.0, "error": "backend down"}})
-    )
-    (tmp_path / "BENCH_r03.json").write_text("not json")
-    got = latest_bench_record(str(tmp_path))
-    assert got is not None and got[0] == "BENCH_r01.json"
-    assert latest_bench_record(str(tmp_path / "missing")) is None
-
-
-def test_cli_bench_diff_and_table(tmp_path, capsys):
-    a = tmp_path / "a.json"
-    b = tmp_path / "b.json"
-    a.write_text(json.dumps({"metric": "m", "value": 100.0}))
-    b.write_text(json.dumps({"metric": "m", "value": 80.0}))
-    assert main(["bench-diff", str(a), str(b), "--threshold", "0.1"]) == 0
-    out = capsys.readouterr().out
-    assert "REGRESSION value" in out
-    assert main(["bench-diff", str(a), str(b), "--json"]) == 0
-    parsed = json.loads(capsys.readouterr().out)
-    assert parsed["regressions"] == ["value"]
-
-    bundle = tmp_path / "bundle.json"
-    bundle.write_text(json.dumps({
-        "nodes": [{"target": "10.0.0.1:8080", "error": "ConnectionRefused"}]
-    }))
-    assert main(["table", str(bundle)]) == 0
-    out = capsys.readouterr().out
-    assert "DEAD" in out and "10.0.0.1:8080" in out
 
 
 def _manifest(programs):
@@ -815,93 +553,6 @@ def test_node_row_mfu_bubble_and_host_bound_flag():
     assert bare["mfu_pct"] is None and bare["bubble_pct"] is None
 
 
-def test_bench_diff_devtime_key_directions():
-    """ISSUE-13 bench keys: MFU/MBU and the measured chip bandwidth
-    are higher-better; the host-gap fraction and the always-on timing
-    overhead are pure waste (lower-better)."""
-    old = {
-        "decode_mfu": 0.40, "decode_mbu": 0.70,
-        "capability_hbm_gbps": 800.0,
-        "serving_host_gap_frac": 0.10,
-        "serving_timing_overhead_frac": 0.004,
-    }
-    new = {
-        "decode_mfu": 0.30,              # -25% -> regression
-        "decode_mbu": 0.80,              # +14% -> improvement
-        "capability_hbm_gbps": 600.0,    # -25% -> regression
-        "serving_host_gap_frac": 0.20,   # doubled bubble -> regression
-        "serving_timing_overhead_frac": 0.002,  # cheaper -> improvement
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {
-        "decode_mfu", "capability_hbm_gbps", "serving_host_gap_frac",
-    }
-    assert set(d["improvements"]) == {
-        "decode_mbu", "serving_timing_overhead_frac",
-    }
-
-
-def test_bench_diff_disagg_key_directions():
-    """Disaggregated-serving keys: the vs-colocated ratio is
-    higher-better, the per-leg TTFT decomposition is lower-better, the
-    wire-byte TOTAL is deliberately directionless (payload size scales
-    with the workload) — but per-token wire bytes became lower-better
-    with ISSUE 20: at fixed traffic, int8 pools exist to shrink them,
-    and a diff must flag them creeping back up."""
-    old = {"metric": "x", "serving_disagg_vs_colocated": 1.2,
-           "disagg_ttft_transfer_s": 0.010,
-           "disagg_ttft_prefill_s": 0.020,
-           "kv_wire_bytes_total": 1000, "kv_wire_bytes_per_token": 40.0}
-    new = {"metric": "x", "serving_disagg_vs_colocated": 0.8,
-           "disagg_ttft_transfer_s": 0.030,
-           "disagg_ttft_prefill_s": 0.018,
-           "kv_wire_bytes_total": 9000, "kv_wire_bytes_per_token": 360.0}
-    d = bench_diff(old, new)
-    assert "serving_disagg_vs_colocated" in d["regressions"]
-    assert "disagg_ttft_transfer_s" in d["regressions"]
-    assert "disagg_ttft_prefill_s" in d["improvements"]
-    assert d["keys"]["kv_wire_bytes_total"]["direction"] is None
-    assert "kv_wire_bytes_total" not in d["regressions"]
-    assert d["keys"]["kv_wire_bytes_per_token"]["direction"] == "lower"
-    assert "kv_wire_bytes_per_token" in d["regressions"]
-
-
-def test_bench_diff_paged_kernel_int8_key_directions():
-    """ISSUE-20 keys: KV footprint ratios and per-token wire bytes are
-    lower-better (the int8 win), decode MBU on either paged path and
-    the kernel-vs-XLA tokens/sec ratio are higher-better, and the
-    parity pin carries no direction worth diffing — but a footprint
-    'improvement' verdict on a RISING ratio would bless a quantization
-    regression, which is exactly what these entries prevent."""
-    old = {
-        "kv_footprint_vs_contiguous": 0.40,
-        "kv_footprint_vs_contiguous_int8": 0.20,
-        "kv_wire_bytes_per_token_int8": 20.0,
-        "decode_mbu_paged_xla": 0.50,
-        "decode_mbu_paged_kernel": 0.60,
-        "paged_kernel_vs_xla_tokens_per_sec": 1.2,
-    }
-    new = {
-        "kv_footprint_vs_contiguous": 0.30,         # -25% -> improvement
-        "kv_footprint_vs_contiguous_int8": 0.30,    # +50% -> regression
-        "kv_wire_bytes_per_token_int8": 40.0,       # doubled -> regression
-        "decode_mbu_paged_xla": 0.40,               # -20% -> regression
-        "decode_mbu_paged_kernel": 0.75,            # +25% -> improvement
-        "paged_kernel_vs_xla_tokens_per_sec": 1.5,  # +25% -> improvement
-    }
-    d = bench_diff(old, new, threshold=0.05)
-    assert set(d["regressions"]) == {
-        "kv_footprint_vs_contiguous_int8",
-        "kv_wire_bytes_per_token_int8",
-        "decode_mbu_paged_xla",
-    }
-    assert set(d["improvements"]) == {
-        "kv_footprint_vs_contiguous",
-        "decode_mbu_paged_kernel",
-        "paged_kernel_vs_xla_tokens_per_sec",
-    }
-
-
 def _disagg_scrape(serving, capability=None):
     node_body = {
         "role": "worker", "node_id": "w" * 64, "peers": {},
@@ -1026,3 +677,63 @@ def test_cli_proto_diff(tmp_path, capsys):
     parsed = json.loads(capsys.readouterr().out)
     assert parsed["compatible"] is False
     assert parsed["frames"]["PING"]["t"] == "removed"
+
+
+# ------------------------------------------- one harness, no dead entries
+def _subcommands(main_fn, capsys) -> set[str]:
+    """The subcommands a CLI's ``--help`` lists."""
+    import re
+
+    with pytest.raises(SystemExit):
+        main_fn(["--help"])
+    listed = re.search(r"\{([\w,\-]+)\}", capsys.readouterr().out)
+    return set(listed.group(1).split(","))
+
+
+def test_tldiag_help_lists_no_bench_diff(capsys):
+    cmds = _subcommands(main, capsys)
+    assert {"scrape", "table", "manifest-diff"} <= cmds
+    assert not any("bench" in c for c in cmds)
+
+
+def test_package_cli_subcommands_resolve_inside_the_package(capsys):
+    """``python -m tensorlink_tpu --help`` lists exactly the commands of
+    ``COMMANDS``, and each is run by a function of the package."""
+    from tensorlink_tpu import __main__ as cli
+
+    assert _subcommands(cli.main, capsys) == set(cli.COMMANDS)
+    assert "bench" not in cli.COMMANDS
+    for name, fn in cli.COMMANDS.items():
+        assert fn.__module__ == "tensorlink_tpu.__main__", name
+
+
+def test_package_reads_no_file_of_the_driver_or_outside_itself():
+    """No module of the package opens the judges' and driver's records
+    or runs a script that lies outside the package. String literals
+    only: a comment or docstring may still cite a round."""
+    import ast
+    from pathlib import Path
+
+    import tensorlink_tpu
+
+    banned = ("BENCH_r", "MULTICHIP_r", "BASELINE.json", "VERDICT.md",
+              "ADVICE.md", "bench.py")
+    found = []
+    for path in sorted(Path(tensorlink_tpu.__file__).parent.rglob("*.py")):
+        tree = ast.parse(path.read_text())
+        docstrings = {
+            id(node.body[0].value)
+            for node in ast.walk(tree)
+            if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef,
+                                 ast.AsyncFunctionDef))
+            and node.body and isinstance(node.body[0], ast.Expr)
+            and isinstance(node.body[0].value, ast.Constant)
+        }
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Constant) and isinstance(node.value, str)
+                    and id(node) not in docstrings
+                    and any(b in node.value for b in banned)):
+                found.append(f"{path}:{node.lineno}: {node.value[:60]!r}")
+            if isinstance(node, ast.Attribute) and node.attr == "run_path":
+                found.append(f"{path}:{node.lineno}: runpy.run_path")
+    assert not found, "\n".join(found)

@@ -144,6 +144,26 @@ def test_variadic_sync_collective_records_largest_element():
     assert ir.collective_bytes() == {"all-reduce": 1048576 * 4}
 
 
+def test_tuple_collective_of_more_than_five_elements_is_parsed():
+    """XLA marks every fifth element of a tuple type (``/*index=5*/``);
+    the CPU backend's all-to-all over 8 devices is such a tuple, and
+    a parser that stops at the mark's ``=`` counts no collective."""
+    elems = ", ".join(
+        ("/*index=5*/" if i == 5 else "") + "f32[1,32,1,1,5]{4,3,2,1,0}"
+        for i in range(8)
+    )
+    txt = (
+        "HloModule jit_h, is_scheduled=true\n\n"
+        "ENTRY %main () -> f32[4] {\n"
+        f"  %all-to-all.2 = ({elems}) all-to-all(%a, %b), "
+        "replica_groups={{0,1,2,3,4,5,6,7}}\n"
+        "}\n"
+    )
+    ir = parse_hlo(txt)
+    assert ir.count("all-to-all") == 1
+    assert ir.collective_bytes() == {"all-to-all": 32 * 5 * 4}
+
+
 def test_parse_stablehlo_counts():
     clean = parse_stablehlo(_STABLE_BF16_CLEAN)
     assert clean.f32_dot == 0

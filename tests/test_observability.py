@@ -104,33 +104,6 @@ def test_profiling_helpers():
         assert any(os.scandir(d)), "profiler trace wrote nothing"
 
 
-def test_roofline_floors_and_bound():
-    """Roofline math: floors, ridge, and the binding-wall verdict (the
-    bench's 'is the residual MFU gap bandwidth?' evidence)."""
-    from tensorlink_tpu.runtime.profiling import roofline
-
-    # compute-bound: high intensity vs ridge
-    r = roofline(flops_per_step=1e12, hbm_bytes_per_step=1e9,
-                 peak_tflops=200.0, hbm_gbps=800.0, measured_step_s=0.01)
-    assert r["bound"] == "compute"
-    assert r["t_compute_floor_s"] == pytest.approx(1e12 / 200e12)
-    assert r["t_memory_floor_s"] == pytest.approx(1e9 / 800e9)
-    assert r["arithmetic_intensity_flop_per_byte"] == pytest.approx(1000.0)
-    assert r["ridge_flop_per_byte"] == pytest.approx(250.0)
-    assert r["fraction_of_binding_floor"] == pytest.approx(
-        (1e12 / 200e12) / 0.01
-    )
-    # memory-bound: intensity below the ridge
-    r2 = roofline(flops_per_step=1e9, hbm_bytes_per_step=1e9,
-                  peak_tflops=200.0, hbm_gbps=800.0)
-    assert r2["bound"] == "memory"
-    assert "measured_step_s" not in r2
-    # attainable MFU at the binding floor < 1 when memory-bound
-    r3 = roofline(flops_per_step=1e9, hbm_bytes_per_step=1e9,
-                  peak_tflops=200.0, hbm_gbps=800.0, measured_step_s=1.0)
-    assert r3["attainable_mfu_at_floor"] < 1.0
-
-
 # ------------------------------------------------------------ tracing
 
 
@@ -145,13 +118,8 @@ def test_tracer_nesting_decorator_and_bounds():
             assert inner.parent_id == outer.span_id
     assert current_span() is None
 
-    @t.trace("deco")
-    def f(x):
-        return x + 1
-
-    assert f(1) == 2
     names = [s.name for s in t.spans()]
-    assert names == ["inner", "outer", "deco"]  # recorded at exit
+    assert names == ["inner", "outer"]  # recorded at exit
 
     # error status is stamped and the exception propagates
     with pytest.raises(ValueError):
@@ -171,12 +139,12 @@ def test_tracer_async_decorator_and_remote_parent():
 
     t = Tracer("svc")
 
-    @t.trace()
     async def work():
-        return 7
+        with t.span("work"):
+            return 7
 
     assert asyncio.run(work()) == 7
-    assert t.spans()[-1].name.endswith("work")
+    assert t.spans()[-1].name == "work"
 
     with t.span("child", remote={"trace_id": "abc", "span_id": "def"}) as s:
         assert s.trace_id == "abc" and s.parent_id == "def"

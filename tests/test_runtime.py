@@ -10,7 +10,6 @@ from tensorlink_tpu.runtime.mesh import MeshRuntime, make_mesh, local_device_inf
 from tensorlink_tpu.runtime.metrics import (
     Metrics,
     pipeline_bubble_fraction,
-    throughput,
 )
 
 
@@ -62,7 +61,6 @@ def test_metrics_snapshot():
     snap = m.snapshot()
     assert snap["counters"]["steps"] == 5
     assert snap["loss"]["n"] == 5
-    assert throughput(100, 2.0, 4) == 12.5
 
 
 def test_parse_op_breakdown_synthetic():

@@ -575,6 +575,95 @@ def test_paged_programs_shape_static_across_request_mixes(tiny_engine):
     assert [f._cache_size() for f in progs] == warm
 
 
+@pytest.fixture(scope="module")
+def engine_program_counts(tiny_engine):
+    """Both engines, plain and speculating, each driven through a
+    churned mix of lengths and budgets; the paged one also through a
+    copy-on-write and an imported prefill, so every pool program runs.
+    Per engine: how often each of its programs was traced and
+    compiled."""
+    from conftest import counting_programs
+    from tensorlink_tpu.parallel.serving import SpecConfig
+
+    cfg, m, p, eng = tiny_engine
+    gen = GenerationConfig(max_new_tokens=6)
+    spec = SpecConfig(k=3, rounds=2)
+    paged = dict(
+        slots=2, gen=gen, decode_chunk=2, block_size=4, prefill_chunk=4
+    )
+    contiguous = dict(slots=2, gen=gen, decode_chunk=3, prefill_block=4)
+    r = np.random.default_rng(31)
+
+    def churn(sch):
+        for n in (5, 3, 7, 2, 9, 4, 6):
+            sch.submit(
+                r.integers(0, cfg.vocab_size, (n,)), max_new=int(1 + n % 5)
+            )
+        sch.run_until_idle()
+
+    def pool_traffic(sch):
+        # a prompt that extends a live one's partial tail block: a copy
+        pra = r.integers(0, cfg.vocab_size, (10,))
+        ra = sch.submit(pra)
+        while sch._pending:
+            sch.step()
+        rb = sch.submit(
+            np.concatenate([pra, r.integers(0, cfg.vocab_size, (2,))])
+        )
+        sch.result(ra), sch.result(rb)
+        # a prefill another engine ran: a graft and an adoption, twice
+        for payload in exported:
+            sch.result(sch.import_prefill(payload))
+        churn(sch)
+
+    out = {}
+    with counting_programs() as counts:
+        exporter = PagedContinuousBatchingEngine(eng, **paged)
+        exported = [
+            exporter.prefill_export(r.integers(0, cfg.vocab_size, (n,)))
+            for n in (9, 5)
+        ]
+        for label, cls, kw, drive in (
+            ("contiguous", ContinuousBatchingEngine, contiguous, churn),
+            ("contiguous_spec", ContinuousBatchingEngine,
+             dict(contiguous, speculative=spec), churn),
+            ("paged", PagedContinuousBatchingEngine, paged, pool_traffic),
+            ("paged_spec", PagedContinuousBatchingEngine,
+             dict(paged, speculative=spec), churn),
+        ):
+            counts.clear()
+            drive(cls(eng, **kw))
+            out[label] = (dict(counts.traces), dict(counts.compiles))
+    return out
+
+
+# every jitted program the two engines build (the contiguous prefill
+# is one a bucket), and the engines of the fixture that build it
+ENGINE_PROGRAMS = {
+    "tl_decode": {"contiguous", "paged"},
+    "tl_spec_chunk": {"contiguous_spec", "paged_spec"},
+    "tl_prefill_chunk": {"paged", "paged_spec"},
+    "tl_pool_table": {"paged", "paged_spec"},
+    "tl_pool_retire": {"paged", "paged_spec"},
+    "tl_pool_copy": {"paged"},
+    "tl_pool_graft": {"paged"},
+    "tl_pool_adopt": {"paged"},
+}
+
+
+@pytest.mark.parametrize("name", ENGINE_PROGRAMS)
+def test_engine_program_traced_and_compiled_once(engine_program_counts, name):
+    """One trace and one compilation of each program an engine builds,
+    whatever the traffic: a second costs a compile in production, at
+    real widths tens of seconds with requests waiting."""
+    ran = {
+        label: (traces[name], compiles.get(name, 0))
+        for label, (traces, compiles) in engine_program_counts.items()
+        if name in traces
+    }
+    assert ran == {label: (1, 1) for label in ENGINE_PROGRAMS[name]}
+
+
 def test_paged_chunked_prefill_does_not_stall_decode(tiny_engine):
     """A long arriving prompt prefills in fixed chunks interleaved with
     decode dispatches: the in-flight request keeps gaining tokens WHILE
