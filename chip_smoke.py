@@ -637,6 +637,7 @@ def train_phase(
 # is off by KDA_CARRIED and more.
 KDA_TOL = 0.02
 KDA_CARRIED = 0.25
+KDA_KERNEL = "tl_kda_fwd"  # not among the harness's closed KERNELS
 
 
 def kda_phase(
@@ -651,7 +652,9 @@ def kda_phase(
     from what earlier chunks handed on. (The benchmark's seeded weights
     decay by e^-45 a chunk: its check barely sees the hand-over, PERF.md
     section 7.) ``state_carried`` says how much: the same call with the
-    state forgotten at every chunk's start, against the reference."""
+    state forgotten at every chunk's start, against the reference.
+    ``kernels`` says whether the chunked call's compiled program holds
+    the forward kernel, ``gates_closed`` why it would not."""
     import jax
     import jax.numpy as jnp
 
@@ -701,6 +704,7 @@ def kda_phase(
     names = ("o", "dq", "dk", "dv", "dg", "dbeta")
     gaps = {n: gap(a, b) for n, a, b in zip(names, got, want)}
     carried = gap(jax.jit(forgetful)(*args), want[0])
+    program = jax.jit(chunked).lower(*args).compile().as_text()
     g = args[3]
     facts = {
         "shape": f"{B} x {T} tokens, {H} heads of {d}, chunks of {CHUNK}",
@@ -712,6 +716,13 @@ def kda_phase(
         "gaps": {n: round(x, 6) for n, x in gaps.items()},
         "state_carried": round(carried, 4),
         "limits": {"gap": KDA_TOL, "state_carried_at_least": KDA_CARRIED},
+        "kernels": sorted({
+            KDA_KERNEL for ln in program.splitlines()
+            if "tpu_custom_call" in ln and KDA_KERNEL in ln
+        }),
+        "gates_closed": [
+            r for r in gate_reasons() if r.startswith(KDA_KERNEL + ":")
+        ],
     }
     failures = [
         f"{n} stands {x:.4f} off the recurrence" for n, x in gaps.items()
@@ -915,7 +926,12 @@ def main(argv: list[str] | None = None) -> int:
             failures.append(f"{k} is not in the compiled train step")
     finish("train", facts, failures)
 
-    finish("kda", *kda_phase())
+    facts, failures = kda_phase()
+    # heads of 128 in whole chunks on one chip: the forward is the kernel
+    if KDA_KERNEL not in facts["kernels"]:
+        failures.append(f"{KDA_KERNEL} is not in the chunked call's program")
+    failures += [f"the gate closed: {r}" for r in facts["gates_closed"]]
+    finish("kda", facts, failures)
 
     # a cold directory must have grown; a warm one (a second run on
     # the same machine) is expected to gain nothing for unchanged

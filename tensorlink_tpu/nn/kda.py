@@ -14,7 +14,11 @@ gated per-head norm on the way out:
 The recurrence runs in chunks (``ops/kda.py``) under the scope
 ``tl.kda.scan``; the caller's scope (``tl.kda``) holds the rest. Its
 output o carries the name ``ops/kda.py::KEPT``, so a block remat that
-saves that name recomputes everything here but the scan. The state is
+saves that name recomputes everything here but the scan. On a TPU the
+step's own pass of the scan is the kernel ``tl_kda_fwd``, which reads
+q, k, v, g as [B, T, H*d], the shape the projections and convolutions
+write: the [B, T, H, d] they are reshaped to here for the per-head norms
+is a view the compiler need not lay out. The state is
 float32 and lives inside the call: there is no cache, so no decode path
 (``cache=`` is refused).
 """

@@ -1,6 +1,7 @@
 """Kimi Delta Attention's recurrence in chunks (gated delta rule with a
-per-channel decay; Kimi Team 2025), forward in plain XLA, backward by
-autodiff of the same program.
+per-channel decay; Kimi Team 2025) in plain XLA, backward by autodiff
+of the same program; the primal forward as a Pallas kernel where a gate
+allows it (the last paragraph).
 
 A head's state S [d_k, d_v] follows, token by token (the definition;
 ``benchmark/reference/kimi_linear.py::delta_rule`` runs it as written
@@ -49,6 +50,18 @@ name ``KEPT`` (``checkpoint_name``), an identity unless an enclosing
 at that shape, and the block's recompute holds no pass of the scan. A
 layer then runs the forward F, the row's F and the backward B; under a
 plain ``jax.checkpoint`` around the caller it would run F three times.
+
+Which pass runs where. The first of those, the step's own forward,
+needs no residuals: on a TPU, for heads 128 wide and whole chunks of 64
+(``_kernel_path``), it is the Pallas kernel ``tl_kda_fwd``
+(``ops/pallas/kda.py``: all rows in one call, a chunk in VMEM at a time,
+nothing but o written) behind a ``jax.custom_vjp`` whose residuals are
+the call's own inputs. Its backward is this file's program as it stands:
+row by row, ``jax.vjp`` of ``_chunked`` without a checkpoint (the rule is
+the checkpoint), so one XLA F to linearise, then B. Everywhere else (the
+CPU, a mesh XLA partitions, another width or dtype) all three are the
+XLA program, and on a TPU the closed gate says why
+(``kernel.gate_closed``).
 """
 
 from __future__ import annotations
@@ -58,6 +71,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from tensorlink_tpu.ops.pallas import gate_closed, on_tpu, partitioned_by_xla
+from tensorlink_tpu.ops.pallas import kda as kernel
 
 CHUNK = 64
 SUB = 16
@@ -87,26 +103,96 @@ def _within_sub_blocks(q, k, G):
     )
 
 
-def kda_chunked(q, k, v, g, beta, *, chunk: int = CHUNK, sub: int = SUB):
+def kda_chunked(
+    q, k, v, g, beta, *, chunk: int = CHUNK, sub: int = SUB,
+    interpret: bool = False,
+):
     """The recurrence in chunks of ``chunk`` tokens, each worked in
     ``sub``-blocks, one batch row at a time. A length that is no whole
     number of chunks (of sub-blocks, if shorter than a chunk) is padded
     at its end with tokens that write nothing (k, v, beta 0) and whose
     outputs are cut off: causality keeps them from the rest. q, k, g
-    [B,T,H,dk]; v [B,T,H,dv]; beta [B,T,H] -> o [B,T,H,dv] float32."""
-    B, T = q.shape[:2]
+    [B,T,H,dk]; v [B,T,H,dv]; beta [B,T,H] -> o [B,T,H,dv] float32.
+    ``interpret`` runs the kernel path off the TPU, interpreted."""
+    T = q.shape[1]
     pad = -T % (chunk if T > chunk else min(sub, T))
     if pad:
         q, k, v, g, beta = (
             jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta)
         )
-        return kda_chunked(q, k, v, g, beta, chunk=chunk, sub=sub)[:, :T]
+        return kda_chunked(
+            q, k, v, g, beta, chunk=chunk, sub=sub, interpret=interpret
+        )[:, :T]
+    if _kernel_path(q, k, v, g, beta, chunk, sub, interpret):
+        return checkpoint_name(_by_kernel(interpret, q, k, v, g, beta), KEPT)
     row = jax.checkpoint(functools.partial(_chunked, chunk=chunk, sub=sub))
-    o = jax.lax.map(lambda x: row(*x), tuple(
-        x.reshape(B, 1, *x.shape[1:]) for x in (q, k, v, g, beta)
+    return checkpoint_name(_row_by_row(row, q, k, v, g, beta), KEPT)
+
+
+def _row_by_row(row, *xs):
+    """``row`` over each batch row of ``xs`` in turn (as a batch of one),
+    its results stacked back into batches."""
+    out = jax.lax.map(lambda x: row(*x), tuple(
+        x.reshape(x.shape[0], 1, *x.shape[1:]) for x in xs
     ))
-    return checkpoint_name(o.reshape(B, *o.shape[2:]), KEPT)
+    return jax.tree.map(lambda o: o.reshape(o.shape[0], *o.shape[2:]), out)
+
+
+def _kernel_path(q, k, v, g, beta, chunk, sub, interpret) -> bool:
+    """Static gate for ``tl_kda_fwd``: silent off the TPU (the XLA path
+    is the only one there); on it a refusal records its reason."""
+    if not interpret and not on_tpu():
+        return False
+    closed = functools.partial(gate_closed, kernel.NAME, q=q.shape, v=v.shape)
+    if not interpret and (why := partitioned_by_xla()):
+        return closed(why)
+    if (q.shape[-1], v.shape[-1]) != (kernel.WIDTH, kernel.WIDTH):
+        return closed(
+            f"heads {q.shape[-1]} / {v.shape[-1]} wide, not {kernel.WIDTH}"
+        )
+    if (chunk, sub) != (kernel.CHUNK, kernel.SUB) or q.shape[1] % chunk:
+        return closed(
+            f"{q.shape[1]} tokens in chunks of {chunk}, sub-blocks of {sub}: "
+            f"not whole chunks of {kernel.CHUNK} in {kernel.SUB}s"
+        )
+    dtypes = [x.dtype.name for x in (q, k, v, g, beta)]
+    if dtypes not in (
+        ["bfloat16"] * 3 + ["float32"] * 2, ["float32"] * 5
+    ):
+        return closed(
+            f"operands {dtypes}: q, k, v not all bfloat16 or all float32, "
+            "or g, beta not float32"
+        )
+    return True
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _by_kernel(interpret, q, k, v, g, beta):
+    return kernel.kda_fwd(q, k, v, g, beta, interpret=interpret)
+
+
+def _by_kernel_fwd(interpret, *xs):
+    # the residuals are the call's own inputs: under a remat that keeps
+    # o by name the kernel's call is dead code in the recompute
+    return kernel.kda_fwd(*xs, interpret=interpret), xs
+
+
+def _by_kernel_bwd(interpret, xs, do):
+    """The XLA program's backward, a row at a time. No checkpoint around
+    ``_chunked``: this rule is one (F to linearise, then B; a checkpoint
+    inside would run F twice)."""
+
+    def row(*row_and_do):
+        _, vjp = jax.vjp(
+            functools.partial(_chunked, chunk=CHUNK, sub=SUB), *row_and_do[:-1]
+        )
+        return vjp(row_and_do[-1])
+
+    return _row_by_row(row, *xs, do)
+
+
+_by_kernel.defvjp(_by_kernel_fwd, _by_kernel_bwd)
 
 
 def _chunked(q, k, v, g, beta, *, chunk: int, sub: int):

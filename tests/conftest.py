@@ -150,6 +150,17 @@ def pytest_pyfunc_call(pyfuncitem):
 # Shared toy-problem helpers (used by test_train.py and test_parallel.py).
 
 
+def count_equations(jaxpr, primitive: str) -> int:
+    """Equations of that primitive in a jaxpr, nested jaxprs included
+    (a scan's body once, however many steps it takes)."""
+    return sum(
+        (e.primitive.name == primitive) + sum(
+            count_equations(sub, primitive)
+            for sub in jax.core.jaxprs_in_params(e.params))
+        for e in jaxpr.eqns
+    )
+
+
 def toy_batch(n=64, d=16, classes=4, seed=0):
     import jax.numpy as jnp
 

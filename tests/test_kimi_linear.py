@@ -13,6 +13,7 @@ import pytest
 
 from benchmark import weights
 from benchmark.reference import kimi_linear as ref
+from conftest import count_equations as _count
 from tensorlink_tpu.config import TrainConfig
 from tensorlink_tpu.models.kimi_linear import (
     KimiBlock,
@@ -181,17 +182,6 @@ def test_trains_through_the_trainer(remat):
 def _grad_of(both, remat):
     model = KimiLinear(dataclasses.replace(TINY, remat=remat))
     return jax.grad(lambda p: loss_fn(model, p, both["batch"], None))
-
-
-def _count(jaxpr, primitive):
-    """Equations of that primitive, nested jaxprs included (a scan's
-    body once, however many steps it takes)."""
-    return sum(
-        (e.primitive.name == primitive) + sum(
-            _count(sub, primitive)
-            for sub in jax.core.jaxprs_in_params(e.params))
-        for e in jaxpr.eqns
-    )
 
 
 def _plain_checkpoint(monkeypatch):

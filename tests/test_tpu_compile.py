@@ -226,6 +226,71 @@ def test_fused_residual_norm_compiles(
     )
 
 
+def _kda_operands(B, T, H, mm, d=128):
+    """q, k, v, g, beta of ``kda_chunked`` as (shape, dtype)."""
+    return [((B, T, H, d), mm)] * 3 + [
+        ((B, T, H, d), jnp.float32), ((B, T, H), jnp.float32)
+    ]
+
+
+@pytest.mark.parametrize("T,mm", [
+    (4096, BF16), (4096, jnp.float32), (4000, BF16),
+], ids=["T4096-bf16", "T4096-f32", "T4000-padded"])
+def test_kda_forward_compiles(compile_for_chip, monkeypatch, T, mm):
+    """``tl_kda_fwd`` at the Kimi cell's shape, 4 rows of 32 heads of
+    128 (a VMEM overrun or a tile Mosaic refuses shows here, without
+    the chip), and through ``kda_chunked``'s own padding."""
+    from tensorlink_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+    text = compile_for_chip(kda.kda_chunked, *_kda_operands(4, T, 32, mm))
+    assert _kernel_lines(text, "tl_kda_fwd")
+    assert "triangular-solve" not in text and "TriangularSolve" not in text
+
+
+def test_kda_gradient_holds_the_kernel_once(compile_for_chip, monkeypatch):
+    from tensorlink_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+
+    def grads(*xs):
+        return jax.grad(
+            lambda *a: jnp.sum(jnp.sin(kda.kda_chunked(*a))), range(5)
+        )(*xs)
+
+    text = compile_for_chip(grads, *_kda_operands(2, 256, 4, BF16))
+    assert len(_kernel_lines(text, "tl_kda_fwd")) == 1
+
+
+@pytest.mark.parametrize("where,reason", [
+    ("a mesh", "partitioned by XLA"), ("heads of 64", "64 / 64 wide"),
+])
+def test_kda_gate_closes_with_a_reason(topo, monkeypatch, where, reason):
+    """Where XLA partitions (a four-chip mesh it splits) and for a head
+    width the kernel does not take, ``kda_chunked`` compiles as the XLA
+    program and the gate says why."""
+    from tensorlink_tpu.ops import kda
+    from tensorlink_tpu.runtime.flight import default_recorder
+
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+    d = 64 if where == "heads of 64" else 128
+    if where == "a mesh":
+        mesh = Mesh([[dev] for dev in topo.devices], ("model", "data"))
+    else:
+        mesh = Mesh(topo.devices[:1], ("model",))
+    args = [
+        jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, P()))
+        for shape, dt in _kda_operands(2, 128, 4, BF16, d)
+    ]
+    seen = len(default_recorder().events(kind="kernel.gate_closed"))
+    with jax.set_mesh(mesh):
+        text = jax.jit(kda.kda_chunked).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
+    new = default_recorder().events(kind="kernel.gate_closed")[seen:]
+    assert new and new[-1]["attrs"]["kernel"] == "tl_kda_fwd"
+    assert reason in new[-1]["attrs"]["reason"], new
+
+
 def test_gate_closes_where_xla_partitions(topo, compile_for_chip, monkeypatch):
     """XLA cannot split a Mosaic kernel: on a four-chip mesh whose axes
     it partitions, lowering one raises. The gate has to close there —
