@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from benchmark import harness, trace, traffic, weights
+from benchmark import balance, harness, trace, traffic, weights
 from benchmark.harness import BenchFailure, note
 
 _B1 = 0.9  # Adam's first-moment decay, as the configuration states it
@@ -44,8 +44,15 @@ def build_trainer(cfg: dict, mix: dict, seed: int):
     ))
     shapes = jax.eval_shape(model.init, jax.random.key(0))
     params = weights.make_tree(seed, shapes, jnp.float32)
+    # routed experts: the selection bias from the load on batch 0, before
+    # Adam's moments are there; the same numbers go to every tree made
+    # from the seed (benchmark/balance.py). No router, nothing runs
+    biases = {}
+    if hasattr(family, "router_scores"):
+        biases = balance.run(family, model, params, cfg, mix, seed)
+        params = balance.lay_over(params, biases)
     state = TrainState.create(params, trainer.optimizer)
-    return family, model, trainer, state, shapes
+    return family, model, trainer, state, shapes, biases
 
 
 def to_device(ids: np.ndarray) -> dict:
@@ -71,13 +78,22 @@ def leaf_norms(tree) -> dict[str, float]:
     }
 
 
-def delta_norms(params, seed, shapes) -> dict[str, float]:
-    """Per leaf, the norm of what training has changed: the weights
-    now, less the weights made again from the seed."""
-    import jax
+def start_tree(seed, shapes, biases):
+    """The weights before the first step, made again from the seed,
+    with the selection biases the driver holds laid over them."""
     import jax.numpy as jnp
 
-    start = weights.make_tree(seed, shapes, jnp.float32)
+    return balance.lay_over(
+        weights.make_tree(seed, shapes, jnp.float32), biases
+    )
+
+
+def delta_norms(params, seed, shapes, biases) -> dict[str, float]:
+    """Per leaf, the norm of what training has changed: the weights
+    now, less the weights before the first step."""
+    import jax
+
+    start = start_tree(seed, shapes, biases)
     diff = jax.jit(
         lambda a, b: jax.tree.map(lambda x, y: x - y, a, b)
     )(params, start)
@@ -86,11 +102,15 @@ def delta_norms(params, seed, shapes) -> dict[str, float]:
 
 
 def reference_run(family, cfg, mix, seed, shapes, steps: int, mode=None,
-                  rows=None) -> dict:
+                  rows=None, biases=None) -> dict:
     """The plain reference through the first ``steps`` steps on the same
     batches: each step's loss, the first gradient as the optimizer gets
     it, and the parameters' change, by leaf. ``rows`` keeps only those
-    rows of every batch (a planted fault of the control script)."""
+    rows of every batch (a planted fault of the control script).
+    ``biases`` are the selection biases the program was given (numbers
+    by path, no code of the program's): any bias is a valid weight, so a
+    fault in the program's forward pass changes at which weights the two
+    are compared and hides no gap."""
     import jax
     import jax.numpy as jnp
 
@@ -108,7 +128,7 @@ def reference_run(family, cfg, mix, seed, shapes, steps: int, mode=None,
         return params, m, v, loss, clipped, norm
 
     with jax.default_matmul_precision("highest"):
-        params = weights.make_tree(seed, shapes, jnp.float32)
+        params = start_tree(seed, shapes, biases)
         m = jax.tree.map(jnp.zeros_like, params)
         v = jax.tree.map(jnp.zeros_like, params)
         losses, norms, grad = [], [], None
@@ -125,7 +145,7 @@ def reference_run(family, cfg, mix, seed, shapes, steps: int, mode=None,
                 grad = leaf_norms(clipped)
             del clipped
         del m, v
-        delta = delta_norms(params, seed, shapes)
+        delta = delta_norms(params, seed, shapes, biases)
     return {"losses": losses, "norms": norms, "grad": grad, "delta": delta}
 
 
@@ -181,7 +201,9 @@ def run(cell) -> dict:
     check_steps = mix["check"]["steps"]
     compiles = harness.CompileCounter()
     t0 = time.perf_counter()
-    family, model, trainer, state, shapes = build_trainer(cfg, mix, seed)
+    family, model, trainer, state, shapes, biases = build_trainer(
+        cfg, mix, seed
+    )
     key = jax.random.key(0)  # dropout is 0: the key moves nothing
     jax.block_until_ready(state)
     built_s = time.perf_counter() - t0
@@ -206,7 +228,7 @@ def run(cell) -> dict:
                 n: g / (1 - _B1)
                 for n, g in leaf_norms(state.opt_state["m"]).items()
             }
-    prog["delta"] = delta_norms(state.params, seed, shapes)
+    prog["delta"] = delta_norms(state.params, seed, shapes, biases)
     state, _, _ = one_step(state, check_steps)  # one more: all is warm
     note(phase="setup", import_s=round(t0 - cell.t_start, 3),
          weights_s=round(built_s, 3), first_step_s=round(cold_s - built_s, 3),
@@ -275,20 +297,19 @@ def run(cell) -> dict:
     del state, trainer, model, batch, step_prog
     gc.collect()
     t0 = time.perf_counter()
-    ref = reference_run(family, cfg, mix, seed, shapes, check_steps)
+    ref = reference_run(
+        family, cfg, mix, seed, shapes, check_steps, biases=biases
+    )
     numbers = compare(prog, ref)
     note(phase="check", seconds=round(time.perf_counter() - t0, 3), **numbers)
     controls = {}
     for mode in getattr(cell, "control_modes", ()):  # never in a measured run
+        how = {"mode": mode}
         if mode == "half_batch":  # a fault, planted in the reference
-            low = reference_run(
-                family, cfg, mix, seed, shapes, check_steps,
-                rows=slice(0, mix["batch_size"] // 2),
-            )
-        else:
-            low = reference_run(
-                family, cfg, mix, seed, shapes, check_steps, mode=mode
-            )
+            how = {"rows": slice(0, mix["batch_size"] // 2)}
+        low = reference_run(
+            family, cfg, mix, seed, shapes, check_steps, biases=biases, **how
+        )
         controls[mode] = compare(low, ref)
     return {
         "attempted": steps, "failed": int(not np.isfinite(losses).all()),

@@ -50,6 +50,50 @@ def train_loss(module, params, batch, rng):
     )
 
 
+def routed_experts(cfg: dict) -> dict:
+    """The router's choice, for ``benchmark/balance.py``: experts a token
+    chooses, and the experts held here (first, count)."""
+    return {
+        "k": cfg["num_experts_per_token"],
+        "held": (_share(cfg).get("first_expert", 0), cfg["num_experts"]),
+    }
+
+
+def router_scores(model, params, ids, bias_for=None):
+    """For each expert layer in order, the [tokens, E] float32 sigmoid
+    scores its router sees for ``ids``: the model's own modules, a block
+    composed as ``KimiBlock.apply`` composes it, the score as
+    ``HeldExpertsMoE._route`` takes it. Traceable; ``params`` come in the
+    step's compute dtype. ``bias_for(scores) -> [E]``, where given, is
+    asked at each expert layer, and that layer's experts then choose
+    under the bias it returns in place of ``params``' own: a layer's
+    bias changes what the layers after it see. Having this function is
+    what has ``drivers/train.py`` set the selection bias from the load
+    (``benchmark/balance.py``)."""
+    import jax
+    import jax.numpy as jnp
+
+    ch = model.children
+    x = ch["tok_emb"].apply(params["tok_emb"], ids)
+    scores = []
+    for name, block in ch["blocks"].children.items():
+        p, b = params["blocks"][name], block.children
+        x = x + b["mixer"].apply(p["mixer"], b["norm1"].apply(p["norm1"], x))
+        h = b["norm2"].apply(p["norm2"], x)
+        mlp = p["mlp"]
+        if block.ffn_kind == "moe":
+            router = mlp["router"]
+            scores.append(jax.nn.sigmoid(
+                h.reshape(-1, h.shape[-1]).astype(jnp.float32)
+                @ router["w"].astype(jnp.float32)
+            ))
+            if bias_for is not None:
+                bias = bias_for(scores[-1]).astype(router["bias"].dtype)
+                mlp = {**mlp, "router": {**router, "bias": bias}}
+        x = x + b["mlp"].apply(mlp, h)
+    return scores
+
+
 def _layers(cfg: dict) -> tuple[int, int, int, int]:
     """(KDA layers, MLA layers, dense feed-forwards, expert layers)."""
     n = cfg["num_hidden_layers"]

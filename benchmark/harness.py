@@ -18,6 +18,7 @@ ROOT = HERE.parent
 KERNELS = (
     "tl_paged_decode", "tl_decode_glue",
     "tl_flash_fwd", "tl_flash_bwd_dq", "tl_flash_bwd_dkv",
+    "tl_kda_fwd",
 )
 
 
@@ -50,8 +51,15 @@ def open_cell(workload: str, t_start: float, **more):
     conf = next(c for c in bench["configs"] if c["name"] == work["config"])
     limits = json.loads((HERE / "limits" / f"{work['name']}.json").read_text())
 
+    import jax
+
     from tensorlink_tpu.runtime.compile_cache import enable_compile_cache
 
+    # the cache holds every program of a cell, whatever size the machine
+    # caps it at: an LRU cache smaller than a cell's programs evicts one
+    # to write the next, and then every run compiles them all again (the
+    # Kimi cell's come to 190 MiB; PERF.md section 6, PR 34)
+    jax.config.update("jax_compilation_cache_max_size", -1)
     enable_compile_cache(
         os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(ROOT / ".jax_cache")
     )

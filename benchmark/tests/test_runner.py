@@ -167,6 +167,185 @@ def test_controls_script_passes_every_reading_through_decide(monkeypatch, capsys
     assert controls.main(argv + ["--modes", "fp8"]) == 1
 
 
+# --------------------------------------------- routed experts (balance.py)
+def test_kimi_run_balances_and_one_bias_reaches_every_tree(monkeypatch, capsys):
+    """A tiny Kimi run through the driver: the ``balance`` note, ``correct``,
+    and the same bias leaves, to the bit, in the program's tree, the
+    reference's tree and the start that changes are taken from; the
+    bias's own change after the three steps is 0 on both sides. (The fp8
+    control and the half-batch fault of this family, through the same
+    driver with the balance in it: ``test_kimi_linear.py``.)"""
+    import jax
+
+    from benchmark import balance, weights
+    from benchmark.tests import test_kimi_linear as kimi
+    from tensorlink_tpu.train.trainer import TrainState
+
+    made, laid = [], []
+    real_run, real_start = balance.run, train.start_tree
+    real_create = TrainState.create
+
+    def bias_leaves(tree):
+        return {
+            weights.path_str(p): np.asarray(x)
+            for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]
+            if weights.path_str(p).endswith("router/bias")
+        }
+
+    def run(*a):
+        made.append(real_run(*a))
+        return made[-1]
+
+    def start_tree(*a):
+        tree = real_start(*a)
+        laid.append(bias_leaves(tree))
+        return tree
+
+    def create(params, optimizer):  # the program's tree, before Adam's
+        laid.append(bias_leaves(params))
+        return real_create(params, optimizer)
+
+    monkeypatch.setattr(balance, "run", run)
+    monkeypatch.setattr(train, "start_tree", start_tree)
+    monkeypatch.setattr(TrainState, "create", create)
+    cell = tiny.cell(kimi.KIMI_TINY, kimi.KIMI_MIX, kimi.LIMITS, seed=6,
+                     seconds=0.3)
+    cell.name = "kimi-linear-l5e8.train_lm_s4096"
+    line = _line(cell, train.run(cell))
+    assert line["correct"] is True and line["failed"] == 0
+    notes = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith('{"phase"')]
+    (note,) = [n for n in notes if n["phase"] == "balance"]
+    assert len(note["layers"]) == 4 and note["rounds"] == balance.ROUNDS
+    assert note["mean_load"] == 128.0
+    assert all(abs(n - 512) <= 4 for n in note["held_routes"])
+    assert all(n >= 126 for n in note["expert_least"])
+    assert all(n <= 130 for n in note["expert_most"])
+    phases = [n["phase"] for n in notes]
+    assert phases.index("balance") < phases.index("setup")
+    # the program's tree, the program's delta start, the reference's
+    # start, the reference's delta start: one set of numbers
+    (biases,) = made
+    assert len(laid) == 4
+    for tree in laid:
+        assert set(tree) == set(biases)
+        for path, b in biases.items():
+            assert tree[path].tobytes() == b.tobytes()
+    compare = next(n for n in notes if n["phase"] == "compare")
+    assert compare["leaves_left_out"] >= 4  # the four biases among them
+
+
+def test_bias_change_after_three_steps_is_nought():
+    """The bias chooses only: no gradient, so Adam leaves it where the
+    balance put it, and a start tree without the balance's numbers
+    would read the whole difference as a change."""
+    from benchmark.tests import test_kimi_linear as kimi
+
+    cfg, mix, seed = kimi.KIMI_TINY, kimi.KIMI_MIX, 6
+    _, _, trainer, state, shapes, biases = train.build_trainer(cfg, mix, seed)
+    import jax
+
+    key = jax.random.key(0)
+    for i in range(3):
+        batch = train.to_device(train.traffic.train_batch(
+            mix, cfg["vocab_size"], seed, i))
+        state, _ = trainer.train_step(state, batch, key)
+    delta = train.delta_norms(state.params, seed, shapes, biases)
+    seeded = train.delta_norms(state.params, seed, shapes, {})
+    for path in biases:
+        assert delta[path] == 0.0
+        assert seeded[path] > 0.01
+    moved = [n for n, d in delta.items() if d > 0]
+    assert len(moved) == len(delta) - len(biases)
+
+
+_GPT2_RUN = """
+import json, sys
+sys.path.insert(0, {root!r})
+import jax
+jax.config.update("jax_enable_compilation_cache", False)
+from benchmark import balance
+from benchmark.drivers import train
+from benchmark.tests import tiny
+
+def never(*a, **kw):
+    raise AssertionError("the balance ran for a family with no router")
+
+balance.run = balance.solve = balance.loads = never
+cell = tiny.cell(tiny.GPT2_TINY, tiny.TRAIN_TINY, tiny.TRAIN_LIMITS, seed=3,
+                 seconds=0.3)
+res = train.run(cell)
+print(json.dumps({{"phase": "done", "attempted": res["attempted"]}}))
+"""
+
+
+def test_gpt2_run_compiles_what_it_compiled_before():
+    """No router, nothing runs: no function of ``balance.py`` that
+    compiles is called, and the tiny GPT-2 run's set-up counts one
+    compilation fewer than the parent of PR 34 counted for the same run
+    in a fresh process (17, measured on its checkout: jax 0.9.0, compile
+    cache off): the tree's builder is kept, so the start that changes
+    are taken from is not compiled again. (In a process that has run
+    other tests the count is smaller, which is why this one starts its
+    own.)"""
+    import os
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-c", _GPT2_RUN.format(root=str(harness.ROOT))],
+        capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    notes = [json.loads(ln) for ln in out.stdout.splitlines()
+             if ln.startswith('{"phase"')]
+    assert notes[-1]["phase"] == "done" and notes[-1]["attempted"] >= 1
+    assert not any(n["phase"] == "balance" for n in notes)
+    setup = next(n for n in notes if n["phase"] == "setup")
+    assert setup["compilations"] == 17 - 1
+
+
+def test_open_cell_lifts_the_machines_cap_on_the_compile_cache(monkeypatch):
+    """A machine may cap JAX's cache (``JAX_COMPILATION_CACHE_MAX_SIZE``)
+    under what one cell's programs come to; the LRU then evicts one
+    program to write the next and every run compiles again (PR 34, on
+    the chip: set-up 133 s for 42). The harness lifts the cap before
+    the cache is opened."""
+    import jax
+
+    from tensorlink_tpu.runtime import compile_cache
+
+    opened = []
+    monkeypatch.setattr(
+        compile_cache, "enable_compile_cache",
+        lambda d: opened.append(jax.config.jax_compilation_cache_max_size))
+    monkeypatch.setattr(
+        harness, "device_or_fail",
+        lambda chips: ({"platform": "tpu", "kind": "TPU v5 lite",
+                        "count": chips}, harness.peaks_for("TPU v5 lite")))
+    before = jax.config.jax_compilation_cache_max_size
+    jax.config.update("jax_compilation_cache_max_size", 192 << 20)
+    try:
+        _, cell = harness.open_cell(
+            "kimi-linear-l5e8.train_lm_s4096", 0.0, seed=1, seconds=1.0)
+        assert opened == [-1]
+    finally:
+        jax.config.update("jax_compilation_cache_max_size", before)
+    assert cell.mix["driver"] == "train" and cell.chips == 1
+
+
+def test_kernels_in_names_the_kda_forward_kernel():
+    text = (
+        '%a = f32[4] custom-call(%x), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="jit(tl_train_step)/tl_kda_fwd"}\n'
+        '%b = f32[4] custom-call(%x), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="jit(tl_train_step)/tl_flash_fwd"}\n'
+        '%c = f32[4] fusion(%x), metadata={op_name="tl_flash_bwd_dq"}\n'
+    )
+    assert harness.kernels_in(text) == ["tl_flash_fwd", "tl_kda_fwd"]
+
+
 # ------------------------------------------------------------- the command
 def test_command_fails_without_a_tpu():
     """``run.py`` itself has no CPU switch: off a TPU it prints no

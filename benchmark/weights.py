@@ -13,10 +13,21 @@ A leaf's values depend on (seed, path, shape) only:
   ``.../table``  normal * 0.02              (embeddings)
   ``.../scale``  1 + 0.1 * normal           (norm gains, kept float32)
   ``.../b``, ``.../bias``  0.02 * normal    (kept float32)
+
+One leaf's rule is not the last word: a routed expert layer's selection
+bias (``.../router/bias``). The ``train`` driver replaces what the rule
+gives it, before the first step, by the bias that evens the experts'
+load on the run's first batch (``benchmark/balance.py``), and lays
+those same numbers over every tree it makes from the seed: the
+program's, the reference's, the start that changes are taken from. The
+reference takes numbers and no code. Any bias is a valid weight, so a
+fault in the program's forward pass, through which the scores are
+taken, changes at which weights the two are compared and hides no gap.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 import zlib
 
@@ -57,15 +68,30 @@ def make_leaf(key, path: str, shape, matrix_dtype=jnp.float32, salt_=None):
     raise ValueError(f"no rule for leaf {path!r}")
 
 
+@functools.cache
+def _builder(leaves: tuple, matrix_dtype):
+    """The one program that makes ``leaves`` ((path, shape) each), kept
+    for the life of the process: a run makes the same tree twice in
+    set-up (the program's weights, the start its changes are taken from)
+    and twice more for the reference, and a program made anew each time
+    is traced, lowered and read from the cache each time: 4 s of the
+    host's for the Kimi tree. The loaded program stays on the device,
+    17 to 19 MB that ``memory_peak_bytes`` now holds (PERF.md section 6,
+    PR 34)."""
+
+    @jax.jit
+    def build(key):
+        return [make_leaf(key, p, s, matrix_dtype) for p, s in leaves]
+
+    return build
+
+
 def make_tree(seed: int, shapes, matrix_dtype=jnp.float32):
     """The whole tree in ONE jitted call on the device. ``shapes`` is a
     pytree of ShapeDtypeStruct (``jax.eval_shape`` of the model's own
     ``init`` gives it: shapes are not numbers)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    paths = [(path_str(p), tuple(s.shape)) for p, s in flat]
-
-    @jax.jit
-    def build(key):
-        return [make_leaf(key, p, s, matrix_dtype) for p, s in paths]
-
-    return jax.tree_util.tree_unflatten(treedef, build(seed_key(seed)))
+    leaves = tuple((path_str(p), tuple(s.shape)) for p, s in flat)
+    return jax.tree_util.tree_unflatten(
+        treedef, _builder(leaves, matrix_dtype)(seed_key(seed))
+    )
