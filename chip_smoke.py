@@ -12,7 +12,7 @@ process exits non-zero with no such line. Without an accelerator it
 stops at the device phase.
 
     python chip_smoke.py              # one chip: device, serve,
-                                      # serve-kernel, train, kda, cache
+                                      # serve-kernel, train, kda, mamba, cache
     python chip_smoke.py --multichip  # four chips: device, multichip
 
 The compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else to
@@ -629,6 +629,15 @@ def train_phase(
     return facts, failures
 
 
+def _rel_gap(a, b) -> float:
+    """|a - b| / |b| in float32: how far a result stands off its
+    reference (the kda and mamba phases)."""
+    import jax.numpy as jnp
+
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
 # --------------------------------------------------------------------- kda
 # kda_chunked hands its matmuls bf16 operands (q, k, v arrive in bf16 in
 # a bf16 step) and keeps sums, the solve and the state in float32; the
@@ -637,7 +646,7 @@ def train_phase(
 # is off by KDA_CARRIED and more.
 KDA_TOL = 0.02
 KDA_CARRIED = 0.25
-KDA_KERNEL = "tl_kda_fwd"  # not among the harness's closed KERNELS
+KDA_KERNEL = "tl_kda_fwd"  # one of the harness's closed KERNELS since PR 34
 
 
 def kda_phase(
@@ -697,13 +706,10 @@ def kda_phase(
         ))(*args)
         return (o[1], *grads)
 
-    def gap(a, b):
-        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-
     want, got = both(delta_rule), both(chunked)
     names = ("o", "dq", "dk", "dv", "dg", "dbeta")
-    gaps = {n: gap(a, b) for n, a, b in zip(names, got, want)}
-    carried = gap(jax.jit(forgetful)(*args), want[0])
+    gaps = {n: _rel_gap(a, b) for n, a, b in zip(names, got, want)}
+    carried = _rel_gap(jax.jit(forgetful)(*args), want[0])
     program = jax.jit(chunked).lower(*args).compile().as_text()
     g = args[3]
     facts = {
@@ -716,10 +722,7 @@ def kda_phase(
         "gaps": {n: round(x, 6) for n, x in gaps.items()},
         "state_carried": round(carried, 4),
         "limits": {"gap": KDA_TOL, "state_carried_at_least": KDA_CARRIED},
-        "kernels": sorted({
-            KDA_KERNEL for ln in program.splitlines()
-            if "tpu_custom_call" in ln and KDA_KERNEL in ln
-        }),
+        "kernels": [k for k in kernels_in(program) if k == KDA_KERNEL],
         "gates_closed": [
             r for r in gate_reasons() if r.startswith(KDA_KERNEL + ":")
         ],
@@ -729,6 +732,101 @@ def kda_phase(
         if not x < KDA_TOL
     ]
     if not carried > KDA_CARRIED:
+        failures.append(
+            f"the state carries {carried:.4f} of the output: this decay "
+            "does not test the hand-over between chunks")
+    return facts, failures
+
+
+# ------------------------------------------------------------------- mamba
+# selective_scan takes u in bf16 (a bf16 step's) and hands y back in
+# bf16; Delta, the decay, the state and the sums are float32 on both
+# sides. Relative to the reference's norm. A state dropped, or decayed
+# wrongly, between chunks is off by MAMBA_CARRIED and more.
+MAMBA_TOL = 0.01
+MAMBA_CARRIED = 0.15
+
+
+def mamba_phase(
+    *, rows: int = 4, seq: int = 4096, d_inner: int = 5120, d_state: int = 16,
+) -> tuple[dict, list[str]]:
+    """``ops/selective_scan.py::selective_scan`` against the
+    token-by-token recurrence
+    (``benchmark/reference/phi4flash.py::selective_scan``), forward and
+    every gradient, at Phi-4-mini-flash's scan shape, with the decay the
+    published initialisation gives: A_n = -n for n = 1..N in every
+    channel and a step in 1e-3..1e-1 a channel, so a chunk of 16 tokens
+    keeps between e^-0.02 and e^-25 of a state, and much of an output
+    comes from what earlier chunks handed on. (The benchmark's seeded
+    weights put A near -1 and the step near 0.7: e^-11 a chunk, so its
+    check sees little of the hand-over and nothing of a long memory,
+    PERF.md section 7.)
+    ``state_carried`` says how much: the same call with the state
+    forgotten at every chunk's start, against the reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference.phi4flash import selective_scan as recurrence
+    from tensorlink_tpu.ops.selective_scan import CHUNK, selective_scan
+
+    B, T, E, N = rows, seq, d_inner, d_state
+    ks = jax.random.split(jax.random.key(SEED), 7)
+    dt = jnp.exp(jax.random.uniform(
+        ks[0], (E,), minval=np.log(1e-3), maxval=np.log(1e-1)))
+    args = (
+        jax.random.normal(ks[1], (B, T, E)).astype(jnp.bfloat16),
+        # softplus(dt_bias + what the token adds), dt_bias = softplus^-1(dt)
+        jax.nn.softplus(
+            dt + jnp.log(-jnp.expm1(-dt))
+            + 0.5 * jax.random.normal(ks[2], (B, T, E))),
+        -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32), (E, N)),
+        jax.random.normal(ks[3], (B, T, N)),
+        jax.random.normal(ks[4], (B, T, N)),
+        # a small skip D, so that the output is the scan's and not D u
+        0.1 * jax.random.normal(ks[5], (E,)),
+    )
+    ct = jax.random.normal(ks[6], (B, T, E))
+
+    def plain(u, *rest):
+        return recurrence(u.astype(jnp.float32), *rest)
+
+    def forgetful(u, delta, A, Bm, Cm, D):  # every chunk a sequence of its own
+        cut = (
+            x.reshape(B * T // CHUNK, CHUNK, x.shape[-1])
+            for x in (u, delta, Bm, Cm)
+        )
+        u, delta, Bm, Cm = cut
+        return selective_scan(u, delta, A, Bm, Cm, D).reshape(B, T, E)
+
+    def both(fn):
+        o, grads = jax.jit(jax.value_and_grad(
+            lambda *a: (lambda o: (jnp.sum(o * ct), o))(
+                fn(*a).astype(jnp.float32)),
+            argnums=range(6), has_aux=True,
+        ))(*args)
+        return (o[1], *grads)
+
+    want, got = both(plain), both(selective_scan)
+    names = ("y", "du", "ddelta", "dA", "dB", "dC", "dD")
+    gaps = {n: _rel_gap(a, b) for n, a, b in zip(names, got, want)}
+    carried = _rel_gap(jax.jit(forgetful)(*args), want[0])
+    g = args[1].mean((0, 1))[:, None] * args[2]  # a token's ln decay [E, N]
+    facts = {
+        "shape": f"{B} x {T} tokens, {E} channels of {N} states, "
+                 f"chunks of {CHUNK}",
+        # ln of what a chunk keeps of a state: least, most
+        "chunk_log_decay": [
+            round(float(CHUNK * g.min()), 3), round(float(CHUNK * g.max()), 3),
+        ],
+        "gaps": {n: round(x, 6) for n, x in gaps.items()},
+        "state_carried": round(carried, 4),
+        "limits": {"gap": MAMBA_TOL, "state_carried_at_least": MAMBA_CARRIED},
+    }
+    failures = [
+        f"{n} stands {x:.4f} off the recurrence" for n, x in gaps.items()
+        if not x < MAMBA_TOL
+    ]
+    if not carried > MAMBA_CARRIED:
         failures.append(
             f"the state carries {carried:.4f} of the output: this decay "
             "does not test the hand-over between chunks")
@@ -932,6 +1030,8 @@ def main(argv: list[str] | None = None) -> int:
         failures.append(f"{KDA_KERNEL} is not in the chunked call's program")
     failures += [f"the gate closed: {r}" for r in facts["gates_closed"]]
     finish("kda", facts, failures)
+
+    finish("mamba", *mamba_phase())
 
     # a cold directory must have grown; a warm one (a second run on
     # the same machine) is expected to gain nothing for unchanged

@@ -137,6 +137,9 @@ VOCABULARY: dict[str, tuple[str, str, str]] = {
     "moe": ("scope", "nn/moe.py", "a Kimi-Linear block's expert half (HeldExpertsMoE): norm, shared expert, residual"),
     "moe.route": ("scope", "nn/moe.py", "inside tl.moe: router scores, top-k, the sort of routes into rows"),
     "moe.experts": ("scope", "nn/moe.py", "inside tl.moe: gather of rows, the grouped matmuls of the held experts, scatter back"),
+    "mamba": ("scope", "nn/mamba.py", "a Phi-4-mini-flash block's Mamba half: norm, projections, short convolution, step, gate, residual"),
+    "mamba.scan": ("scope", "ops/selective_scan.py", "the chunked selective scan inside tl.mamba, forward and backward"),
+    "gmu": ("scope", "nn/mamba.py", "a Phi-4-mini-flash block's gated-memory half: norm, gate projection, product with another layer's scan output, out-projection, residual"),
     "head": ("scope", "model head", "final norm and the unembedding matmul"),
     "loss": ("scope", "train/trainer.py", "the loss from logits"),
     "train.cast": ("scope", "train/trainer.py", "the dtype policy: master weights to the compute dtype, and the gradients' way back"),

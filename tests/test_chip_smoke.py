@@ -155,6 +155,41 @@ def test_kda_phase_catches_a_lost_state(monkeypatch):
     assert len(failures) >= 6 and "o stands" in failures[0]
 
 
+def test_mamba_phase_tiny():
+    facts, failures = chip_smoke.mamba_phase(
+        rows=1, seq=1024, d_inner=32, d_state=16)
+    assert not failures, failures
+    assert set(facts["gaps"]) == {"y", "du", "ddelta", "dA", "dB", "dC", "dD"}
+    # the slowest state keeps most of a chunk, the fastest none of it
+    fastest, slowest = facts["chunk_log_decay"]
+    assert fastest < -10 and slowest > -1
+    assert facts["state_carried"] > chip_smoke.MAMBA_CARRIED
+
+
+def test_mamba_phase_catches_a_lost_state(monkeypatch):
+    """A scan that forgets its state between chunks (what the
+    benchmark's fast-decaying seeded weights would all but let through)
+    stands far off the recurrence here, in the output and in every
+    gradient."""
+    import tensorlink_tpu.ops.selective_scan as ops_scan
+
+    real = ops_scan.selective_scan
+
+    def forgetful(u, delta, A, Bm, Cm, D):
+        B, T, E = u.shape
+        cut = (
+            x.reshape(B * T // ops_scan.CHUNK, ops_scan.CHUNK, x.shape[-1])
+            for x in (u, delta, Bm, Cm)
+        )
+        u, delta, Bm, Cm = cut
+        return real(u, delta, A, Bm, Cm, D).reshape(B, T, E)
+
+    monkeypatch.setattr(ops_scan, "selective_scan", forgetful)
+    _, failures = chip_smoke.mamba_phase(
+        rows=1, seq=1024, d_inner=32, d_state=16)
+    assert len(failures) >= 6 and "y stands" in failures[0]
+
+
 def test_multichip_phase_tiny(devices):
     """The four-chip path on virtual devices: the sharded trainer
     against the plain one, the model=4 engine against the one-device

@@ -151,6 +151,102 @@ def test_flash_with_a_narrower_v_compiles(compile_for_chip):
     assert _kernel_lines(text, "tl_flash_bwd_dkv")
 
 
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window512"])
+def test_flash_at_differential_attention_widths_compiles(compile_for_chip, window):
+    """Phi-4-mini-flash's differential attention at the benchmark cell's
+    shape: q, k 64 wide, v a pair's 128, 20 query heads on 10 key heads,
+    4 x 4,096 tokens, causal, over every earlier key and over a band of
+    512 (the kernels' restricted grid), at the blocks ``flash_block_for``
+    gives."""
+    from tensorlink_tpu.ops.flash import flash_block_for
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        flash_attention_bwd,
+        flash_attention_fwd_lse,
+    )
+
+    B, H, Hkv, T, D, Dv = 4, 20, 10, 4096, 64, 128
+    blk = flash_block_for(T, B, D)
+    q, k, v = ((B, H, T, D), BF16), ((B, Hkv, T, D), BF16), ((B, Hkv, T, Dv), BF16)
+    o = ((B, H, T, Dv), BF16)
+
+    def fwd(q, k, v):
+        return flash_attention_fwd_lse(
+            q, k, v, causal=True, block_q=blk, block_k=blk, window=window)
+
+    def bwd(q, k, v, o, lse, do):
+        return flash_attention_bwd(
+            q, k, v, o, lse, do, causal=True, block_q=blk, block_k=blk,
+            window=window)
+
+    assert _kernel_lines(compile_for_chip(fwd, q, k, v), "tl_flash_fwd")
+    text = compile_for_chip(bwd, q, k, v, o, ((B, H, T), jnp.float32), o)
+    assert _kernel_lines(text, "tl_flash_bwd_dq")
+    assert _kernel_lines(text, "tl_flash_bwd_dkv")
+
+
+def test_phi4_flash_cell_step_fits_the_chip(topo, compile_for_chip, monkeypatch):
+    """The step of ``phi4-mini-flash-l6.train_lm_s4096`` as the benchmark
+    builds it (697 M parameters, Adam, bf16 on float32 masters, 4 rows of
+    4,096, block remat), compiled for one described v5e: arguments,
+    temporaries and code by the compiler's count stay under the 15.75
+    GiB it allows, and the program holds the flash kernels at 64 / 128:
+    two calls a layer in three layers, forward again in each block's
+    recompute. A count, not a chip run."""
+    import json
+    import pathlib
+
+    from benchmark.families import phi4flash as fam
+    from tensorlink_tpu.config import TrainConfig
+    from tensorlink_tpu.ops import flash
+    from tensorlink_tpu.train.trainer import Trainer, TrainState
+
+    monkeypatch.setattr(flash, "on_tpu", lambda: True)
+    root = pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+    cfg = json.loads((root / "configs" / "phi4-mini-flash-l6.json").read_text())
+    mix = json.loads((root / "traffic" / "train_lm_s4096.json").read_text())
+    hp = cfg["train"]
+    model = fam.build(cfg)
+    trainer = Trainer(model, fam.train_loss, TrainConfig(
+        batch_size=mix["batch_size"], micro_batches=mix["micro_batches"],
+        learning_rate=hp["learning_rate"], optimizer=hp["optimizer"],
+        weight_decay=hp["weight_decay"], schedule=hp["schedule"],
+        warmup_steps=hp["warmup_steps"], grad_clip_norm=hp["clip_norm"],
+        dtype=hp["compute_dtype"],
+    ))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+            tree)
+
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    state = jax.eval_shape(
+        lambda p: TrainState.create(p, trainer.optimizer), shapes)
+    ids = jax.ShapeDtypeStruct(
+        (mix["batch_size"], mix["seq_len"]), jnp.int32, sharding=one_chip)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    # ``compile_for_chip`` is asked for its switch alone: the persistent
+    # cache is off while this module's compiles run
+    compiled = trainer._train_step.lower(
+        described(state), {"input_ids": ids, "labels": ids}, described(key),
+    ).compile()
+    mem = compiled.memory_analysis()
+    gib = (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.generated_code_size_in_bytes
+    ) / 2 ** 30
+    assert 11.5 < gib < 15.75, gib  # 697.1 M x 12 B of arguments alone: 7.79
+    text = compiled.as_text()
+    wide = _kernel_lines(text, "tl_flash_fwd")
+    assert len(wide) == 12 and all(
+        "bf16[4,20,4096,64]" in ln and "bf16[4,10,4096,128]" in ln
+        for ln in wide)
+    assert len(_kernel_lines(text, "tl_flash_bwd_dq")) == 6
+    assert len(_kernel_lines(text, "tl_flash_bwd_dkv")) == 6
+
+
+
 @pytest.mark.parametrize("T", [1, 4])
 @pytest.mark.parametrize("pools", ["bf16", "int8"])
 def test_paged_decode_compiles(compile_for_chip, monkeypatch, pools, T):

@@ -349,6 +349,79 @@ def test_a_kimi_block_instruction_reads_one_half(kimi_step_text):
                 assert parent in inner[:inner.index(child)], path
 
 
+# --------------------- a model whose layers read what earlier ones gave
+PHI4_SCOPES = ["tl.mamba", "tl.mamba.scan", "tl.gmu", "tl.attn"]
+
+
+@pytest.fixture(scope="module")
+def phi4_step_text():
+    """Phi-4-mini-flash's tiny preset through ``Trainer``: two Mamba
+    mixers, window, full and cross differential attention, a gated
+    memory unit, blocks recomputed."""
+    import dataclasses
+
+    from tensorlink_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
+
+    model = Phi4Flash(dataclasses.replace(Phi4FlashConfig.tiny(), remat=True))
+
+    def loss(module, params, batch, rng):
+        return softmax_cross_entropy(
+            module.apply(params, batch["input_ids"]), batch["labels"]
+        )
+
+    tr = Trainer(model, loss, TrainConfig(
+        batch_size=2, micro_batches=1, learning_rate=1e-3, optimizer="adam",
+        grad_clip_norm=1.0,
+    ))
+    ids = np.arange(2 * 33).reshape(2, 33) % 128
+    batch = {
+        "input_ids": jnp.asarray(ids[:, :-1]), "labels": jnp.asarray(ids[:, 1:])
+    }
+    state = jax.eval_shape(tr.init_state, KEY)
+    return tr.audit_programs(state, batch, KEY)[0]["lower"]().as_text(
+        debug_info=True
+    )
+
+
+@pytest.mark.parametrize("name", PHI4_SCOPES + ["tl.mlp", "tl.embed", "tl.head"])
+def test_phi4_train_program_holds_the_scope(phi4_step_text, name):
+    assert known(name) and tracing.VOCABULARY[name[3:]][0] == "scope"
+    assert re.search(rf"[/(]{re.escape(name)}[/)]", phi4_step_text)
+    if name in ("tl.embed", "tl.head"):
+        return
+    # backward instructions keep the scope, under the block's remat and
+    # the scan's own backward rule too
+    paths = set(re.findall(r'"(jit\(tl_train_step\)[^"]*)"', phi4_step_text))
+    assert any(
+        "transpose(" in p and re.search(rf"/{re.escape(name)}(/|$)", p)
+        for p in paths
+    )
+
+
+def test_every_scope_in_the_phi4_program_is_in_the_table(phi4_step_text):
+    found = set(re.findall(r"tl\.[a-z_.]*[a-z]", phi4_step_text))
+    assert set(PHI4_SCOPES) <= found and all(known(n) for n in found), found
+    assert not found & {"tl.kda", "tl.mla", "tl.moe"}
+
+
+def test_a_phi4_block_instruction_reads_one_half(phi4_step_text):
+    """Inside a block every instruction lies under the scope of the
+    half it belongs to, the scan only inside ``tl.mamba``, and no
+    instruction under two halves."""
+    halves = ("tl.mamba", "tl.gmu", "tl.attn", "tl.mlp")
+    seen = set()
+    for path in set(re.findall(r'"(jit\(tl_train_step\)[^"]*)"', phi4_step_text)):
+        scopes = re.findall(r"tl\.[a-z_.]*[a-z]", path)
+        inner = [s for s in scopes if s.startswith(halves)]
+        if not inner:
+            continue
+        seen.update(inner)
+        assert len({s.split(".")[1] for s in inner}) == 1, path
+        if "tl.mamba.scan" in inner:
+            assert "tl.mamba" in inner[:inner.index("tl.mamba.scan")], path
+    assert seen == set(halves) | {"tl.mamba.scan"}
+
+
 def test_sharded_trainer_names_its_program_and_span(rec):
     from tensorlink_tpu.parallel.engine import ShardedTrainer
 
