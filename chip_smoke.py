@@ -762,11 +762,14 @@ def mamba_phase(
     check sees little of the hand-over and nothing of a long memory,
     PERF.md section 7.)
     ``state_carried`` says how much: the same call with the state
-    forgotten at every chunk's start, against the reference."""
+    forgotten at every chunk's start, against the reference.
+    ``kernels`` says which of the scan's two kernels the compiled
+    gradient holds, ``gates_closed`` why it would not."""
     import jax
     import jax.numpy as jnp
 
     from benchmark.reference.phi4flash import selective_scan as recurrence
+    from tensorlink_tpu.ops.pallas.selective_scan import BWD, FWD, NAME, TIME
     from tensorlink_tpu.ops.selective_scan import CHUNK, selective_scan
 
     B, T, E, N = rows, seq, d_inner, d_state
@@ -798,22 +801,27 @@ def mamba_phase(
         u, delta, Bm, Cm = cut
         return selective_scan(u, delta, A, Bm, Cm, D).reshape(B, T, E)
 
-    def both(fn):
-        o, grads = jax.jit(jax.value_and_grad(
+    def gradient(fn):
+        return jax.jit(jax.value_and_grad(
             lambda *a: (lambda o: (jnp.sum(o * ct), o))(
                 fn(*a).astype(jnp.float32)),
             argnums=range(6), has_aux=True,
-        ))(*args)
+        ))
+
+    def both(program):
+        o, grads = program(*args)
         return (o[1], *grads)
 
-    want, got = both(plain), both(selective_scan)
+    ours = gradient(selective_scan).lower(*args).compile()
+    want, got = both(gradient(plain)), both(ours)
     names = ("y", "du", "ddelta", "dA", "dB", "dC", "dD")
     gaps = {n: _rel_gap(a, b) for n, a, b in zip(names, got, want)}
     carried = _rel_gap(jax.jit(forgetful)(*args), want[0])
+    program = ours.as_text()
     g = args[1].mean((0, 1))[:, None] * args[2]  # a token's ln decay [E, N]
     facts = {
         "shape": f"{B} x {T} tokens, {E} channels of {N} states, "
-                 f"chunks of {CHUNK}",
+                 f"chunks of {CHUNK} (the kernels' time blocks {TIME})",
         # ln of what a chunk keeps of a state: least, most
         "chunk_log_decay": [
             round(float(CHUNK * g.min()), 3), round(float(CHUNK * g.max()), 3),
@@ -821,6 +829,15 @@ def mamba_phase(
         "gaps": {n: round(x, 6) for n, x in gaps.items()},
         "state_carried": round(carried, 4),
         "limits": {"gap": MAMBA_TOL, "state_carried_at_least": MAMBA_CARRIED},
+        "kernels": [
+            k for k in (FWD, BWD) if any(
+                "tpu_custom_call" in ln and k in ln
+                for ln in program.splitlines()
+            )
+        ],
+        "gates_closed": [
+            r for r in gate_reasons() if r.startswith(NAME + ":")
+        ],
     }
     failures = [
         f"{n} stands {x:.4f} off the recurrence" for n, x in gaps.items()
@@ -1031,7 +1048,16 @@ def main(argv: list[str] | None = None) -> int:
     failures += [f"the gate closed: {r}" for r in facts["gates_closed"]]
     finish("kda", facts, failures)
 
-    finish("mamba", *mamba_phase())
+    from tensorlink_tpu.ops.pallas.selective_scan import BWD, FWD
+
+    facts, failures = mamba_phase()
+    # 5,120 channels of 16 states on one chip: both passes are the kernels
+    failures += [
+        f"{k} is not in the compiled gradient" for k in (FWD, BWD)
+        if k not in facts["kernels"]
+    ]
+    failures += [f"the gate closed: {r}" for r in facts["gates_closed"]]
+    finish("mamba", facts, failures)
 
     # a cold directory must have grown; a warm one (a second run on
     # the same machine) is expected to gain nothing for unchanged

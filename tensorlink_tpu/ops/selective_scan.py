@@ -1,5 +1,6 @@
 """Mamba-1's selective scan (Gu & Dao 2023, "Mamba: linear-time sequence
-modeling with selective state spaces") in chunks, in plain XLA.
+modeling with selective state spaces") in chunks, in plain XLA, and both
+passes as Pallas kernels where a gate allows it (the last paragraph).
 
 A channel's state s [N] follows, token by token (the definition;
 ``benchmark/reference/phi4flash.py::selective_scan`` runs it as written
@@ -39,13 +40,32 @@ pass of the scan (PR 30's mechanism, as ``ops/kda.py::KEPT``).
 
 Delta, the decay, the state and the sums are float32 whatever dtype u
 arrives in; y leaves in u's dtype.
+
+Which pass runs where. On a TPU, for channels in whole 128-lane tiles,
+16 states, Delta float32 and u, B, C bfloat16 or float32
+(``_kernel_path``), the scan is the two kernels of
+``ops/pallas/selective_scan.py`` behind a ``jax.custom_vjp``: the step's
+forward is ``tl_mamba_scan_fwd``, which reads u, Delta, B, C as they
+come ([B, T, ·], no chunked copies) and writes y and the state entering
+each block of ``TIME`` = 128 tokens, [B, T / 128, N, d] (the kept
+states, by the same name); the backward is ``tl_mamba_scan_bwd``, which
+runs each block forward again in VMEM and back. Under the model's block
+remat a layer so runs each kernel once. A length that is no whole number
+of blocks is padded as above. Everywhere else (the CPU, a mesh XLA
+partitions, other widths or dtypes) it is this file's XLA program, and
+on a TPU the closed gate says why (``kernel.gate_closed``).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from tensorlink_tpu.ops.pallas import gate_closed, on_tpu, partitioned_by_xla
+from tensorlink_tpu.ops.pallas import selective_scan as kernel
 
 CHUNK = 16
 # the names the result and the chunks' entry states carry, for a remat
@@ -105,13 +125,23 @@ def _scan_bwd(res, dy):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
-def selective_scan(u, delta, A, Bm, Cm, D):
+def selective_scan(u, delta, A, Bm, Cm, D, *, interpret: bool = False):
     """u, delta [B,T,d]; A [d,N] (negative); Bm, Cm [B,T,N]; D [d] ->
     y [B,T,d] in u's dtype. A length that is no whole number of chunks
-    is padded at its end with tokens whose step is 0 (they leave the
-    state as it is and write nothing) and whose outputs are cut off."""
+    (of the kernels' time blocks) is padded at its end with tokens whose
+    step is 0 (they leave the state as it is and write nothing) and whose
+    outputs are cut off. ``interpret`` runs the kernel path off the TPU,
+    interpreted."""
     B, T, d = u.shape
     f32 = jnp.float32
+    if _kernel_path(u, delta, Bm, Cm, interpret):
+        pad = ((0, 0), (0, -T % kernel.TIME), (0, 0))
+        y = _by_kernel(
+            interpret, *(jnp.pad(x, pad) for x in (u, delta)),
+            A.astype(f32).T, *(jnp.pad(x, pad) for x in (Bm, Cm)),
+            D.astype(f32)[None],
+        )
+        return checkpoint_name(y[:, :T], KEPT[0])
     C = min(CHUNK, T)
     pad = -T % C
     uf, dt = u.astype(f32), delta.astype(f32)
@@ -125,3 +155,49 @@ def selective_scan(u, delta, A, Bm, Cm, D):
     y = jnp.moveaxis(y.reshape(T + pad, B, d), 0, 1)[:, :T]
     y = y + D.astype(f32) * uf
     return checkpoint_name(y.astype(u.dtype), KEPT[0])
+
+
+def _kernel_path(u, delta, Bm, Cm, interpret) -> bool:
+    """Static gate for ``tl_mamba_scan_fwd`` and ``_bwd``: silent off the
+    TPU (the XLA path is the only one there); on it a refusal records
+    its reason."""
+    if not interpret and not on_tpu():
+        return False
+    closed = functools.partial(
+        gate_closed, kernel.NAME, u=u.shape, states=Bm.shape[-1]
+    )
+    if not interpret and (why := partitioned_by_xla()):
+        return closed(why)
+    if u.shape[-1] % kernel.LANE:
+        return closed(
+            f"{u.shape[-1]} channels: no whole number of {kernel.LANE}-lane tiles"
+        )
+    if Bm.shape[-1] != kernel.STATES:
+        return closed(f"{Bm.shape[-1]} states a channel, not {kernel.STATES}")
+    two = ("bfloat16", "float32")
+    dtypes = [x.dtype.name for x in (u, delta, Bm, Cm)]
+    if dtypes[1] != "float32" or not all(t in two for t in dtypes):
+        return closed(
+            f"operands {dtypes}: delta not float32, or u, B, C not "
+            "bfloat16 or float32"
+        )
+    return True
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _by_kernel(interpret, u, dt, At, Bm, Cm, D):
+    return kernel.scan_fwd(u, dt, At, Bm, Cm, D, interpret=interpret)[0]
+
+
+def _by_kernel_fwd(interpret, *xs):
+    y, entered = kernel.scan_fwd(*xs, interpret=interpret)
+    return y, (xs, checkpoint_name(entered, KEPT[1]))
+
+
+def _by_kernel_bwd(interpret, res, dy):
+    xs, entered = res
+    grads = kernel.scan_bwd(*xs, entered, dy, interpret=interpret)
+    return tuple(g.astype(x.dtype) for g, x in zip(grads, xs))
+
+
+_by_kernel.defvjp(_by_kernel_fwd, _by_kernel_bwd)

@@ -164,6 +164,8 @@ def test_mamba_phase_tiny():
     fastest, slowest = facts["chunk_log_decay"]
     assert fastest < -10 and slowest > -1
     assert facts["state_carried"] > chip_smoke.MAMBA_CARRIED
+    # off the TPU the kernels' gate is shut, and says nothing
+    assert facts["kernels"] == [] and facts["gates_closed"] == []
 
 
 def test_mamba_phase_catches_a_lost_state(monkeypatch):

@@ -4,7 +4,9 @@ forward and every gradient, under the published initialisation's decay
 (A_n = -n, a step of 1e-3..1e-1: a state lives for hundreds of tokens,
 so most of an output crosses chunk boundaries) and under the benchmark's
 seeded weights' (A about -1, a step about 0.7: it dies in ten), at a
-length that is no whole number of chunks."""
+length that is no whole number of chunks. The Pallas kernels
+(``ops/pallas/selective_scan.py``) run interpreted, against the XLA path
+and the recurrence, over three time blocks and three channel blocks."""
 
 import functools
 
@@ -15,13 +17,18 @@ import pytest
 
 from benchmark.reference.phi4flash import selective_scan as recurrence
 from tensorlink_tpu.ops import selective_scan as ops
+from tensorlink_tpu.ops.pallas import selective_scan as kernel
 from tensorlink_tpu.ops.selective_scan import KEPT, selective_scan
+from tensorlink_tpu.runtime.flight import default_recorder
 
 T, E, N = 150, 24, 16  # nine chunks of 16 and 6 tokens
+# the kernels' shape: two whole time blocks and a part of a third, and
+# three blocks of channels in each kernel
+KT, KE = 2 * kernel.TIME + 44, 2688
 NAMES = ("y", "du", "ddelta", "dA", "dB", "dC", "dD")
 
 
-def operands(regime: str, B: int, dtype):
+def operands(regime: str, B: int, dtype, T: int = T, E: int = E):
     ks = jax.random.split(jax.random.key(3), 7)
     if regime == "published":
         A = -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32), (E, N))
@@ -38,22 +45,46 @@ def operands(regime: str, B: int, dtype):
     ), jax.random.normal(ks[6], (B, T, E))
 
 
+def _run(fn, args, ct):
+    """fn's output and its six gradients under the loss sum(y * ct)."""
+
+    def loss(*a):
+        y = fn(*a).astype(jnp.float32)
+        return jnp.sum(y * ct), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=range(6), has_aux=True))(*args)
+    return (y, *grads)
+
+
+def _plain(u, *rest):
+    return recurrence(u.astype(jnp.float32), *rest)
+
+
 @functools.lru_cache(maxsize=None)
 def both(regime, B, dtype):
     """(chunked, recurrence): each the output and the six gradients."""
     args, ct = operands(regime, B, dtype)
+    return _run(selective_scan, args, ct), _run(_plain, args, ct)
 
-    def run(fn):
-        def loss(*a):
-            y = fn(*a).astype(jnp.float32)
-            return jnp.sum(y * ct), y
 
-        (_, y), grads = jax.value_and_grad(
-            loss, argnums=range(6), has_aux=True)(*args)
-        return (y, *grads)
+@functools.lru_cache(maxsize=None)
+def by_kernel(regime, B, dtype):
+    """{kernels, xla, recurrence}: each the output and the six gradients
+    at the kernels' shape; the kernels interpreted."""
+    args, ct = operands(regime, B, dtype, KT, KE)
+    return {
+        "kernels": _run(
+            functools.partial(selective_scan, interpret=True), args, ct),
+        "xla": _run(selective_scan, args, ct),
+        "recurrence": _run(_plain, args, ct),
+    }
 
-    return run(selective_scan), run(
-        lambda u, *rest: recurrence(u.astype(jnp.float32), *rest))
+
+def _gap(a, b) -> float:
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    assert a.shape == b.shape and float(jnp.linalg.norm(b)) > 0
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
 @pytest.mark.parametrize("name", NAMES)
@@ -62,11 +93,26 @@ def both(regime, B, dtype):
 @pytest.mark.parametrize("regime", ["published", "seeded"])
 def test_chunked_is_the_recurrence(regime, B, dtype, name):
     got, want = both(regime, B, jnp.dtype(dtype))
-    a = got[NAMES.index(name)].astype(jnp.float32)
-    b = want[NAMES.index(name)].astype(jnp.float32)
-    assert a.shape == b.shape and float(jnp.linalg.norm(b)) > 0
-    gap = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    gap = _gap(got[NAMES.index(name)], want[NAMES.index(name)])
     # bf16: y leaves in u's dtype, rounded once (2^-9 an element)
+    assert gap < (4e-3 if dtype == "bfloat16" else 2e-5), gap
+
+
+@pytest.mark.parametrize("against", ["xla", "recurrence"])
+@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("B", [1, 3])
+@pytest.mark.parametrize("regime", ["published", "seeded"])
+def test_kernels_are_the_xla_path_and_the_recurrence(
+    regime, B, dtype, name, against
+):
+    """The kernel path, interpreted, over 300 tokens (two time blocks of
+    128 and 44 tokens padded to a third: the state and its cotangent are
+    handed across blocks both ways) and 2,688 channels (three blocks in
+    each kernel, so dB and dC are sums of partial sums)."""
+    runs = by_kernel(regime, B, jnp.dtype(dtype))
+    i = NAMES.index(name)
+    gap = _gap(runs["kernels"][i], runs[against][i])
     assert gap < (4e-3 if dtype == "bfloat16" else 2e-5), gap
 
 
@@ -134,3 +180,47 @@ def test_a_short_sequence_is_one_chunk():
     short = [x[:, :5] if x.ndim == 3 else x for x in args]
     np.testing.assert_allclose(
         selective_scan(*short), recurrence(*short), rtol=1e-5, atol=1e-6)
+
+
+def _abstract(channels=256, states=16, dtype=jnp.bfloat16, delta=jnp.float32):
+    """u, delta, Bm, Cm as the gate sees them."""
+    return (
+        jax.ShapeDtypeStruct((2, 256, channels), dtype),
+        jax.ShapeDtypeStruct((2, 256, channels), delta),
+        jax.ShapeDtypeStruct((2, 256, states), dtype),
+        jax.ShapeDtypeStruct((2, 256, states), dtype),
+    )
+
+
+@pytest.mark.parametrize("where,operands_,reason", [
+    ("a mesh XLA partitions", _abstract(), "partitioned by XLA"),
+    ("channels off the lane tiles", _abstract(channels=200),
+     "200 channels: no whole number of 128-lane tiles"),
+    ("another number of states", _abstract(states=4),
+     "4 states a channel, not 16"),
+    ("a bfloat16 step", _abstract(delta=jnp.bfloat16), "delta not float32"),
+    ("an integer u", _abstract(dtype=jnp.int32), "u, B, C not bfloat16"),
+])
+def test_the_gate_closes_with_a_reason(monkeypatch, devices, where, operands_, reason):
+    """On a TPU (as the gate is told here) each condition the kernels
+    do not take closes the gate, and the flight recorder says why."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    seen = len(default_recorder().events(kind="kernel.gate_closed"))
+    if where == "a mesh XLA partitions":
+        with jax.set_mesh(jax.make_mesh((2,), ("data",), devices=devices[:2])):
+            assert not ops._kernel_path(*operands_, interpret=False)
+    else:
+        assert not ops._kernel_path(*operands_, interpret=False)
+    new = default_recorder().events(kind="kernel.gate_closed")[seen:]
+    assert len(new) == 1 and new[0]["attrs"]["kernel"] == kernel.NAME
+    assert reason in new[0]["attrs"]["reason"], new
+
+
+@pytest.mark.parametrize("on_a_tpu", [False, True])
+def test_the_gate_opens_silently(monkeypatch, on_a_tpu):
+    """Off the TPU the gate is shut and says nothing (the XLA path is
+    the only one there); on one, at the Phi cell's widths, it opens."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: on_a_tpu)
+    seen = len(default_recorder().events(kind="kernel.gate_closed"))
+    assert ops._kernel_path(*_abstract(5120), interpret=False) is on_a_tpu
+    assert len(default_recorder().events(kind="kernel.gate_closed")) == seen
