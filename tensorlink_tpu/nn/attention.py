@@ -12,10 +12,12 @@ psum per attention block.
 
 from __future__ import annotations
 
-from typing import Callable
+import math
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tensorlink_tpu.nn.module import Module
 from tensorlink_tpu.nn.layers import Dense
@@ -205,18 +207,88 @@ def fuse_qkv_params(attn_params: dict, num_heads: int, num_kv_heads: int,
     return {"qkv": qkv, "o": attn_params["o"]}
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """Rotary position embedding over the last dim. x: [B, T, H, D]."""
+class Yarn(NamedTuple):
+    """YaRN (Peng et al. 2023, arXiv:2309.00071) as HF's
+    ``_compute_yarn_parameters`` reads ``rope_scaling``: each rotated
+    pair's inverse frequency blended between ``theta``'s own and that
+    over ``factor`` by a linear ramp over the pairs whose rotations in
+    ``original_max_position_embeddings`` positions lie between
+    ``beta_fast`` and ``beta_slow``; ``attention_factor`` (None: 0.1 ln
+    factor + 1) multiplies cos and sin."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
+
+
+class Rotary(NamedTuple):
+    """What rotates: ``theta``, the ``fraction`` of a head's dimensions
+    that rotates (the first ones, rotate-half pairing among them; the
+    rest pass through), and YaRN's frequencies where ``yarn`` is set.
+    A tuple, so that ``Module.config()`` keeps it."""
+
+    theta: float = 10000.0
+    fraction: float = 1.0
+    yarn: Yarn | None = None
+
+    @classmethod
+    def of(cls, spec) -> "Rotary":
+        """From itself or its tuple / JSON list form."""
+        theta, fraction, yarn = cls(*spec)
+        return cls(theta, fraction, None if yarn is None else Yarn(*yarn))
+
+    def frequencies(self, dim: int):
+        """-> (inverse frequencies [dim // 2] float32, cos/sin factor)
+        for ``dim`` rotated dimensions."""
+        half = dim // 2
+        if self.yarn is None:
+            return 1.0 / (
+                self.theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+            ), 1.0
+        y, f32 = self.yarn, np.float32
+
+        def correction(rotations):  # the pair that turns ``rotations`` times
+            return dim * math.log(
+                y.original_max_position_embeddings / (rotations * 2 * math.pi)
+            ) / (2 * math.log(self.theta))
+
+        low = max(math.floor(correction(y.beta_fast)), 0)
+        high = min(math.ceil(correction(y.beta_slow)), dim - 1)
+        pos = f32(self.theta) ** (np.arange(0, dim, 2, dtype=f32) / f32(dim))
+        ramp = np.clip(
+            (np.arange(half, dtype=f32) - low) / f32(max(high - low, 1e-3)),
+            0, 1,
+        )
+        keep = 1 - ramp  # the share of theta's own frequency
+        inv = (1 / (y.factor * pos)) * (1 - keep) + (1 / pos) * keep
+        scale = y.attention_factor
+        if scale is None:
+            scale = 0.1 * math.log(y.factor) + 1.0 if y.factor > 1 else 1.0
+        return jnp.asarray(inv, jnp.float32), float(scale)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array,
+               rot: Rotary = Rotary()) -> jax.Array:
+    """Rotary position embedding over the last dim, as ``rot`` says.
+    x: [B, T, H, D]."""
     D = x.shape[-1]
-    half = D // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    dim = int(D * rot.fraction)
+    half = dim // 2
+    freqs, factor = rot.frequencies(dim)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B?, T, half]
     # broadcast to [B, T, 1, half]
     while angles.ndim < x.ndim:
         angles = angles[..., None, :] if angles.ndim == x.ndim - 1 else angles[None]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    x1, x2 = x[..., :half], x[..., half:dim]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if dim < D:
+        parts.append(x[..., dim:])
+    out = jnp.concatenate(parts, axis=-1)
     return out.astype(x.dtype)
 
 
@@ -267,13 +339,16 @@ class MultiHeadAttention(Module):
         num_kv_heads: int | None = None,
         head_dim: int | None = None,
         use_bias: bool = True,
-        rope: bool = False,
-        rope_theta: float = 10000.0,
         causal: bool = False,
         attn_impl: str | Callable = "auto",
         scale: float | None = None,  # None = 1/sqrt(head_dim); T5 = 1.0
         window: int | None = None,  # sliding-window attention (Mistral)
         qkv_fused: bool = False,  # one fused projection (see below)
+        # the rotary embedding of q and k (None: none)
+        rotary: Rotary | tuple | None = None,
+        # o_h = sigmoid(x W_g)_h * attention_h before ``o`` (Qiu et al.
+        # 2025, arXiv:2505.06708): W_g [dim, num_heads], no bias
+        head_gate: bool = False,
     ):
         super().__init__()
         self.dim = dim
@@ -281,8 +356,8 @@ class MultiHeadAttention(Module):
         self.num_kv_heads = num_kv_heads or num_heads
         self.head_dim = head_dim or dim // num_heads
         self.use_bias = use_bias
-        self.rope = rope
-        self.rope_theta = rope_theta
+        self.rotary = None if rotary is None else Rotary.of(rotary)
+        self.head_gate = head_gate
         self.causal = causal
         if window is not None:
             if window < 1:
@@ -361,6 +436,18 @@ class MultiHeadAttention(Module):
             self.child("k", Dense(dim, kvdim, use_bias=use_bias, shard="col"))
             self.child("v", Dense(dim, kvdim, use_bias=use_bias, shard="col"))
         self.child("o", Dense(qdim, dim, use_bias=use_bias, shard="row"))
+        if head_gate:
+            self.child("g", Dense(dim, self.num_heads, use_bias=False,
+                                  shard="col"))
+
+    def _out(self, params, out, gate):
+        """[B, T, H, Dh] attention -> [B, T, dim]: the heads' gates,
+        where the module has them, then ``o``."""
+        B, T = out.shape[:2]
+        if gate is not None:
+            out = out * gate.astype(out.dtype)[..., None]
+        out = out.reshape(B, T, self.num_heads * self.head_dim)
+        return self.children["o"].apply(params["o"], out)
 
     def _project_qkv_fused(self, params, x):
         """Fused projection -> (q [B,T,H,Dh], k/v [B,T,G,Dh])."""
@@ -425,7 +512,7 @@ class MultiHeadAttention(Module):
                     # attention path itself is mask-authoritative — so
                     # rope-less models (GPT-2: learned positions at the
                     # embedding) may omit them.
-                    if self.rope:
+                    if self.rotary is not None:
                         raise ValueError(
                             "per-row cache indices with rope require "
                             "explicit positions (rows sit at different "
@@ -440,14 +527,19 @@ class MultiHeadAttention(Module):
                 # RoPE needs global token positions
                 positions = positions + jax.lax.axis_index("seq") * T
 
-        if self.rope:
+        if self.rotary is not None:
             if precomputed_kv is not None:
                 raise NotImplementedError(
                     "precomputed_kv with rope would re-rotate the keys; "
                     "apply rope in project_kv first"
                 )
-            q = apply_rope(q, positions, self.rope_theta)
-            k = apply_rope(k, positions, self.rope_theta)
+            q = apply_rope(q, positions, self.rotary)
+            k = apply_rope(k, positions, self.rotary)
+        gate = None
+        if self.head_gate:  # from the queries' own input, in float32
+            gate = jax.nn.sigmoid(
+                self.children["g"].apply(params["g"], x).astype(jnp.float32)
+            )
 
         new_cache = None
         use_blockwise = False
@@ -469,7 +561,9 @@ class MultiHeadAttention(Module):
                     "supported (no cached cross-attention exists to "
                     "need it)"
                 )
-            out, new_cache = self._apply_paged(params, q, k, v, cache, mask)
+            out, new_cache = self._apply_paged(
+                params, q, k, v, cache, mask, gate
+            )
             return out, new_cache
         if cache is not None:
             rolling = "rolling" in cache
@@ -578,9 +672,7 @@ class MultiHeadAttention(Module):
                         bias=bias, scale=getattr(self, "scale", None),
                         window=None,
                     )
-                out = out.reshape(B, T, self.num_heads * self.head_dim)
-                out = self.children["o"].apply(params["o"], out)
-                return out, new_cache
+                return self._out(params, out, gate), new_cache
             wslot = cache["index"] % cap if rolling else cache["index"]
             if rolling and T > cap:
                 # duplicate wrapped slots: scatter order for duplicate
@@ -757,13 +849,12 @@ class MultiHeadAttention(Module):
             # poisoning the output makes it loud downstream instead
             # (same standard as the LoRA composition guards).
             out = jnp.where(cache["index"] == 0, out, jnp.nan)
-        out = out.reshape(B, T, self.num_heads * self.head_dim)
-        out = self.children["o"].apply(params["o"], out)
+        out = self._out(params, out, gate)
         if cache is not None:
             return out, new_cache
         return out
 
-    def _apply_paged(self, params, q, k, v, cache, mask):
+    def _apply_paged(self, params, q, k, v, cache, mask, gate=None):
         """Paged-cache attention: scatter the T fresh tokens through the
         per-row block table into the shared block pools, gather each
         row's logical view back, and attend it mask-authoritatively.
@@ -867,9 +958,7 @@ class MultiHeadAttention(Module):
                 q, ck, cv, bt, idx + T,
                 k_scale=cks, v_scale=cvs, mask=mask, window=win,
             )
-            out = out.reshape(B, T, self.num_heads * self.head_dim)
-            out = self.children["o"].apply(params["o"], out)
-            return out, new_cache
+            return self._out(params, out, gate), new_cache
         # gather the logical view: [B, MB, bs, Hkv, D] -> [B, Lv, ...].
         # Sentinel table entries clamp into the last pool block — pure
         # garbage, but the positional keep below never reaches them
@@ -918,9 +1007,7 @@ class MultiHeadAttention(Module):
                 causal=False, mask=keep, q_offset=0,
                 scale=getattr(self, "scale", None), window=None,
             )
-        out = out.reshape(B, T, self.num_heads * self.head_dim)
-        out = self.children["o"].apply(params["o"], out)
-        return out, new_cache
+        return self._out(params, out, gate), new_cache
 
     def project_kv(self, params, src):
         """Project a cross-attention source ONCE: (k, v) [B, Tk, Hkv, D]

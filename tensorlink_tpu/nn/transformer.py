@@ -11,7 +11,7 @@ import jax
 
 from tensorlink_tpu.nn.module import Module
 from tensorlink_tpu.nn.layers import Dense, Dropout, LayerNorm, RMSNorm
-from tensorlink_tpu.nn.attention import MultiHeadAttention
+from tensorlink_tpu.nn.attention import MultiHeadAttention, Rotary
 from tensorlink_tpu.runtime.tracing import scope
 
 
@@ -130,8 +130,7 @@ class TransformerBlock(Module):
                 num_kv_heads=num_kv_heads,
                 use_bias=use_bias,
                 causal=causal,
-                rope=rope,
-                rope_theta=rope_theta,
+                rotary=Rotary(rope_theta) if rope else None,
                 attn_impl=attn_impl,
                 window=attn_window,
                 qkv_fused=qkv_fused,

@@ -130,6 +130,7 @@ VOCABULARY: dict[str, tuple[str, str, str]] = {
     # -- device: scopes inside the programs
     "embed": ("scope", "model embedding", "token/position lookup and embedding dropout"),
     "attn": ("scope", "nn/attention.py", "a block's attention half: its norm, projections, attention, residual"),
+    "swa": ("scope", "nn/attention.py", "a Laguna block's sliding-window attention half: norm, projections, rotary, the flash kernels, the heads' gates, o, residual"),
     "mlp": ("scope", "nn/transformer.py", "a block's feed-forward half (dense or MoE): its norm, matmuls, residual"),
     "kda": ("scope", "nn/kda.py", "a Kimi-Linear block's KDA half: norm, projections, short convolutions, gates, head norm, residual"),
     "kda.scan": ("scope", "ops/kda.py", "the chunked gated delta-rule recurrence inside tl.kda, forward and backward"),

@@ -359,15 +359,15 @@ def test_qkv_fused_parity_and_roundtrip():
     separate module bitwise-close, incl. GQA interleave, cache decode,
     and config() round-trip."""
     from tensorlink_tpu.nn.attention import (
-        MultiHeadAttention, fuse_qkv_params,
+        MultiHeadAttention, Rotary, fuse_qkv_params,
     )
     from tensorlink_tpu.nn.module import module_from_config
 
     for H, Hkv in ((4, 4), (4, 2), (4, 1)):
         sep = MultiHeadAttention(32, H, num_kv_heads=Hkv, causal=True,
-                                 rope=True, use_bias=True)
+                                 rotary=Rotary(), use_bias=True)
         fus = MultiHeadAttention(32, H, num_kv_heads=Hkv, causal=True,
-                                 rope=True, use_bias=True, qkv_fused=True)
+                                 rotary=Rotary(), use_bias=True, qkv_fused=True)
         p = sep.init(KEY)
         pf = fuse_qkv_params(p, H, Hkv, sep.head_dim)
         assert pf["qkv"]["w"].shape == (32, Hkv * (H // Hkv + 2) * sep.head_dim)
@@ -1054,10 +1054,10 @@ def test_vector_cache_index_matches_scalar_decode():
     step where every row happens to share the same index must match the
     scalar-index path bitwise, and a row parked AT capacity must write
     nothing (mode="drop")."""
-    from tensorlink_tpu.nn.attention import MultiHeadAttention
+    from tensorlink_tpu.nn.attention import MultiHeadAttention, Rotary
 
     m = MultiHeadAttention(
-        32, 4, num_kv_heads=2, causal=True, rope=True,
+        32, 4, num_kv_heads=2, causal=True, rotary=Rotary(),
         attn_impl="reference",
     )
     p = m.init(KEY)
@@ -1100,10 +1100,10 @@ def test_vector_cache_index_matches_scalar_decode():
 
 
 def test_vector_cache_index_contract_errors():
-    from tensorlink_tpu.nn.attention import MultiHeadAttention
+    from tensorlink_tpu.nn.attention import MultiHeadAttention, Rotary
 
     m = MultiHeadAttention(
-        32, 4, causal=True, rope=True, attn_impl="reference"
+        32, 4, causal=True, rotary=Rotary(), attn_impl="reference"
     )
     p = m.init(KEY)
     cache = m.init_cache(2, 8, jnp.float32)

@@ -7,7 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from tensorlink_tpu import nn
-from tensorlink_tpu.nn.attention import apply_rope, dot_product_attention
+from tensorlink_tpu.nn.attention import Rotary, apply_rope, dot_product_attention
 from tensorlink_tpu.nn.module import init_module
 
 
@@ -111,7 +111,7 @@ def test_rope_relative_shift_invariance():
 
 def test_kv_cache_decode_matches_full_forward():
     """Incremental decode through the cache == full causal forward."""
-    m = nn.MultiHeadAttention(16, 4, causal=True, rope=True)
+    m = nn.MultiHeadAttention(16, 4, causal=True, rotary=Rotary())
     p = m.init(KEY)
     T = 6
     x = jax.random.normal(KEY, (2, T, 16))

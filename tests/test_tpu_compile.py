@@ -260,6 +260,163 @@ def test_phi4_flash_cell_step_fits_the_chip(topo, compile_for_chip, monkeypatch)
 
 
 
+@pytest.mark.parametrize("H,window", [(72, 512), (48, None)],
+                         ids=["72_8_window512", "48_8_full"])
+def test_flash_at_laguna_widths_compiles(compile_for_chip, H, window):
+    """Laguna-S-2.1's attention at the benchmark cell's shape: q, k, v
+    128 wide, 72 query heads on 8 key heads over a band of 512 (the
+    window layers) and 48 on 8 over every earlier key (the full ones),
+    4 x 4,096 tokens, causal, at the blocks ``flash_block_for`` gives."""
+    from tensorlink_tpu.ops.flash import flash_block_for
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        flash_attention_bwd,
+        flash_attention_fwd_lse,
+    )
+
+    B, Hkv, T, D = 4, 8, 4096, 128
+    blk = flash_block_for(T, B, D)
+    q, kv = ((B, H, T, D), BF16), ((B, Hkv, T, D), BF16)
+
+    def fwd(q, k, v):
+        return flash_attention_fwd_lse(
+            q, k, v, causal=True, block_q=blk, block_k=blk, window=window)
+
+    def bwd(q, k, v, o, lse, do):
+        return flash_attention_bwd(
+            q, k, v, o, lse, do, causal=True, block_q=blk, block_k=blk,
+            window=window)
+
+    assert _kernel_lines(compile_for_chip(fwd, q, kv, kv), "tl_flash_fwd")
+    text = compile_for_chip(bwd, q, kv, kv, q, ((B, H, T), jnp.float32), q)
+    assert _kernel_lines(text, "tl_flash_bwd_dq")
+    assert _kernel_lines(text, "tl_flash_bwd_dkv")
+
+
+def test_laguna_cell_step_fits_the_chip(topo, compile_for_chip, monkeypatch):
+    """The step of ``laguna-s2.1-l5e8.train_lm_s4096`` as the benchmark
+    builds it (811.0 M parameters, Adam, bf16 on float32 masters, 4 rows
+    of 4,096, block remat), compiled for one described v5e: arguments,
+    temporaries and code by the compiler's count stay under the 15.75
+    GiB it allows (14.12 when it was first counted), so no share of the
+    attention heads is needed; the program holds the flash kernels at
+    128 / 128, the three window layers' at 72 on 8 heads under
+    ``tl.swa`` and the two full layers' at 48 on 8 under ``tl.attn``,
+    the forward again in each block's recompute. A count, not a chip
+    run."""
+    import json
+    import pathlib
+    import re
+
+    from benchmark.families import laguna as fam
+    from tensorlink_tpu.config import TrainConfig
+    from tensorlink_tpu.ops import flash
+    from tensorlink_tpu.train.trainer import Trainer, TrainState
+
+    monkeypatch.setattr(flash, "on_tpu", lambda: True)
+    root = pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+    cfg = json.loads((root / "configs" / "laguna-s2.1-l5e8.json").read_text())
+    mix = json.loads((root / "traffic" / "train_lm_s4096.json").read_text())
+    hp = cfg["train"]
+    model = fam.build(cfg)
+    trainer = Trainer(model, fam.train_loss, TrainConfig(
+        batch_size=mix["batch_size"], micro_batches=mix["micro_batches"],
+        learning_rate=hp["learning_rate"], optimizer=hp["optimizer"],
+        weight_decay=hp["weight_decay"], schedule=hp["schedule"],
+        warmup_steps=hp["warmup_steps"], grad_clip_norm=hp["clip_norm"],
+        dtype=hp["compute_dtype"],
+    ))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+            tree)
+
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    state = jax.eval_shape(
+        lambda p: TrainState.create(p, trainer.optimizer), shapes)
+    ids = jax.ShapeDtypeStruct(
+        (mix["batch_size"], mix["seq_len"]), jnp.int32, sharding=one_chip)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    # ``compile_for_chip`` is asked for its switch alone: the persistent
+    # cache is off while this module's compiles run
+    compiled = trainer._train_step.lower(
+        described(state), {"input_ids": ids, "labels": ids}, described(key),
+    ).compile()
+    mem = compiled.memory_analysis()
+    gib = (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.generated_code_size_in_bytes
+    ) / 2 ** 30
+    assert mem.argument_size_in_bytes / 2 ** 30 > 9.06  # 811.0 M x 12 B
+    assert 13.0 < gib <= 14.12, gib
+    text = compiled.as_text()
+
+    def scopes(name, heads):
+        lines = [ln for ln in _kernel_lines(text, name)
+                 if f"bf16[4,{heads},4096,128]" in ln]
+        return [re.findall(r"tl\.[a-z_.]*[a-z]",
+                           re.search(r'op_name="([^"]*)"', ln).group(1))
+                for ln in lines]
+
+    for name, calls in (("tl_flash_fwd", 2), ("tl_flash_bwd_dq", 1),
+                        ("tl_flash_bwd_dkv", 1)):
+        assert len(_kernel_lines(text, name)) == 5 * calls
+        assert scopes(name, 72) == [["tl.swa"]] * 3 * calls
+        assert scopes(name, 48) == [["tl.attn"]] * 2 * calls
+
+
+def test_laguna_reference_step_fits_the_chip(topo, compile_for_chip):
+    """The check's step for ``laguna-s2.1-l5e8.train_lm_s4096``: the
+    plain reference's loss, gradient and Adam step as
+    ``benchmark/drivers/train.py::reference_run`` jits it (float32
+    weights and both moments donated, ``highest`` precision, 4 rows of
+    4,097 ids), compiled for one described v5e. Arguments, the outputs
+    that alias none of them, temporaries and code by the compiler's
+    count stay under the 15.75 GiB it allows (15.37 when first
+    counted), and no gradient tree leaves the step beside the state (a
+    clipped gradient as output, 3.02 GiB more, does not load beside it
+    on the chip). A count, not a chip run."""
+    import functools
+    import json
+    import pathlib
+
+    from benchmark.families import laguna as fam
+
+    root = pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+    cfg = json.loads((root / "configs" / "laguna-s2.1-l5e8.json").read_text())
+    mix = json.loads((root / "traffic" / "train_lm_s4096.json").read_text())
+    ref, hp = fam.reference(), cfg["train"]
+    block = mix.get("check", {}).get("rows_per_block", 1)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+    def step(params, m, v, ids, t):
+        loss, grads = ref.loss_and_grads(params, ids, cfg, block, None)
+        params, m, v, clipped, norm = ref.adam_step(
+            params, m, v, grads, t, hp)
+        return params, m, v, loss, clipped, norm
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shapes = jax.eval_shape(fam.build(cfg).init, jax.random.key(0))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=one_chip),
+        shapes)
+    ids = jax.ShapeDtypeStruct(
+        (mix["batch_size"], mix["seq_len"] + 1), jnp.int32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    # ``compile_for_chip`` is asked for its switch alone, as above
+    with jax.default_matmul_precision("highest"):
+        mem = step.lower(state, state, state, ids, t).compile().memory_analysis()
+    gib = 2 ** 30
+    args = mem.argument_size_in_bytes / gib
+    fresh = (mem.output_size_in_bytes - mem.alias_size_in_bytes) / gib
+    total = (args + fresh + mem.temp_size_in_bytes / gib
+             + mem.generated_code_size_in_bytes / gib)
+    assert args > 9.06  # 811.0 M x 12 B
+    assert fresh < 2 ** -10, fresh  # scalars and the norms by leaf
+    assert total <= 15.75, (args, fresh, mem.temp_size_in_bytes / gib, total)
+
+
 @pytest.mark.parametrize("T", [1, 4])
 @pytest.mark.parametrize("pools", ["bf16", "int8"])
 def test_paged_decode_compiles(compile_for_chip, monkeypatch, pools, T):

@@ -422,6 +422,79 @@ def test_a_phi4_block_instruction_reads_one_half(phi4_step_text):
     assert seen == set(halves) | {"tl.mamba.scan"}
 
 
+# ----------------- a model whose attention halves differ by layer kind
+LAGUNA_SCOPES = ["tl.swa", "tl.attn", "tl.moe", "tl.moe.route", "tl.moe.experts"]
+
+
+@pytest.fixture(scope="module")
+def laguna_step_text():
+    """Laguna's tiny preset through ``Trainer``: full attention in
+    layers 0 and 4, window attention in 1-3, a dense and four expert
+    feed-forwards, blocks recomputed."""
+    import dataclasses
+
+    from tensorlink_tpu.models.laguna import Laguna, LagunaConfig
+
+    model = Laguna(dataclasses.replace(LagunaConfig.tiny(), remat=True))
+
+    def loss(module, params, batch, rng):
+        return softmax_cross_entropy(
+            module.apply(params, batch["input_ids"]), batch["labels"]
+        )
+
+    tr = Trainer(model, loss, TrainConfig(
+        batch_size=2, micro_batches=1, learning_rate=1e-3, optimizer="adam",
+        grad_clip_norm=1.0,
+    ))
+    ids = np.arange(2 * 33).reshape(2, 33) % 128
+    batch = {
+        "input_ids": jnp.asarray(ids[:, :-1]), "labels": jnp.asarray(ids[:, 1:])
+    }
+    state = jax.eval_shape(tr.init_state, KEY)
+    return tr.audit_programs(state, batch, KEY)[0]["lower"]().as_text(
+        debug_info=True
+    )
+
+
+@pytest.mark.parametrize("name", LAGUNA_SCOPES + ["tl.mlp", "tl.embed", "tl.head"])
+def test_laguna_train_program_holds_the_scope(laguna_step_text, name):
+    assert known(name) and tracing.VOCABULARY[name[3:]][0] == "scope"
+    assert re.search(rf"[/(]{re.escape(name)}[/)]", laguna_step_text)
+    if name in ("tl.embed", "tl.head"):
+        return
+    paths = set(re.findall(r'"(jit\(tl_train_step\)[^"]*)"', laguna_step_text))
+    assert any(
+        "transpose(" in p and re.search(rf"/{re.escape(name)}(/|$)", p)
+        for p in paths
+    )
+
+
+def test_every_scope_in_the_laguna_program_is_in_the_table(laguna_step_text):
+    found = set(re.findall(r"tl\.[a-z_.]*[a-z]", laguna_step_text))
+    assert set(LAGUNA_SCOPES) <= found and all(known(n) for n in found), found
+    assert not found & {"tl.kda", "tl.mla", "tl.mamba", "tl.gmu"}
+
+
+def test_a_laguna_block_instruction_reads_one_half(laguna_step_text):
+    """Inside a block every instruction lies under the scope of the
+    half it belongs to: a window layer's attention under ``tl.swa``, a
+    full layer's under ``tl.attn``, the router and experts only inside
+    ``tl.moe``, and no instruction under two halves."""
+    halves = ("tl.swa", "tl.attn", "tl.moe", "tl.mlp")
+    seen = set()
+    for path in set(re.findall(r'"(jit\(tl_train_step\)[^"]*)"', laguna_step_text)):
+        scopes = re.findall(r"tl\.[a-z_.]*[a-z]", path)
+        inner = [s for s in scopes if s.startswith(halves)]
+        if not inner:
+            continue
+        seen.update(inner)
+        assert len({s.split(".")[1] for s in inner}) == 1, path
+        for child in ("tl.moe.route", "tl.moe.experts"):
+            if child in inner:
+                assert "tl.moe" in inner[:inner.index(child)], path
+    assert seen == set(halves) | {"tl.moe.route", "tl.moe.experts"}
+
+
 def test_sharded_trainer_names_its_program_and_span(rec):
     from tensorlink_tpu.parallel.engine import ShardedTrainer
 
