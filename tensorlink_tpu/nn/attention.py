@@ -35,11 +35,18 @@ def band_keep(q_pos, k_pos, causal: bool, window: int | None):
     if causal:
         keep = q_pos >= k_pos
     if window is not None:
-        lo = k_pos > q_pos - window
+        lo = window_keep(q_pos, k_pos, window)
         keep = lo if keep is None else jnp.logical_and(keep, lo)
         if not causal:
             keep = jnp.logical_and(keep, k_pos < q_pos + window)
     return keep
+
+
+def window_keep(q_pos, k_pos, window: int):
+    """The band's lower edge alone, as ``band_keep`` draws it: key k is
+    inside query q's window iff k > q - window (the flash kernels' tiles
+    that only this edge crosses need no causal compare)."""
+    return k_pos > q_pos - window
 
 
 def dot_product_attention(

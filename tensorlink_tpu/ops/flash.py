@@ -141,9 +141,11 @@ def flash_attention(q, k, v, kv_mask=None, causal: bool = False,
     in-kernel, no repeat); kv_mask: [B, Tk] bool/float (nonzero=attend);
     window: sliding-window band — in-kernel masking plus whole-block
     skipping, so long-seq windowed attention costs O(T*window).
-    causal (no window): blocks above the diagonal are skipped, the block
-    on it is computed in 128/256-wide sub-tiles of which only those the
-    diagonal crosses are masked (ops/pallas/flash_attention.py).
+    causal: blocks above the diagonal are skipped, the block on it is
+    computed in 128/256-wide sub-tiles of which only those the diagonal
+    crosses are masked; under a window each q-block reads only the keys
+    its band meets, in the same sub-tiles, masked only where an edge of
+    the band crosses them (ops/pallas/flash_attention.py).
     -> [B, T, H, Dv]."""
     return _fwd(q, k, v, kv_mask, causal, interpret, window)[0]
 

@@ -34,26 +34,50 @@ diagonal crosses are masked. At T=1024 with one 1024-block that is 36 of
 64 (forward) or 10 of 16 (backward) sub-tiles, 56-62 % of the score
 square where causality needs 50 %; the kernels before PR 28 computed
 75 % of it at 512-blocks and masked every tile. A ``kv_mask`` keeps its
-``where`` on every tile; a window, ``causal=False`` and blocks that are
-not square keep the whole-block body.
+``where`` on every tile; ``causal=False`` and blocks that are not square
+keep the whole-block body.
 
-Sliding-window attention (``window``, Mistral-style) RESTRICTS THE GRID:
-for causal windows each q-block's k-loop covers only the
+A causal sliding window (``window``, Mistral-style) over one sequence,
+at most a block and one position wide, keeps the band q - window < k <=
+q and has a grid of its own (_banded): one step a block of the kernel's
+side (q-blocks in the forward and dq, k-blocks in dkv), which reads the
+other side as one window through an element-offset BlockSpec: the block
+and the pad = (nt - 1) * sub positions its band reaches past it, nt =
+ceil((window - 1) / sub) + 1 being the sub-tiles a strip of ``sub``
+meets (_window, _band_runs). No step fetches a block it does not need
+(an earlier round measured predicating compute alone slower than full
+causal on a v5e: skipped blocks still paid their HBM fetch). The block
+is worked in strips as wide as the diagonal's sub-tiles; strip i meets
+tiles i .. i + nt - 1 of the window, the same for every strip, so one
+loop over the strips, traced and lowered once, works them: the
+diagonal's tile takes the causal mask, the farthest the shifted compare
+of ``nn.attention.window_keep``, and those between run unmasked. The
+strips at the sequence's edges (its first pad queries, its last pad
+keys) work their tiles in a loop of their own, one a turn, with the
+band's whole compare. At T=4096, 1024-blocks and a band of 512 that is
+150 tiles of 128² a head in the forward, 45 of 256² in the backward
+kernels (2.3 and 2.8 block-areas), where the restricted grid below
+computes 7 whole blocks.
+
+A wider causal window (more than a block and one position: its window
+would not fit in VMEM beside the block), and one over two lengths,
+RESTRICT THE GRID instead: each q-block's k-loop covers only the
 ceil((bq+window)/bk)+1 blocks its band can intersect, with the BlockSpec
-index map aiming the DMA at the band (an earlier round measured
-predicating compute alone slower than full causal on a v5e: skipped
-blocks still paid their HBM fetch). Measured on a v5e in PR 28, bf16 [1, 8, 32768, 128]
-W=4096 (the Mistral-7B shape), 1024-blocks, kernels' device time:
-forward 2.8x, forward and backward 2.8x over full causal.
+index map aiming the DMA at the band, and each block is worked whole
+under the band's mask. Measured on a v5e in PR 28, bf16 [1, 8, 32768,
+128] W=4096 (the Mistral-7B shape), 1024-blocks, kernels' device time:
+forward 2.8x, forward and backward 2.8x over full causal. A window
+without ``causal`` keeps the whole-block body on the full grid.
 
 Layout: [B, H, T, D] inside the kernels (contiguous lanes along D).
 Grids: fwd/dq (B, H, Tq/bq, Tk/bk) with k innermost; dkv
-(B, H, Tk/bk, Tq/bq) with q innermost.
+(B, H, Tk/bk, Tq/bq) with q innermost; a band's (B, H, blocks, 1).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -132,12 +156,13 @@ def _keep_mask(mask_ref, causal, qi, kj, block_q, block_k, shape,
     return keep
 
 
-# Sub-tile widths of the diagonal block (_diagonal_tiles), as a v5e
-# chose them at [4, 16, 1024, 64] bf16 (PERF.md, PR 28): the forward is
-# 29 % faster at 128 than at 256 (36 of 64 tiles against 40, and half
-# the masked area), the two backward kernels 4-5 % faster at 256 (their
-# matmuls stream 256 rows past each weight tile instead of 128, which
-# outweighs the 4 extra tiles).
+# Sub-tile widths of the diagonal block (_diagonal_tiles) and of a
+# band's strips (_band_runs), as a v5e chose them at [4, 16, 1024, 64]
+# bf16 (PERF.md, PR 28): the forward is 29 % faster at 128 than at 256
+# (36 of 64 tiles against 40, and half the masked area), the two
+# backward kernels 4-5 % faster at 256 (their matmuls stream 256 rows
+# past each weight tile instead of 128, which outweighs the 4 extra
+# tiles). PR 41 swept them again on the band and kept both.
 SUB_TILE_FWD = 128
 SUB_TILE_BWD = 256
 
@@ -147,10 +172,65 @@ def _diagonal_aware(causal: bool, window: int | None, block_q: int,
     """Static choice of the kernels' body, from what a call fixes at
     trace time. True: causal, no window, square blocks, so that block
     qi == kj is the one the diagonal crosses, corner to corner, and the
-    kernels tell the blocks below, on and above it apart. False: the
-    whole-block body, for everything else (no diagonal; a band, which
-    has its own restricted grid; blocks that are not square)."""
+    kernels tell the blocks below, on and above it apart. False: a band
+    (_banded) or the whole-block body, for everything else (no
+    diagonal; a wider band, which has its own restricted grid; blocks
+    that are not square)."""
     return causal and window is None and block_q == block_k
+
+
+def _banded(causal: bool, window: int | None, Tq: int, Tk: int,
+            block: int) -> bool:
+    """A causal band over one sequence that reaches at most one
+    ``block`` past the kernel's own: each grid step takes one block and
+    the window of the other side that its band meets (_visit_band). A
+    wider band, or one over two lengths, keeps the restricted grid of
+    whole blocks (its window would not fit in VMEM beside the block's),
+    a window without ``causal`` the whole-block body on the full
+    grid."""
+    return causal and window is not None and Tq == Tk and window <= block + 1
+
+
+def _window(o, block: int, pad: int, seq: int, mirror: bool):
+    """First row of the other side's window in a band's grid step over
+    block ``o``: min(block + pad, seq) rows from ``pad`` before the
+    block to its end (under ``mirror``, the dkv kernel's q side: from
+    its start to ``pad`` after it), moved inside the sequence where they
+    would run off it."""
+    if mirror:
+        first = jnp.minimum(o * block, seq - min(block + pad, seq))
+    else:
+        first = jnp.maximum(o * block - pad, 0)
+    # a whole number of sub-tiles, as block, pad and seq are
+    return pl.multiple_of(first, math.gcd(block, pad, seq))
+
+
+def _window_spec(block: int, width: int, window: int, seq: int,
+                 d: int | None, group: int, *, mirror: bool):
+    """BlockSpec of the other side's window (_window) for a band's
+    kernel worked in sub-tiles ``width`` wide, of a [B, H, T, d]
+    operand; ``d`` None: the [B, 1, T] padding vector; ``group``: heads
+    that share a k/v head."""
+    pad = _band_pad(block, width, window)
+    rows = pl.Element(min(block + pad, seq))
+    if d is None:
+        return pl.BlockSpec(
+            (pl.Element(1), pl.Element(1), rows),
+            lambda b, h, o, j: (b, 0, _window(o, block, pad, seq, mirror)))
+    return pl.BlockSpec(
+        (pl.Element(1), pl.Element(1), rows, pl.Element(d)),
+        lambda b, h, o, j: (
+            b, h // group, _window(o, block, pad, seq, mirror), 0))
+
+
+def _sub_width(block: int, width: int) -> int:
+    """The sub-tile width a block is worked in: ``width`` where it
+    divides the block, else 128, else the block (a short sequence's
+    single block: one masked tile)."""
+    for sub in (width, LANES):
+        if block % sub == 0:
+            return sub
+    return block
 
 
 def _sub_tile(causal: bool, window: int | None, block_q: int,
@@ -159,47 +239,85 @@ def _sub_tile(causal: bool, window: int | None, block_q: int,
     the whole-block body, see _diagonal_aware)."""
     if not _diagonal_aware(causal, window, block_q, block_k):
         return None
-    for sub in (width, LANES):
-        if block_q % sub == 0:
-            return sub
-    return block_q  # a short sequence's single block: one masked tile
+    return _sub_width(block_q, width)
 
 
 def _diagonal_tiles(block: int, sub: int, mirror: bool = False):
     """The sub-tiles of the diagonal block that causality leaves, as
-    ``[(strip, [(span, on_diagonal), ...]), ...]`` of static slices:
-    n(n+1)/2 of the n*n, n = block/sub. A strip is ``sub`` q rows and
-    its spans the k columns before it (whole, no mask) and its own (the
-    square the diagonal crosses); mirrored, for the dkv kernel, a strip
-    is ``sub`` k columns and its spans its own q rows and those below."""
+    ``[(strip, [(span, edges), ...]), ...]`` of static slices: n(n+1)/2
+    of the n*n, n = block/sub. A strip is ``sub`` q rows and its spans
+    the k columns before it (whole, no mask) and its own (the square the
+    diagonal crosses); mirrored, for the dkv kernel, a strip is ``sub``
+    k columns and its spans its own q rows and those below. ``edges`` as
+    _tile_keep reads them."""
     out = []
+    on = (0, True, None)
     for i in range(block // sub):
         strip = pl.ds(i * sub, sub)
         before, after = i * sub, block - (i + 1) * sub
         if mirror:
-            rest = [(pl.ds((i + 1) * sub, after), False)] if after else []
-            out.append((strip, [(strip, True)] + rest))
+            rest = [(pl.ds((i + 1) * sub, after), None)] if after else []
+            out.append((strip, [(strip, on)] + rest))
         else:
-            rest = [(pl.ds(0, before), False)] if before else []
-            out.append((strip, rest + [(strip, True)]))
+            rest = [(pl.ds(0, before), None)] if before else []
+            out.append((strip, rest + [(strip, on)]))
     return out
 
 
-def _tile_keep(mask_ref, cols, on_diagonal: bool, shape):
-    """Keep mask of one tile of the diagonal-aware body (None = keep
-    all): the lower triangle on a diagonal square (local positions do:
-    the block's and the sub-tile's offsets are the same for q and k
-    there), the padding vector's columns where a call has one, nothing
-    at all below the diagonal: no iota, no compare, no ``where``."""
-    keep = None
-    if on_diagonal:
-        from tensorlink_tpu.nn.attention import band_keep
+def _band_runs(sub: int, window: int, mirror: bool):
+    """The sub-tiles one strip of a causal band of ``window`` keys
+    meets: ``(nt, [(first, count, edges), ...])``. A strip is ``sub`` q
+    rows against the keys before them (mirrored, ``sub`` k columns
+    against the q rows after them); its band meets nt = ceil((window -
+    1) / sub) + 1 tiles of ``sub``, tile t at t * sub along the strip's
+    window (_visit_band). Their class is read off the range of k - q a
+    tile holds: the one the diagonal crosses takes the causal mask, the
+    farthest the shifted compare of the band's lower edge, and those
+    between are wholly inside, unmasked, neighbours run together as one
+    span (``edges`` None); ``edges`` as _tile_keep reads them."""
+    nt = -(-(window - 1) // sub) + 1
+    runs = []
+    for t in range(nt):
+        # the tile's first k less its first q
+        off = -t * sub if mirror else (t - nt + 1) * sub
+        lo, hi = off - (sub - 1), off + (sub - 1)
+        cut = lo <= -window
+        edges = (-off, hi > 0, window if cut else None) if hi > 0 or cut else None
+        if edges is None and runs and runs[-1][2] is None:
+            runs[-1] = (runs[-1][0], runs[-1][1] + 1, None)
+        else:
+            runs.append((t, 1, edges))
+    return nt, runs
 
-        keep = band_keep(
-            jax.lax.broadcasted_iota(jnp.int32, shape, 0),
-            jax.lax.broadcasted_iota(jnp.int32, shape, 1),
-            True, None,
-        )
+
+def _band_pad(block: int, width: int, window: int) -> int:
+    """Positions a strip's window reaches past its block: (nt - 1) * sub
+    of _band_runs, the other side's window is the block and these."""
+    sub = _sub_width(block, width)
+    return (_band_runs(sub, window, False)[0] - 1) * sub
+
+
+def _tile_keep(mask_ref, cols, edges, shape):
+    """Keep mask of one tile of the diagonal-aware body or of a band
+    (None = keep all). ``edges`` None: a tile inside, with no iota, no
+    compare, no ``where``. Else ``(shift, diagonal, window | None)``:
+    the q positions' offset against the k positions' (none on the
+    diagonal's own squares, where the block's and the sub-tile's offsets
+    are the same for q and k), whether the diagonal crosses the tile,
+    and the window where the band's lower edge does, through
+    nn.attention's predicates; and the padding vector's columns where a
+    call has one."""
+    keep = None
+    if edges is not None:
+        from tensorlink_tpu.nn.attention import band_keep, window_keep
+
+        shift, diagonal, window = edges
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        if not isinstance(shift, int) or shift:
+            q_pos = q_pos + shift
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        keep = (band_keep(q_pos, k_pos, True, window) if diagonal
+                else window_keep(q_pos, k_pos, window))
     if mask_ref is not None:
         kv_keep = jnp.broadcast_to(mask_ref[0, :, cols] > 0, shape)
         keep = kv_keep if keep is None else jnp.logical_and(keep, kv_keep)
@@ -208,16 +326,27 @@ def _tile_keep(mask_ref, cols, on_diagonal: bool, shape):
 
 def _visit(body, *, mirror: bool, width: int, causal: bool,
            window: int | None, block_q: int, block_k: int, qi, kj,
-           in_range, mask_ref):
+           in_range, mask_ref, band_seq: int | None = None):
     """Run ``body(strip, [(span, keep | None), ...])`` over the score
     tiles of grid step (qi, kj); a strip is q rows and a span k columns,
-    or the reverse under ``mirror`` (the dkv kernel). Whole-block body
+    or the reverse under ``mirror`` (the dkv kernel). A band (_banded,
+    over a sequence of ``band_seq``): _visit_band. Whole-block body
     (_diagonal_aware False): one tile, the block, if it is visible, with
     the full band and padding mask. Else the block is recognised from
     its indices, as _block_visible recognises a skipped one: below the
     diagonal one unmasked tile, on it the tiles of _diagonal_tiles
     (``width`` wide), above it nothing."""
     strip_len, span_len = (block_k, block_q) if mirror else (block_q, block_k)
+
+    def keep_of(strip, span, edges):
+        rows, cols = (span, strip) if mirror else (strip, span)
+        return _tile_keep(mask_ref, cols, edges, (rows.size, cols.size))
+
+    if band_seq is not None:
+        _visit_band(body, keep_of, mirror=mirror, width=width,
+                    window=window, block=strip_len, seq=band_seq,
+                    o=kj if mirror else qi)
+        return
     strip, span = pl.ds(0, strip_len), pl.ds(0, span_len)
     sub = _sub_tile(causal, window, block_q, block_k, width)
     if sub is None:
@@ -233,21 +362,73 @@ def _visit(body, *, mirror: bool, width: int, causal: bool,
             ))])
         return
 
-    def keep_of(strip, span, on_diagonal):
-        rows, cols = (span, strip) if mirror else (strip, span)
-        return _tile_keep(
-            mask_ref, cols, on_diagonal, (rows.size, cols.size))
-
     @pl.when(kj < qi)
     def _below():
-        body(strip, [(span, keep_of(strip, span, False))])
+        body(strip, [(span, keep_of(strip, span, None))])
 
     @pl.when(kj == qi)
     def _diagonal():
         for strip_, spans in _diagonal_tiles(strip_len, sub, mirror):
             body(strip_, [
-                (span_, keep_of(strip_, span_, diag)) for span_, diag in spans
+                (span_, keep_of(strip_, span_, edges))
+                for span_, edges in spans
             ])
+
+
+def _visit_band(body, keep_of, *, mirror: bool, width: int, window: int,
+                block: int, seq: int, o):
+    """A causal band's grid step: block ``o`` of ``block`` q rows (the
+    dkv kernel, under ``mirror``: k columns), in strips of ``sub``,
+    against the other side's window (_window): the block and the pad =
+    (nt - 1) * sub positions before it (under ``mirror`` after it) that
+    its band reaches (_band_runs). Strip i meets tiles i .. i + nt - 1
+    of the window, so every strip has the same tiles at offsets that
+    move with i: one loop over the strips, traced and lowered once,
+    works them. The strips whose band runs off the sequence (its first
+    pad queries; its last pad keys) work their tiles one by one, each
+    if it is in the sequence; for their blocks the window is moved
+    inside it, and the offsets with it."""
+    sub = _sub_width(block, width)
+    nt, runs = _band_runs(sub, window, mirror)
+    n, pad = block // sub, (nt - 1) * sub
+    first = _window(o, block, pad, seq, mirror)
+    # the window's row of the block's strip 0 and of tile 0 with it
+    base = first - o * block + (0 if mirror else pad)
+
+    def at(i, t, count=1):
+        return pl.ds(pl.multiple_of((i + t) * sub - base, sub), count * sub)
+
+    def whole(i, carry):
+        strip = pl.ds(pl.multiple_of(i * sub, sub), sub)
+        body(strip, [(at(i, t, c), keep_of(strip, at(i, t, c), e))
+                     for t, c, e in runs])
+        return carry
+
+    def one_by_one(i, carry):
+        strip = pl.ds(pl.multiple_of(i * sub, sub), sub)
+
+        def tile(t, carry):
+            # the band's whole compare, from tile t's offset k - q
+            edges = (t * sub if mirror else (nt - 1 - t) * sub, True, window)
+            body(strip, [(at(i, t), keep_of(strip, at(i, t), edges))])
+            return carry
+
+        # the tiles in the sequence: q rows before its end (k columns
+        # from its start)
+        if mirror:
+            return jax.lax.fori_loop(
+                0, jnp.minimum((seq - o * block) // sub - i, nt), tile, carry)
+        return jax.lax.fori_loop(
+            jnp.maximum((pad - o * block) // sub - i, 0), nt, tile, carry)
+
+    if mirror:
+        cut = jnp.clip((seq - o * block - pad) // sub, 0, n)
+        jax.lax.fori_loop(0, cut, whole, 0)
+        jax.lax.fori_loop(cut, n, one_by_one, 0)
+    else:
+        cut = jnp.clip((pad - o * block) // sub, 0, n)
+        jax.lax.fori_loop(0, cut, one_by_one, 0)
+        jax.lax.fori_loop(cut, n, whole, 0)
 
 
 def _f32(ref, span):
@@ -285,6 +466,7 @@ def _flash_fwd_kernel(
     window: int | None = None,
     win_grid_nk: int | None = None,  # set = windowed-causal restricted
     nk_full: int | None = None,      # grid (see flash_attention_fwd_lse)
+    band_seq: int | None = None,     # a causal band's length (_visit_band)
 ):
     if has_mask:
         q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
@@ -346,7 +528,7 @@ def _flash_fwd_kernel(
     _visit(
         update, mirror=False, width=SUB_TILE_FWD, causal=causal,
         window=window, block_q=block_q, block_k=block_k, qi=qi, kj=kj,
-        in_range=in_range, mask_ref=mask_ref,
+        in_range=in_range, mask_ref=mask_ref, band_seq=band_seq,
     )
 
     @pl.when(j_grid == nk - 1)
@@ -414,15 +596,20 @@ def flash_attention_fwd_lse(
     _check_blocks(Tq, Tk, block_q, block_k)
     scale = D ** -0.5
     nk_full = Tk // block_k
-    # windowed causal: only ceil((bq + window)/bk)+1 k-blocks can
+    # a band: one grid step a q-block, which reads the k/v window its
+    # band meets (_visit_band)
+    band = _banded(causal, window, Tq, Tk, block_q)
+    win = functools.partial(_window_spec, block_q, SUB_TILE_FWD, window, Tq,
+                            mirror=False)
+    # a wider causal window: only ceil((bq + window)/bk)+1 k-blocks can
     # intersect a q-block's band — restrict the GRID (and with it the
     # k/v block DMA) to that range instead of predicating compute only:
     # under pl.when alone a skipped block still pays its HBM fetch.
     win_nk = None
-    if window is not None and causal and nk_full > 1:
+    if window is not None and causal and nk_full > 1 and not band:
         win_nk = min(nk_full, (block_q + window + block_k) // block_k + 1)
     grid_nk = win_nk if win_nk is not None else nk_full
-    grid = (B, H, Tq // block_q, grid_nk)
+    grid = (B, H, Tq // block_q, 1 if band else grid_nk)
 
     diagonal = _diagonal_aware(causal, window, block_q, block_k)
 
@@ -438,6 +625,13 @@ def flash_attention_fwd_lse(
             _win_lo(i, block_q, block_k, window) + j, nk_full - 1
         )
 
+    def kv_spec(d):
+        if band:
+            return win(d, group)
+        return pl.BlockSpec(
+            (1, 1, block_k, d),
+            lambda b, h, i, j: (b, h // group, kv_block(i, j), 0))
+
     kernel = functools.partial(
         _flash_fwd_kernel,
         causal=causal,
@@ -448,19 +642,18 @@ def flash_attention_fwd_lse(
         window=window,
         win_grid_nk=win_nk,
         nk_full=nk_full,
+        band_seq=Tq if band else None,
     )
     in_specs = [
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, i, j: (b, h // group, kv_block(i, j), 0)),
-        pl.BlockSpec((1, 1, block_k, Dv),
-                     lambda b, h, i, j: (b, h // group, kv_block(i, j), 0)),
+        kv_spec(D),
+        kv_spec(Dv),
     ]
     args = [q, k, v]
     if kv_mask is not None:
         # kv_mask rides a middle singleton dim ([B, 1, Tk]) so the block's
         # last two dims (1, block_k) satisfy Mosaic's tiling rule
-        in_specs.append(pl.BlockSpec(
+        in_specs.append(win(None, 1) if band else pl.BlockSpec(
             (1, 1, block_k), lambda b, h, i, j: (b, 0, kv_block(i, j))
         ))
         args.append(kv_mask.astype(jnp.float32)[:, None, :])
@@ -505,6 +698,7 @@ def _flash_bwd_dq_kernel(
     window: int | None = None,
     win_grid_nk: int | None = None,
     nk_full: int | None = None,
+    band_seq: int | None = None,
 ):
     if has_mask:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
@@ -546,7 +740,7 @@ def _flash_bwd_dq_kernel(
     _visit(
         accumulate, mirror=False, width=SUB_TILE_BWD, causal=causal,
         window=window, block_q=block_q, block_k=block_k, qi=qi, kj=kj,
-        in_range=in_range, mask_ref=mask_ref,
+        in_range=in_range, mask_ref=mask_ref, band_seq=band_seq,
     )
 
     @pl.when(j_grid == nk - 1)
@@ -568,6 +762,7 @@ def _flash_bwd_dkv_kernel(
     window: int | None = None,
     win_grid_nq: int | None = None,
     nq_full: int | None = None,
+    band_seq: int | None = None,
 ):
     if has_mask:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
@@ -620,7 +815,7 @@ def _flash_bwd_dkv_kernel(
     _visit(
         accumulate, mirror=True, width=SUB_TILE_BWD, causal=causal,
         window=window, block_q=block_q, block_k=block_k, qi=qi, kj=kj,
-        in_range=in_range, mask_ref=mask_ref,
+        in_range=in_range, mask_ref=mask_ref, band_seq=band_seq,
     )
 
     @pl.when(i_grid == nq - 1)
@@ -664,12 +859,18 @@ def flash_attention_bwd(
 
     nk_full = Tk // block_k
     nq_full = Tq // block_q
+    # a band: one grid step a block of the kernel's own side, which
+    # reads the other side's window (as in the forward)
+    band_q = _banded(causal, window, Tq, Tk, block_q)
+    band_k = _banded(causal, window, Tq, Tk, block_k)
+    win = functools.partial(_window_spec, width=SUB_TILE_BWD, window=window,
+                            seq=Tq)
     win_nk = win_nq = None
     if window is not None and causal:
         # same grid restriction as the forward (see its comment)
-        if nk_full > 1:
+        if nk_full > 1 and not band_q:
             win_nk = min(nk_full, (block_q + window + block_k) // block_k + 1)
-        if nq_full > 1:
+        if nq_full > 1 and not band_k:
             win_nq = min(nq_full, (block_k + window + block_q) // block_q + 1)
 
     diagonal = _diagonal_aware(causal, window, block_q, block_k)
@@ -694,6 +895,8 @@ def flash_attention_bwd(
         return pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0))
 
     def kspec_of(d):
+        if band_q:
+            return win(block_q, d=d, group=group, mirror=False)
         return pl.BlockSpec(
             (1, 1, block_k, d),
             lambda b, h, i, j: (b, h // group, kv_block(i, j), 0))
@@ -708,17 +911,19 @@ def flash_attention_bwd(
     in_specs = [qspec, kspec_of(D), kspec_of(Dv), qspec_of(Dv), rowq, rowq]
     if kv_mask is not None:
         args.append(kv_mask.astype(jnp.float32)[:, None, :])
-        in_specs.append(pl.BlockSpec(
+        in_specs.append(win(
+            block_q, d=None, group=1, mirror=False) if band_q else pl.BlockSpec(
             (1, 1, block_k), lambda b, h, i, j: (b, 0, kv_block(i, j))
         ))
 
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, win_grid_nk=win_nk, nk_full=nk_full,
-            **common,
+            band_seq=Tq if band_q else None, **common,
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(B, H, nq_full, win_nk if win_nk is not None else nk_full),
+        grid=(B, H, nq_full, 1 if band_q else (
+            win_nk if win_nk is not None else nk_full)),
         in_specs=in_specs,
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -728,6 +933,8 @@ def flash_attention_bwd(
 
     # dkv grid swaps the outer two block axes: (b, h, kj, qi)
     def qspec2(d):
+        if band_k:
+            return win(block_k, d=d, group=1, mirror=True)
         return pl.BlockSpec(
             (1, 1, block_q, d), lambda b, h, j, i: (b, h, q_block(j, i), 0))
 
@@ -738,21 +945,21 @@ def flash_attention_bwd(
     def hspec2(d):
         return pl.BlockSpec((1, 1, block_k, d), lambda b, h, j, i: (b, h, j, 0))
 
-    rowq2 = pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, h, j, i: (b, h, q_block(j, i), 0))
-    in_specs2 = [qspec2(D), kspec2(D), kspec2(Dv), qspec2(Dv), rowq2, rowq2]
+    in_specs2 = [qspec2(D), kspec2(D), kspec2(Dv), qspec2(Dv), qspec2(1),
+                 qspec2(1)]
     if kv_mask is not None:
         in_specs2.append(pl.BlockSpec((1, 1, block_k), lambda b, h, j, i: (b, 0, j)))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, win_grid_nq=win_nq, nq_full=nq_full,
-            **common,
+            band_seq=Tk if band_k else None, **common,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Tk, Dv), v.dtype),
         ),
-        grid=(B, H, nk_full, win_nq if win_nq is not None else nq_full),
+        grid=(B, H, nk_full, 1 if band_k else (
+            win_nq if win_nq is not None else nq_full)),
         in_specs=in_specs2,
         out_specs=(hspec2(D), hspec2(Dv)),
         scratch_shapes=[

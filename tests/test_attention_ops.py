@@ -844,11 +844,11 @@ def test_pallas_flash_window_with_padding_mask():
 
 
 def test_pallas_flash_window_restricted_grid_with_kv_mask():
-    """Restricted-grid windowed kernels WITH a kv padding mask (advisor
-    r4: the mask BlockSpec's kv_block(i,j) DMA indexing in restricted
-    mode had no coverage — the other window tests ran either single
-    k-block shapes or kv_mask=None). T=1024, W=128, 128-blocks: win_nk
-    (4) < nk_full (8). Forward + all three grads vs the reference."""
+    """Windowed kernels WITH a kv padding mask: the mask comes as the
+    band's window too (the same element-offset BlockSpec as k and v),
+    and its columns are read at the strips' offsets. T=1024, W=128,
+    128-blocks: each q-block's window is 256 of the 1024 keys. Forward
+    + all three grads vs the reference."""
     from tensorlink_tpu.ops.pallas.flash_attention import (
         flash_attention_bwd, flash_attention_fwd_lse,
     )
@@ -914,45 +914,31 @@ def test_pallas_flash_window_restricted_grid_with_kv_mask():
             np.testing.assert_allclose(av, bv, atol=1e-4)
 
 
-def test_pallas_flash_window_restricted_grid_parity():
-    """T=2048 with a small window: the k-grid is genuinely RESTRICTED
-    ((bq+W+bk)/bk+1 < Tk/bk) — skipped blocks' DMA never happens, and
-    init/finalize key on grid-local indices. Forward + grads parity."""
-    from tensorlink_tpu.ops.pallas.flash_attention import (
-        flash_attention_bwd, flash_attention_fwd_lse,
+@pytest.mark.parametrize("T,block,W,H,Hkv,masked", [
+    pytest.param(2048, 512, 200, 2, 2, False, id="T2048-b512-W200"),
+    pytest.param(1024, 512, 256, 1, 1, False, id="window-half-block"),
+    pytest.param(512, 256, 256, 1, 1, False, id="window-one-block"),
+    pytest.param(1024, 256, 400, 1, 1, False, id="window-over-block"),
+    pytest.param(512, 256, 100, 1, 1, False, id="window-100"),
+    pytest.param(256, 128, 64, 9, 1, False, id="gqa9"),
+    pytest.param(256, 128, 64, 6, 1, False, id="gqa6"),
+    pytest.param(512, 256, 100, 2, 1, True, id="kv-mask"),
+])
+def test_pallas_flash_window_restricted_grid_parity(T, block, W, H, Hkv,
+                                                    masked):
+    """Causal bands on their own grid, one step a block against the
+    window its band meets: a band narrower than a block, as wide,
+    narrower than a sub-tile and not a multiple of it, grouped-query
+    groups of 9 and 6, a padding mask; and one wider than a block and
+    not a multiple of it, on the restricted grid. Forward, lse and the
+    three gradients against the plain path."""
+    q, g = (_rand((1, T, H, 32), jnp.float32, 7 + i) for i in range(2))
+    k, v = (_rand((1, T, Hkv, 32), jnp.float32, 9 + i) for i in range(2))
+    kv_mask = jnp.arange(T)[None, :] < T - 37 if masked else None
+    _kernels_vs_reference(
+        q, k, v, g, block, kv_mask=kv_mask, window=W,
+        fwd_tol=2e-5, bwd_tol=5e-5,
     )
-
-    r = np.random.default_rng(7)
-    B, T, H, D, W = 1, 2048, 2, 32, 200
-    q, k, v = (
-        jnp.asarray(r.normal(size=(B, T, H, D)), jnp.float32)
-        for _ in range(3)
-    )
-    qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
-    out, lse = flash_attention_fwd_lse(
-        qt, kt, vt, None, causal=True, block_q=512, block_k=512,
-        interpret=True, window=W,
-    )
-    ref = dot_product_attention(q, k, v, causal=True, window=W)
-    np.testing.assert_allclose(
-        np.asarray(out.swapaxes(1, 2)), np.asarray(ref),
-        atol=2e-5, rtol=2e-5,
-    )
-
-    g = jnp.asarray(r.normal(size=(B, H, T, D)), jnp.float32)
-    dq, dk, dv = flash_attention_bwd(
-        qt, kt, vt, out, lse, g, None, causal=True,
-        block_q=512, block_k=512, interpret=True, window=W,
-    )
-    def ref_loss(q_, k_, v_):
-        o = dot_product_attention(q_, k_, v_, causal=True, window=W)
-        return jnp.sum(o.swapaxes(1, 2) * g)
-    rq, rk, rv = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
-    for a, b in ((dq, rq), (dk, rk), (dv, rv)):
-        np.testing.assert_allclose(
-            np.asarray(a.swapaxes(1, 2)), np.asarray(b),
-            atol=5e-5, rtol=5e-5,
-        )
 
 
 def test_rolling_cache_multitoken_write_wraps():
@@ -1256,29 +1242,34 @@ def _kernels_vs_reference(q, k, v, g, block, *, causal=True, kv_mask=None,
     assert o.dtype == q.dtype and lse.dtype == jnp.float32
     assert (dq.dtype, dk.dtype, dv.dtype) == (q.dtype, k.dtype, v.dtype)
 
-    q32, k32, v32, g32 = (x.astype(jnp.float32) for x in (q, k, v, g))
-    ref, vjp = jax.vjp(
-        lambda q_, k_, v_: _fallback_attn(
-            q_, k_, v_, kv_mask, causal, window),
-        q32, k32, v32,
-    )
-    rq, rk, rv = vjp(g32)
-    # the reference's lse, from its own scores
-    T, rep = q.shape[1], q.shape[2] // k.shape[2]
-    s = jnp.einsum(
-        "bqhd,bkhd->bhqk", q32, jnp.repeat(k32, rep, axis=2)
-    ) * q.shape[-1] ** -0.5
     from tensorlink_tpu.nn.attention import band_keep
 
-    keep = jnp.ones((1, 1, T, T), bool)
-    if causal or window is not None:
-        keep = band_keep(
-            jnp.arange(T)[:, None], jnp.arange(T)[None, :], causal, window
-        )[None, None]
-    if kv_mask is not None:
-        keep = jnp.logical_and(keep, (kv_mask > 0)[:, None, None, :])
-    rlse = jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1)
-    rlse = jnp.where(jnp.any(keep, axis=-1), rlse, LSE_MASKED)
+    @jax.jit  # one compile, not one per operation
+    def reference(q32, k32, v32, g32):
+        ref, vjp = jax.vjp(
+            lambda q_, k_, v_: _fallback_attn(
+                q_, k_, v_, kv_mask, causal, window),
+            q32, k32, v32,
+        )
+        # the reference's lse, from its own scores
+        T, rep = q.shape[1], q.shape[2] // k.shape[2]
+        s = jnp.einsum(
+            "bqhd,bkhd->bhqk", q32, jnp.repeat(k32, rep, axis=2)
+        ) * q.shape[-1] ** -0.5
+        keep = jnp.ones((1, 1, T, T), bool)
+        if causal or window is not None:
+            keep = band_keep(
+                jnp.arange(T)[:, None], jnp.arange(T)[None, :], causal,
+                window,
+            )[None, None]
+        if kv_mask is not None:
+            keep = jnp.logical_and(keep, (kv_mask > 0)[:, None, None, :])
+        rlse = jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1)
+        rlse = jnp.where(jnp.any(keep, axis=-1), rlse, LSE_MASKED)
+        return (ref, rlse, *vjp(g32))
+
+    ref, rlse, rq, rk, rv = reference(
+        *(x.astype(jnp.float32) for x in (q, k, v, g)))
 
     def close(a, b, tol):
         np.testing.assert_allclose(
@@ -1321,9 +1312,9 @@ def test_flash_causal_subtiles_match_reference(T, block, D, dtype):
 def test_flash_bodies_beside_the_diagonal_path(case):
     """What must keep its behaviour: a padding mask keeps its ``where``
     on every tile, also where it empties whole rows (keys 0..299 of one
-    sequence masked: its rows 0..299 see nothing and read 0); a window
-    and ``causal=False`` keep the whole-block body; GQA reads the
-    unrepeated heads through either."""
+    sequence masked: its rows 0..299 see nothing and read 0); a causal
+    window takes the band's strips (_visit_band), ``causal=False`` the
+    whole-block body; GQA reads the unrepeated heads through each."""
     T, H, D, block = 512, 4, 64, 256
     causal, kv_mask, window, hkv = True, None, None, H
     if case == "kv_mask_empties_rows":
@@ -1400,6 +1391,37 @@ def _branches(jaxpr):
     return out
 
 
+def _flash_kernels(T, D, **kw):
+    """The three flash kernels' ``pallas_call`` parameters, by name, as
+    one head at [1, 1, T, D] bf16 traces them (nothing compiles)."""
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        flash_attention_bwd, flash_attention_fwd_lse,
+    )
+
+    t4 = jax.ShapeDtypeStruct((1, 1, T, D), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((1, 1, T), jnp.float32)
+    text = [
+        jax.make_jaxpr(lambda q, k, v: flash_attention_fwd_lse(
+            q, k, v, None, **kw))(t4, t4, t4),
+        jax.make_jaxpr(lambda q, k, v, o, l, g: flash_attention_bwd(
+            q, k, v, o, l, g, None, **kw))(t4, t4, t4, t4, lse, t4),
+    ]
+    kernels = {}
+
+    def find(jaxpr):  # the entry points are jitted: look inside
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                kernels[e.params["name"]] = e.params
+            for sub in jax.core.jaxprs_in_params(e.params):
+                find(sub)
+
+    for jp in text:
+        find(jp.jaxpr)
+    assert sorted(kernels) == [
+        "tl_flash_bwd_dkv", "tl_flash_bwd_dq", "tl_flash_fwd"]
+    return kernels
+
+
 # bound on the diagonal block's computed share, by (block, forward?):
 # 128-wide sub-tiles in the forward (10 of 16, 36 of 64), 256-wide in
 # the backward kernels (3 of 4 at a 512-block, 10 of 16 at 1024)
@@ -1413,34 +1435,11 @@ def test_flash_causal_kernels_skip_tiles_above_the_diagonal(
     all three, dP in the two backward kernels) produce at most that
     share of block x block elements; the body of a block below the
     diagonal builds no position mask at all."""
-    from tensorlink_tpu.ops.pallas.flash_attention import (
-        flash_attention_bwd, flash_attention_fwd_lse,
-    )
-
-    B, H, T, D = 1, 1, 2 * block, 64
-    t4 = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16)
-    lse = jax.ShapeDtypeStruct((B, H, T), jnp.float32)
-    kw = dict(causal=True, block_q=block, block_k=block)
-    text = [
-        jax.make_jaxpr(lambda q, k, v: flash_attention_fwd_lse(
-            q, k, v, None, **kw))(t4, t4, t4),
-        jax.make_jaxpr(lambda q, k, v, o, l, g: flash_attention_bwd(
-            q, k, v, o, l, g, None, **kw))(t4, t4, t4, t4, lse, t4),
-    ]
-    kernels = {}
-
-    def find(jaxpr):  # the entry points are jitted: look inside
-        for e in jaxpr.eqns:
-            if e.primitive.name == "pallas_call":
-                kernels[e.params["name"]] = e.params["jaxpr"]
-            for sub in jax.core.jaxprs_in_params(e.params):
-                find(sub)
-
-    for jp in text:
-        find(jp.jaxpr)
-    assert sorted(kernels) == [
-        "tl_flash_bwd_dkv", "tl_flash_bwd_dq", "tl_flash_fwd"]
-    for name, body in kernels.items():
+    D = 64
+    kernels = _flash_kernels(2 * block, D, causal=True, block_q=block,
+                             block_k=block)
+    for name, params in kernels.items():
+        body = params["jaxpr"]
         below = [b for b in _branches(body) if "iota" not in b[0]]
         diagonal = [b for b in _branches(body) if "iota" in b[0]]
         assert len(below) == 1 and len(diagonal) == 1, name
@@ -1454,6 +1453,99 @@ def test_flash_causal_kernels_skip_tiles_above_the_diagonal(
         assert scores(diagonal[0]) <= share * kinds * block * block, name
         for prim in ("iota", "select_n", "lt", "le", "ge", "gt"):
             assert prim not in below[0][0], (name, prim)
+
+
+def _loops(jaxpr):
+    """Every top-level loop of a kernel body as what its body holds:
+    ``(primitive names, [dot_general (contracted size, result
+    elements)], [select_n result elements], loops nested in it)``."""
+    def walk(jp, names, dots, selects, nested):
+        for e in jp.eqns:
+            names.append(e.primitive.name)
+            if e.primitive.name == "dot_general":
+                (lc, _), _ = e.params["dimension_numbers"]
+                dots.append((
+                    e.invars[0].aval.shape[lc[0]],
+                    int(np.prod(e.outvars[0].aval.shape)),
+                ))
+            if e.primitive.name == "select_n":
+                selects.append(int(np.prod(e.outvars[0].aval.shape)))
+            if e.primitive.name == "while":
+                nested.append(e)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub, names, dots, selects, nested)
+
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "while":
+            names, dots, selects, nested = [], [], [], []
+            walk(e.params["body_jaxpr"].jaxpr, names, dots, selects, nested)
+            out.append((names, dots, selects, nested))
+    return out
+
+
+def _band_tiles_by_hand(sub, window):
+    """One strip of ``sub`` queries of a causal band, from every
+    position: the sub-tiles of the keys before it that the band meets,
+    and how many of them it covers only in part."""
+    q = np.arange(sub)[:, None] + 8 * sub  # far from the sequence's start
+    k = np.arange(9 * sub)[None, :]
+    keep = (k <= q) & (k > q - window)
+    tiles = keep.reshape(sub, 9, sub).swapaxes(0, 1).reshape(9, -1)
+    met, whole = tiles.any(-1), tiles.all(-1)
+    return int(met.sum()), int((met & ~whole).sum())
+
+
+# (block, window, blocks in T): the cells' band (a 1024-block and half a
+# block before it), the widest band a block takes (one block and one
+# position past it), a band narrower than a sub-tile and not a multiple
+# of it
+@pytest.mark.parametrize("block,window,nblocks", [
+    (1024, 512, 4), (256, 257, 4), (512, 100, 2),
+])
+def test_flash_band_kernels_visit_only_the_band(block, window, nblocks):
+    """The mechanism, from the kernels' traced bodies under a causal
+    band: one grid step a block, whose other side comes as one window,
+    the block and the sub-tiles its band reaches past it. Two loops over
+    the block's strips: one, traced once, works every strip whose band
+    lies in the sequence, and its score-shaped matmuls produce exactly
+    the sub-tiles a strip's band meets, with position iotas and
+    ``where`` only on those it covers in part (the tiles between run
+    unmasked); the other, for the strips at the sequence's edge, works
+    one tile a turn."""
+    from tensorlink_tpu.ops.pallas.flash_attention import (
+        SUB_TILE_BWD, SUB_TILE_FWD,
+    )
+
+    D = 64
+    kernels = _flash_kernels(nblocks * block, D, causal=True, block_q=block,
+                             block_k=block, window=window)
+    for name, params in kernels.items():
+        fwd = name == "tl_flash_fwd"
+        width = SUB_TILE_FWD if fwd else SUB_TILE_BWD
+        sub = width if block % width == 0 else 128
+        met, partial = _band_tiles_by_hand(sub, window)
+        assert params["grid_mapping"].grid[-1] == 1, name
+        # the window: the block and the tiles its band reaches past it
+        window_of = [1, 2] if name != "tl_flash_bwd_dkv" else [0, 3, 4, 5]
+        for i in window_of:
+            rows = params["grid_mapping"].block_mappings[i].block_shape[2]
+            assert rows.block_size == block + (met - 1) * sub, (name, i)
+        kinds = 1 if fwd else 2  # S; S and dP
+        loops = _loops(params["jaxpr"])
+        assert len(loops) == 2, name
+        (whole,) = [lp for lp in loops if not lp[3]]
+        (edge,) = [lp for lp in loops if lp[3]]
+        names, dots, selects, _ = whole
+        scores = sum(n for contracted, n in dots if contracted == D)
+        assert scores == kinds * met * sub * sub, name
+        assert names.count("iota") == 2 * partial, name
+        # where on S and on p in the forward, on p in the backward
+        assert sum(selects) == (2 if fwd else 1) * partial * sub * sub, name
+        names, dots, _, nested = edge
+        assert len(nested) == 1, name  # its tiles, one a turn
+        scores = sum(n for contracted, n in dots if contracted == D)
+        assert scores == kinds * sub * sub, name
 
 
 # ------------------------------------------- paged-decode kernel (ISSUE 20)

@@ -156,7 +156,7 @@ def test_flash_at_differential_attention_widths_compiles(compile_for_chip, windo
     """Phi-4-mini-flash's differential attention at the benchmark cell's
     shape: q, k 64 wide, v a pair's 128, 20 query heads on 10 key heads,
     4 x 4,096 tokens, causal, over every earlier key and over a band of
-    512 (the kernels' restricted grid), at the blocks ``flash_block_for``
+    512 (the band's own grid), at the blocks ``flash_block_for``
     gives."""
     from tensorlink_tpu.ops.flash import flash_block_for
     from tensorlink_tpu.ops.pallas.flash_attention import (
